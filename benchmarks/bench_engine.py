@@ -24,6 +24,7 @@ from repro.core.avss import SearchConfig
 from repro.core.mcam import MCAMConfig
 from repro.core.memory import MemoryConfig
 from repro.engine import (MemoryStore, RetrievalEngine, SearchRequest)
+from repro.launch.mesh import make_mesh
 
 # PINNED to the acceptance shape of the PR-5/6 shortlist baselines
 # (BENCH_shortlist.json compares against rows at this exact N) -- do NOT
@@ -89,7 +90,7 @@ def run():
     # launch with XLA_FLAGS=--xla_force_host_platform_device_count=8 to see
     # the multi-shard shape)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     svs = jax.device_put(sv, NamedSharding(mesh, P("data")))
     eng = RetrievalEngine(cfg, backend="ref")
     with mesh:

@@ -33,6 +33,7 @@ _WORKER = """
     from repro.core.mcam import MCAMConfig
     from repro.core.memory import MemoryConfig
     from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+    from repro.launch.mesh import make_mesh
 
     N, B, D, K = 4096, 16, 48, 64
     cfg = SearchConfig("mtmc", cl=8, mode="avss", mcam=MCAMConfig(),
@@ -58,7 +59,7 @@ _WORKER = """
                 "us_per_call": us1, "shards": 1,
                 "qps": B / us1 * 1e6}]
     for n_dev in (2, 4, 8):
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         sstore = store.shard(mesh, ("data",))
         with mesh:
             f = jax.jit(lambda st, q: (eng.search(st, q, req).votes,))
@@ -82,7 +83,7 @@ _WORKER = """
         st, q, SearchRequest(mode="ideal", k=K)).dist,))
     _, (ref_dist,) = time_us(ideal_ref, store, qv)
     for n_dev in (2, 8):
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         sstore = store.shard(mesh, ("data",))
         for tag, fmr in (("dense", 1 << 30), ("fused", 1)):
             req = SearchRequest(mode="ideal", k=K, backend="mxu",
@@ -96,7 +97,7 @@ _WORKER = """
                                     % (tag, K, n_dev),
                             "us_per_call": us, "shards": n_dev,
                             "shortlist": tag, "qps": B / us * 1e6})
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sstore = store.shard(mesh, ("data",))
     for tag, fmr in (("dense", 1 << 30), ("fused", 1)):
         req = SearchRequest(mode="two_phase", k=K, backend="mxu",
@@ -125,7 +126,7 @@ _WORKER = """
                     "us_per_call": usw1, "shards": 1,
                     "rows_per_s": W / usw1 * 1e6})
     for n_dev in (2, 4, 8):
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = make_mesh((n_dev,), ("data",))
         sbase = base.shard(mesh, ("data",))
         with mesh:
             fws = jax.jit(lambda st, v, l: (st.write(v, l).values,))
@@ -147,7 +148,9 @@ _WORKER = """
 
 
 def run():
-    env = dict(os.environ)
+    # the child measures a forced host mesh by design: pin it to the CPU so
+    # it never waits for an accelerator the parent process may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEVICES}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(_WORKER)],

@@ -40,7 +40,9 @@ def run_one(arch, shape, mesh, results_dir, retrieval=False, timeout=3600):
     if retrieval:
         cmd.append("--retrieval")
     t0 = time.time()
-    env = dict(os.environ)
+    # the dry-run lowers for a forced 512-device host platform: pin the
+    # child to the CPU so it never contends for an attached accelerator
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout, env=env)

@@ -24,13 +24,15 @@ repro/kernels/shortlist.py).
 
 from __future__ import annotations
 
+import re
+
 # Cross-device collectives that must never appear in a shard-local write
 # (store._program_streamed) or an unsharded search.
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "all-to-all",
                   "collective-permute")
 
 # Every spelling XLA uses for a scatter once it reaches HLO: the op itself
-# and the dynamic-update-slice it expands to on CPU.
+# and the dynamic-update-slice some backends expand it to.
 SCATTER_SPELLINGS = ("scatter(", "dynamic-update-slice")
 
 # jax.named_scope tag wrapping the read-time string layout
@@ -65,6 +67,27 @@ def matched_lines(hlo: str, needles) -> list[str]:
     return out
 
 
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE_TOKEN = re.compile(r"\w+")
+
+
+def scoped_lines(hlo: str, tag: str) -> list[str]:
+    """HLO lines whose op_name scope path names `tag` as a component
+    (`jit(f)/layout_support/mul`, also inside AD wrappers such as
+    `jvp(layout_support)`). Matching components, not substrings, keeps
+    other metadata -- e.g. the table of Python function names the HLO
+    text carries -- from tripping a check."""
+    out, seen = [], set()
+    for line in hlo.splitlines():
+        m = _OP_NAME.search(line)
+        if m and tag in _SCOPE_TOKEN.findall(m.group(1)):
+            s = line.strip()
+            if s not in seen:
+                seen.add(s)
+                out.append(s)
+    return out
+
+
 # -- checkers: [] == invariant holds ----------------------------------------
 
 
@@ -80,23 +103,24 @@ def check_no_scatter_any_spelling(hlo: str) -> list[str]:
 
 def check_scatter_write(hlo: str) -> list[str]:
     """The single-shard / unsharded write DID take the scatter fast path
-    (control direction: dynamic-update-slice present)."""
-    if matched_lines(hlo, ("dynamic-update-slice",)):
+    (control direction: a scatter, in either spelling, is present --
+    XLA:CPU used to expand it to dynamic-update-slice and now keeps it)."""
+    if matched_lines(hlo, SCATTER_SPELLINGS):
         return []
-    return ["expected a dynamic-update-slice (scatter write path) "
-            "but the compiled program contains none"]
+    return ["expected a scatter (scatter write path) but the compiled "
+            "program contains none"]
 
 
 def check_no_layout_ops(hlo: str) -> list[str]:
     """Store-based searches jit against write-time grids: the read-time
     `layout_support` scope tag must not appear."""
-    return matched_lines(hlo, (LAYOUT_SCOPE_TAG,))
+    return scoped_lines(hlo, LAYOUT_SCOPE_TAG)
 
 
 def check_layout_ops_present(hlo: str) -> list[str]:
     """Control direction: the raw-array path DOES lay the store out under
     jit, proving the scope tag is visible in this build's HLO text."""
-    if matched_lines(hlo, (LAYOUT_SCOPE_TAG,)):
+    if scoped_lines(hlo, LAYOUT_SCOPE_TAG):
         return []
     return [f"expected the {LAYOUT_SCOPE_TAG!r} scope tag (read-time "
             f"layout) but the compiled program contains none"]
@@ -105,7 +129,7 @@ def check_layout_ops_present(hlo: str) -> list[str]:
 def check_fused_tag(hlo: str, expected: bool) -> list[str]:
     """The `shortlist_fused` scope tag appears iff the dispatch rule
     (repro/engine/sharded._use_fused) says the fused kernel engages."""
-    lines = matched_lines(hlo, (FUSED_SCOPE_TAG,))
+    lines = scoped_lines(hlo, FUSED_SCOPE_TAG)
     if expected and not lines:
         return [f"dispatch rule says the fused shortlist engages but the "
                 f"{FUSED_SCOPE_TAG!r} tag is absent from the compiled HLO"]
@@ -118,7 +142,7 @@ def check_router_tag(hlo: str, expected: bool) -> list[str]:
     """The `router_sketch` scope tag appears iff routing is engaged
     (`SearchRequest.nprobe < store.n_shards`) -- exhaustive searches must
     not pay the sketch matmul, routed ones must go through it."""
-    lines = matched_lines(hlo, (ROUTER_SCOPE_TAG,))
+    lines = scoped_lines(hlo, ROUTER_SCOPE_TAG)
     if expected and not lines:
         return [f"nprobe < n_shards engages the router but the "
                 f"{ROUTER_SCOPE_TAG!r} tag is absent from the compiled HLO"]

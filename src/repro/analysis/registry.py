@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.analysis import cost as cost_lib
 from repro.analysis import hlo_contracts as hc
+from repro.launch.mesh import make_mesh
 
 #: k used by every search cell (small, so cells compile in milliseconds).
 CELL_K = 16
@@ -218,7 +219,7 @@ def _search_cell(mode: str, backend: str, fmr: int, packed: bool,
         store, qv = fx["store"], fx["qv"]
         mesh = None
         if sharded:
-            mesh = jax.make_mesh((n_shards,), ("data",))
+            mesh = make_mesh((n_shards,), ("data",))
             store = store.shard(mesh, ("data",))
         if not packed:
             store = _unpacked(store)
@@ -394,7 +395,7 @@ def _write_cell(kind: str, n_shards: int) -> Cell:
         fx = _fix()
         wstore, vecs, labs = fx["wstore"], fx["wvecs"], fx["wlabs"]
         if kind != "unsharded":
-            mesh = jax.make_mesh((n_shards,), ("data",))
+            mesh = make_mesh((n_shards,), ("data",))
             wstore = wstore.shard(mesh, ("data",))
         compiled = _compile(lambda st, v, l: st.write(v, l),
                             wstore, vecs, labs)

@@ -72,6 +72,9 @@ class MCAMConfig:
 
 _M1 = np.uint32(0x7FEB352D)
 _M2 = np.uint32(0x846CA68B)
+_GOLDEN = 0x9E3779B9
+#: seed offset of the second uniform stream of `hash_normal` (Box-Muller)
+NORMAL_PAIR_OFFSET = 0x5BD1
 
 
 def _mix(x: jax.Array) -> jax.Array:
@@ -84,26 +87,67 @@ def _mix(x: jax.Array) -> jax.Array:
     return x
 
 
+def hash_init(seed: int) -> np.uint32:
+    """Initial state of the counter hash for one noise stream."""
+    return np.uint32((seed * _GOLDEN + 0x85EBCA6B) & 0xFFFFFFFF)
+
+
+def hash_fold(h: jax.Array, i: jax.Array, k: int) -> jax.Array:
+    """Fold the k-th integer coordinate `i` into hash state `h`.
+
+    Split out of `hash_uniform` so a kernel can fold the coordinates it
+    holds fixed (query, string) once and the innermost one (cell) per
+    cell -- integer-exact, so the result equals the one-shot hash."""
+    step = np.uint32(((k + 1) * _GOLDEN) & 0xFFFFFFFF)
+    return _mix((jnp.asarray(i).astype(jnp.uint32) + step) ^ h)
+
+
+def hash_to_uniform(h: jax.Array) -> jax.Array:
+    """uint32 hash state -> uniform in (0, 1), exactly representable.
+
+    The top 23 bits go through int32 (a same-width bitcast, then an
+    int32 -> f32 conversion): both lower in XLA and in Mosaic, which has
+    no uint32 -> f32 conversion, and (j + 0.5) * 2**-23 is exact in f32,
+    so u never rounds to 0 or 1."""
+    j = jax.lax.bitcast_convert_type(h >> 9, jnp.int32)
+    return (j.astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -23)
+
+
+def box_muller(u1: jax.Array, u2: jax.Array) -> jax.Array:
+    """Standard normal from two independent uniforms in (0, 1)."""
+    r = jnp.sqrt(-2.0 * jnp.log(u1))
+    return r * jnp.cos(2.0 * jnp.float32(np.pi) * u2)
+
+
 def hash_uniform(*idx: jax.Array, seed: int) -> jax.Array:
     """Deterministic uniform(0,1) from integer coordinates (broadcasting)."""
-    h = jnp.uint32(seed) * jnp.uint32(0x9E3779B9) + jnp.uint32(0x85EBCA6B)
+    h = jnp.asarray(hash_init(seed))
     for k, i in enumerate(idx):
-        step = jnp.uint32(k + 1) * jnp.uint32(0x9E3779B9)
-        h = _mix(h ^ (jnp.asarray(i).astype(jnp.uint32) + step))
-    return (h.astype(jnp.float32) + 0.5) * jnp.float32(1.0 / 4294967296.0)
+        h = hash_fold(h, i, k)
+    return hash_to_uniform(h)
 
 
 def hash_normal(*idx: jax.Array, seed: int) -> jax.Array:
     """Deterministic standard normal via Box-Muller over two hash streams."""
-    u1 = hash_uniform(*idx, seed=seed)
-    u2 = hash_uniform(*idx, seed=seed + 0x5BD1)
-    r = jnp.sqrt(-2.0 * jnp.log(u1))
-    return r * jnp.cos(2.0 * jnp.float32(np.pi) * u2)
+    return box_muller(hash_uniform(*idx, seed=seed),
+                      hash_uniform(*idx, seed=seed + NORMAL_PAIR_OFFSET))
 
 
 # ---------------------------------------------------------------------------
 # String current + sense amplifier.
 # ---------------------------------------------------------------------------
+
+
+def noisy_mismatch(m: jax.Array, device_noise: jax.Array,
+                   cfg: MCAMConfig) -> jax.Array:
+    """Effective mismatch exponent of a cell under device variation."""
+    return jnp.clip(m + cfg.sigma_device * device_noise, 0.0,
+                    float(MAX_MISMATCH))
+
+
+def cell_resistance(m: jax.Array, cfg: MCAMConfig) -> jax.Array:
+    """Series resistance rho**m of cells at (effective) mismatch m."""
+    return jnp.exp(m * jnp.float32(np.log(cfg.rho)))
 
 
 def string_resistance(cell_mismatch: jax.Array, cfg: MCAMConfig,
@@ -112,12 +156,20 @@ def string_resistance(cell_mismatch: jax.Array, cfg: MCAMConfig,
 
     cell_mismatch: (..., cells) float or int mismatch levels in [0, 3].
     device_noise:  optional (..., cells) standard-normal perturbations.
+
+    The sum is a left fold in cell order, written out: a float reduction
+    is free to reorder, and the search kernel (kernels/mcam_search.py)
+    accumulates its cells in exactly this order, so the two agree bit for
+    bit on every compiler.
     """
     m = cell_mismatch.astype(jnp.float32)
     if device_noise is not None:
-        m = m + cfg.sigma_device * device_noise
-        m = jnp.clip(m, 0.0, float(MAX_MISMATCH))
-    return jnp.power(jnp.float32(cfg.rho), m).sum(-1)
+        m = noisy_mismatch(m, device_noise, cfg)
+    r = cell_resistance(m, cfg)
+    total = r[..., 0]
+    for c in range(1, r.shape[-1]):
+        total = total + r[..., c]
+    return total
 
 
 def current_from_resistance(r_sum: jax.Array, n_cells: int, cfg: MCAMConfig,

@@ -22,12 +22,11 @@ KERNEL_BACKENDS = ("pallas", "mxu", "fused")
 
 @functools.cache
 def kernels_available() -> bool:
-    """True when the Pallas kernel package imports (optional dependency)."""
-    try:
-        from repro.kernels import ops  # noqa: F401
-        return True
-    except Exception:
-        return False
+    """True once the Pallas kernel package imports. The kernels ship with
+    the engine, so an import failure is a broken install: it raises rather
+    than quietly serving every search from the `ref` backend."""
+    from repro.kernels import ops  # noqa: F401
+    return True
 
 
 def resolve_backend(backend: str = "auto", use_kernel: str = "auto") -> str:
@@ -43,4 +42,4 @@ def resolve_backend(backend: str = "auto", use_kernel: str = "auto") -> str:
                     f"unknown backend {choice!r}; expected one of "
                     f"{BACKENDS + ('auto',)}")
             return choice
-    return "pallas" if kernels_available() else "ref"
+    return "pallas"

@@ -155,7 +155,6 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
     RetrievalEngine.two_phase(q, s, k, valid) on a single device,
     whichever shortlist path engages.
     """
-    from jax.experimental.shard_map import shard_map
     from repro.core import avss as avss_lib
     from repro.kernels import ops as kernel_ops
 
@@ -254,11 +253,11 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
             outs = outs + (take(l_all),)
         return outs
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axes), P(axes), *extra_specs),
         out_specs=(P(),) * (3 + (labels is not None)),
-        check_rep=False,
+        check_vma=False,
     )(q1h, q_grid, s_values, valid, *extras)
     res = {"votes": out[0], "dist": out[1], "indices": out[2],
            "iterations": avss_lib.search_iterations(
@@ -293,7 +292,6 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
     Collective volume is O(B * k * shards), independent of capacity.
     Returns {dist, votes=-dist, labels, indices} each (B, k').
     """
-    from jax.experimental.shard_map import shard_map
 
     rows_loc = proj.shape[0] // int(np.prod([mesh.shape[a] for a in axes]))
     fused = _use_fused(backend, rows_loc, fused_min_rows)
@@ -324,11 +322,11 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
         take = lambda x: jnp.take_along_axis(x, order, axis=1)
         return take(d_all), take(l_all), take(i_all)
 
-    dist, labels_out, indices = shard_map(
+    dist, labels_out, indices = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axes), P(axes), *extra_specs),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(q_onehot, proj, labels, *extras)
     return {"dist": dist, "labels": labels_out, "votes": -dist,
             "indices": indices}

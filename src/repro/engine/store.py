@@ -63,7 +63,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import avss as avss_lib
@@ -79,13 +79,11 @@ _DATA_FIELDS = ["values", "proj", "proj_packed", "s_grid", "labels",
                 "size", "lo", "hi", "sketch_sums", "sketch_counts"]
 
 
-def _host_device() -> jax.Device | None:
-    """The host (CPU) device for `residency="host"` placement, or None
-    when jax exposes no CPU backend."""
-    try:
-        return jax.local_devices(backend="cpu")[0]
-    except RuntimeError:
-        return None
+def _host_device() -> jax.Device:
+    """The host (CPU) device for `residency="host"` placement. Raises when
+    jax exposes no CPU backend: a host-resident store that silently stayed
+    on the device would defeat the point of paging it."""
+    return jax.local_devices(backend="cpu")[0]
 
 
 def _quantize(x: jax.Array, levels: int, lo: jax.Array,
@@ -159,14 +157,19 @@ class MemoryStore:
         ragged-pad rows, empty slots, and the unsharded search."""
         enc = cfg.search.enc
         zeros = jnp.zeros((cfg.capacity, cfg.dim), jnp.int32)
-        proj = kernel_ops.support_projection(zeros, enc)
+        # every slot holds the same row, so lay out one and broadcast it:
+        # the layouts' lane-padded temporaries scale with the rows laid
+        # out (24 GiB for a 1M-row store on a TPU)
+        row = zeros[:1]
+        fill = lambda a: jnp.broadcast_to(a, (cfg.capacity,) + a.shape[1:])
+        proj = kernel_ops.support_projection(row, enc)
         labels = jnp.full((cfg.capacity,), -1, jnp.int32)
         sk_sums, sk_counts = router_lib.build_sketch(zeros, labels, 1)
         return cls(
             values=zeros,
-            proj=proj,
-            proj_packed=kernel_ops.pack_projection(proj, enc),
-            s_grid=_layout(zeros, cfg),
+            proj=fill(proj),
+            proj_packed=fill(kernel_ops.pack_projection(proj, enc)),
+            s_grid=fill(_layout(row, cfg)),
             labels=labels,
             size=jnp.zeros((), jnp.int32),
             lo=jnp.zeros((), jnp.float32),
@@ -436,8 +439,6 @@ class MemoryStore:
         and ragged pad rows (global row >= cfg.capacity) are never written.
         Compiled HLO carries no all-gather/all-to-all/scatter collectives
         (asserted in tests/test_store.py)."""
-        from jax.experimental.shard_map import shard_map
-
         from repro.engine.sharded import _shard_index
 
         mesh, axes = self.mesh, self.axes
@@ -481,11 +482,11 @@ class MemoryStore:
                     sel(grid_, grid_loc), new_labs,
                     sk_sums[None], sk_counts[None])
 
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(),) * 6 + (P(axes),) * 5,
             out_specs=(P(axes),) * 7,
-            check_rep=False,
+            check_vma=False,
         )(start, *batch, self.values, self.proj, self.proj_packed,
           self.s_grid, self.labels)
         return dataclasses.replace(
@@ -556,6 +557,13 @@ class MemoryStore:
                     "resident; residency='host' applies to logical "
                     "partitions (shard(n_shards=S, residency='host')) "
                     "paged by engine/pager.ShardPager")
+            if any(t != AxisType.Auto for t in mesh.axis_types):
+                raise ValueError(
+                    "MemoryStore.shard: the mesh needs Auto axes (build it "
+                    "with repro.launch.mesh.make_mesh); jax.make_mesh "
+                    "defaults to Explicit axes, under which the store's "
+                    "writes and sketch updates have no unambiguous output "
+                    "sharding")
             axes = tuple(axes)
             n_shards = int(np.prod([mesh.shape[a] for a in axes]))
         elif n_shards is None or n_shards < 1:
@@ -571,10 +579,9 @@ class MemoryStore:
         if mesh is None:
             if residency == "host":
                 dev = _host_device()
-                if dev is not None:
-                    moved = {f: jax.device_put(getattr(store, f), dev)
-                             for f in _DATA_FIELDS}
-                    store = dataclasses.replace(store, **moved)
+                moved = {f: jax.device_put(getattr(store, f), dev)
+                         for f in _DATA_FIELDS}
+                store = dataclasses.replace(store, **moved)
             return dataclasses.replace(store, mesh=None, axes=(),
                                        residency=residency)
         row = NamedSharding(mesh, P(axes))

@@ -217,20 +217,15 @@ def rescore_shortlist(q_grid: jax.Array, s_grid: jax.Array,
                  + jnp.arange(S, dtype=jnp.uint32)[None, None, :])
     b_idx = noise_qidx.astype(jnp.uint32)[:, None, None]
     mc = cfg.mcam
+    dev = rd = None
     if cfg.noisy:
         cell = jnp.arange(sl, dtype=jnp.uint32)
         dev = mcam_lib.hash_normal(b_idx[..., None], string_id[..., None],
                                    cell, seed=mc.seed)
-        m_eff = jnp.clip(m + mc.sigma_device * dev, 0.0,
-                         float(enc_lib.MAX_MISMATCH))
-    else:
-        m_eff = m
-    r = jnp.exp(m_eff * jnp.float32(np.log(mc.rho))).sum(-1)
-    cur = jnp.float32(sl) / r
-    if cfg.noisy:
         rd = mcam_lib.hash_normal(b_idx, string_id,
                                   seed=mc.seed + ref.READ_SEED_OFFSET)
-        cur = cur * (1.0 + mc.sigma_read * rd)
+    r = mcam_lib.string_resistance(m, mc, device_noise=dev)
+    cur = mcam_lib.current_from_resistance(r, sl, mc, read_noise=rd)
     v = (cur[..., None] > thresholds).sum(-1).astype(jnp.float32)
     seg = s_grid.shape[1]
     w_flat = jnp.tile(weights.astype(jnp.float32), seg)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import mcam as mcam_lib
-from repro.core.encodings import MAX_MISMATCH
 from repro.core.mcam import MCAMConfig
 
 READ_SEED_OFFSET = 0x2C1B
@@ -49,18 +48,14 @@ def mcam_search_ref(q_strings: jax.Array, s_strings: jax.Array,
         qs, b = args                                                # (S, sl)
         m = jnp.abs(qs[None].astype(jnp.int32)
                     - s_strings.astype(jnp.int32)).astype(jnp.float32)
+        dev = rd = None
         if noisy:
             dev = mcam_lib.hash_normal(
                 b, string_id[..., None], cell[None, None, :], seed=cfg.seed)
-            m_eff = jnp.clip(m + cfg.sigma_device * dev, 0.0, float(MAX_MISMATCH))
-        else:
-            m_eff = m
-        r = jnp.exp(m_eff * jnp.float32(jnp.log(cfg.rho))).sum(-1)  # (N, S)
-        cur = jnp.float32(sl) / r
-        if noisy:
             rd = mcam_lib.hash_normal(b, string_id,
                                       seed=cfg.seed + READ_SEED_OFFSET)
-            cur = cur * (1.0 + cfg.sigma_read * rd)
+        r = mcam_lib.string_resistance(m, cfg, device_noise=dev)   # (N, S)
+        cur = mcam_lib.current_from_resistance(r, sl, cfg, read_noise=rd)
         v = (cur[..., None] > thresholds).sum(-1).astype(jnp.float32)
         votes = (v * weights[None, :]).sum(-1)                      # (N,)
         dist = (m.sum(-1) * weights[None, :]).sum(-1)

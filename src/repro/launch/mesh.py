@@ -2,12 +2,27 @@
 
 Functions (never module-level constants) so importing this module touches no
 jax device state -- the dry-run must set XLA_FLAGS before first jax init.
+
+Every mesh built here has `AxisType.Auto` axes: the models, the store and
+the sharded search are written for compiler-propagated shardings, while
+`jax.make_mesh` on its own now defaults to Explicit axes.
 """
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
 import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Sequence[jax.Device] | None = None) -> Mesh:
+    """`jax.make_mesh` with every axis `AxisType.Auto`."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +37,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} -- set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (see launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -30,5 +45,5 @@ def make_host_mesh(model_parallel: int = 1):
     devices = jax.devices()
     n = len(devices)
     mp = max(1, min(model_parallel, n))
-    return jax.make_mesh((n // mp, mp), ("data", "model"),
-                         devices=devices[: (n // mp) * mp])
+    return make_mesh((n // mp, mp), ("data", "model"),
+                     devices=devices[: (n // mp) * mp])

@@ -5,6 +5,8 @@ serve_step for the production meshes).
     PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b --smoke \
         --batch 4 --steps 16 [--retrieval]
 
+`--no-smoke` serves the published config at its full widths.
+
 Multi-tenant retrieval serving (PR 9): `TenantServer` below is the minimal
 coalescing shell over `RetrievalEngine.search_tenants` -- concurrent
 per-tenant queries accumulate into one device batch, run as ONE compiled
@@ -26,6 +28,7 @@ import numpy as np
 
 from repro.configs import load_config
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.models.sharding import Rules
 
@@ -138,10 +141,15 @@ def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
           retrieval_backend: str = "auto", retrieval_k: int = 32,
           retrieval_fused_min_rows: int | None = None,
           retrieval_shards: int | None = None,
-          retrieval_nprobe: int | None = None):
+          retrieval_nprobe: int | None = None) -> dict:
+    """Decode `steps` tokens for `batch` requests after a `prompt_len`
+    random prompt. Returns the tokens (batch, steps), `compile_s` (the
+    first step, compile included, to its result) and `decode_s` (the
+    decode loop, which reads every token back to the host)."""
     cfg = load_config(arch, smoke=smoke)
     rules = Rules(batch=(), fsdp=(), tensor=(), expert=())
-    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    # jitted so a published-width init never materialises its f32 draws
+    params = jax.jit(partial(tfm.init, cfg=cfg))(jax.random.PRNGKey(0))
     max_seq = prompt_len + steps
     caches = tfm.init_cache(cfg, batch, max_seq)
     step_fn = jax.jit(steps_lib.make_serve_step(cfg, rules))
@@ -179,13 +187,18 @@ def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
 
     key = jax.random.PRNGKey(1)
     tok = jax.random.randint(key, (batch, 1), 0, cfg.vocab_size)
+    compile_s = 0.0
     for t in range(prompt_len):  # warm the cache with a random prompt
+        t0 = time.perf_counter()
         args = (params, caches, {"tokens": tok}, jnp.int32(t))
         out = step_fn(*args, mstate) if retrieval else step_fn(*args)
         logits, caches = out
+        if t == 0:                                # the first step compiles
+            jax.block_until_ready(logits)
+            compile_s = time.perf_counter() - t0
         tok = jax.random.randint(jax.random.fold_in(key, t), (batch, 1), 0,
                                  cfg.vocab_size)
-    t0 = time.time()
+    t0 = time.perf_counter()
     toks = []
     for i in range(steps):
         args = (params, caches, {"tokens": tok}, jnp.int32(prompt_len + i))
@@ -193,17 +206,22 @@ def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
         logits, caches = out
         tok = jnp.argmax(logits[:, 0], -1)[:, None]
         toks.append(np.asarray(tok))
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     assert np.isfinite(np.asarray(logits, np.float32)).all()
-    print(f"{arch}: {steps} steps x {batch} reqs in {dt:.2f}s "
+    print(f"{arch}: first step {compile_s:.2f}s (compile included); "
+          f"{steps} steps x {batch} reqs in {dt:.2f}s "
           f"({steps * batch / dt:.1f} tok/s)")
-    return np.concatenate(toks, 1)
+    return {"tokens": np.concatenate(toks, 1), "compile_s": compile_s,
+            "decode_s": dt}
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (default); --no-smoke serves the "
+                         "published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -239,6 +257,7 @@ def main(argv=None):
                          "transformer decode loop (TenantServer coalescing "
                          "shell over RetrievalEngine.search_tenants)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.tenants is not None:
         serve_tenants(args.tenants, args.steps, args.batch,
                       backend=args.retrieval_backend, k=args.retrieval_k)

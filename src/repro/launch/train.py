@@ -38,6 +38,7 @@ from repro.configs import load_config
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.data.lm import LMDataConfig, SyntheticLM, embedding_batch_for_step
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tfm
 from repro.models.sharding import active_mesh, rules_for_mesh
@@ -258,6 +259,7 @@ def main(argv=None):
     ap.add_argument("--hat-k-shot", type=int, default=3)
     ap.add_argument("--hat-eval-episodes", type=int, default=3)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.hat:
         out = train_hat(args.hat_pretrain_steps, args.hat_meta_steps,
                         args.hat_n_way, args.hat_k_shot,

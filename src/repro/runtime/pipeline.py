@@ -74,10 +74,9 @@ def pipeline_apply(stage_fn, params_stacked, x_microbatches, mesh: Mesh,
                 axis)
         return outs
 
-    from jax.experimental.shard_map import shard_map
     spec_p = jax.tree_util.tree_map(lambda _: P(axis), params_stacked)
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(params_stacked, x_microbatches)

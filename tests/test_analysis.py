@@ -53,14 +53,20 @@ def test_checkers_catch_injected_violations():
     ok = CLEAN_HLO + "  dus.1 = f32[8] dynamic-update-slice(a, b, i)\n"
     assert hc.check_scatter_write(ok) == []
 
-    tagged = CLEAN_HLO + "  f.2 = f32[5] fusion(x), name=\"shortlist_fused\"\n"
+    # scope checks read op_name path components, as compiled HLO spells
+    # them; a function name that merely CONTAINS a tag is not a scope
+    tagged = CLEAN_HLO + ("  f.2 = f32[5] fusion(x), metadata={op_name="
+                          "\"jit(search)/shortlist_fused/dot_general\"}\n")
     assert hc.check_fused_tag(tagged, True) == []
     assert hc.check_fused_tag(tagged, False)
     assert hc.check_fused_tag(CLEAN_HLO, False) == []
     assert hc.check_fused_tag(CLEAN_HLO, True)
 
-    layout = CLEAN_HLO + "  l.1 = s8[4] copy(x), name=\"layout_support\"\n"
+    layout = CLEAN_HLO + ("  l.1 = s8[4] copy(x), metadata={op_name="
+                          "\"jit(f)/jvp(layout_support)/copy\"}\n")
+    named = CLEAN_HLO + '  5 "test_search_compiles_without_layout_support"\n'
     assert hc.check_no_layout_ops(CLEAN_HLO) == []
+    assert hc.check_no_layout_ops(named) == []
     assert hc.check_no_layout_ops(layout)
     assert hc.check_layout_ops_present(layout) == []
     assert hc.check_layout_ops_present(CLEAN_HLO)

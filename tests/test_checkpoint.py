@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, latest_step, restore, save
+from repro.launch.mesh import make_mesh
 
 
 def _tree(key=0):
@@ -64,7 +65,7 @@ def test_elastic_restore_onto_sharding(tmp_path):
     """Save unsharded, restore onto a mesh sharding (elastic restart)."""
     t = _tree()
     save(str(tmp_path), 5, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     shardings = jax.tree_util.tree_map(
         lambda a: NamedSharding(mesh, P()), t)

@@ -20,6 +20,7 @@ from repro.core import memory as mem
 from repro.core.avss import SearchConfig
 from repro.core.memory import MemoryConfig
 from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture()
@@ -75,7 +76,7 @@ def test_search_shim_warns_once_and_is_bit_identical(toy):
 
 def test_distributed_shim_warns_once_and_is_bit_identical(toy):
     cfg, state, q = toy
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     mem._WARNED.discard("distributed_search")
     f_old = jax.jit(lambda s, qq: mem.distributed_search(
         s, qq, cfg, mesh, axes=("data",), k=4))

@@ -36,8 +36,9 @@ def test_train_step_on_multi_device_mesh():
         from repro.models import transformer as tfm
         from repro.models.sharding import rules_for_mesh, active_mesh
         from repro.launch.dryrun import _with_shardings
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = rules_for_mesh(mesh)
         cfg = load_config("starcoder2-3b", smoke=True)
         tc = TrainConfig(learning_rate=1e-3)
@@ -69,7 +70,7 @@ def test_dryrun_cell_small_mesh():
         import repro.launch.mesh as mesh_lib
         # shrink the production mesh for the 8-device test environment
         mesh_lib.make_production_mesh = (
-            lambda multi_pod=False: jax.make_mesh(
+            lambda multi_pod=False: mesh_lib.make_mesh(
                 (2, 2, 2) if multi_pod else (4, 2),
                 ("pod", "data", "model") if multi_pod else ("data", "model")))
         import repro.launch.dryrun as dr
@@ -96,9 +97,10 @@ def test_dryrun_cell_small_mesh():
 def test_pipeline_parallel_matches_sequential():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.runtime.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         S, M, mb, d = 4, 6, 3, 8
         keys = jax.random.split(jax.random.PRNGKey(0), S)
         Ws = jnp.stack([jax.random.normal(k, (d, d)) / np.sqrt(d)
@@ -124,7 +126,8 @@ def test_elastic_checkpoint_across_mesh_shapes():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         w = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(16, 4),
                            NamedSharding(mesh, P("data", None)))
         save({tmp!r}, 1, {{"w": w}})
@@ -134,7 +137,8 @@ def test_elastic_checkpoint_across_mesh_shapes():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import restore
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         sh = {{"w": NamedSharding(mesh, P("data", None))}}
         out = restore({tmp!r}, {{"w": jax.ShapeDtypeStruct((16, 4),
                                                            jnp.float32)}},

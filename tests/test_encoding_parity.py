@@ -16,6 +16,7 @@ import pytest
 from repro.core.avss import SearchConfig
 from repro.core.encodings import CELL_STATES, make_encoding
 from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+from repro.launch.mesh import make_mesh
 
 # (name, cl, paper-Table-1 levels, words per dimension)
 TABLE1 = [
@@ -79,7 +80,7 @@ def test_sharded_store_bit_identical_per_encoding(name, cl):
     subprocess sweep lives in tests/test_engine.py) serves every encoding
     bit-identically to the unsharded search, ref and fused shortlists."""
     cfg, store, qv = _store_and_queries(name, cl)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sharded = store.shard(mesh)
     for backend in ("ref", "fused"):
         req = SearchRequest(mode="two_phase", k=12, backend=backend)

@@ -23,6 +23,7 @@ from repro.core import avss as avss_lib
 from repro.core.avss import SearchConfig
 from repro.core.mcam import MCAMConfig
 from repro.engine import RetrievalEngine, resolve_backend
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -347,7 +348,7 @@ def test_sharded_fused_shortlist_matches_dense_and_unsharded():
                        jnp.arange(72)).astype(jnp.int32)   # 18 masked rows
     store = MemoryStore.from_quantized(sv, labels, cfg)
     qv = jax.random.randint(jax.random.PRNGKey(1), (5, 20), 0, 4)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sstore = store.shard(mesh, ("data",))
     eng = RetrievalEngine(cfg, backend="mxu")
     for mode in ("ideal", "two_phase"):
@@ -422,6 +423,7 @@ def test_sharded_fused_8dev_ragged_bit_identical():
     inside the merged top-k."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.analysis import hlo_contracts as hc
         from repro.core.avss import SearchConfig
         from repro.core.memory import MemoryConfig
@@ -435,7 +437,7 @@ def test_sharded_fused_8dev_ragged_bit_identical():
         labs = jnp.arange(30, dtype=jnp.int32) % 7
         store = MemoryStore.create(cfg).calibrate(vecs).write(vecs, labs)
         q = vecs[:6] + 0.02
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         sstore = store.shard(mesh, ("data",))
         assert sstore.capacity == 104, sstore.capacity  # ragged: 13/shard
         eng = RetrievalEngine(cfg.search, backend="mxu")
@@ -535,6 +537,7 @@ def test_two_phase_recall_at_k_is_one(key):
 def test_sharded_two_phase_bit_identical():
     code = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.avss import SearchConfig
         from repro.core.mcam import MCAMConfig
@@ -550,7 +553,7 @@ def test_sharded_two_phase_bit_identical():
         tp = eng.two_phase(qv, sv, k=48)
         for shape, axes in [((8,), ("data",)),
                             ((4, 2), ("data", "model"))]:
-            mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
+            mesh = make_mesh(shape, ("data", "model")[:len(shape)])
             svs = jax.device_put(sv, NamedSharding(mesh, P(axes)))
             with mesh:
                 sh = eng.sharded_two_phase(qv, svs, mesh, axes=axes, k=48)
@@ -563,7 +566,7 @@ def test_sharded_two_phase_bit_identical():
         # is the ONLY thing keeping shards in agreement
         sv2 = jnp.concatenate([sv[:32]] * 8, 0)
         tp2 = eng.two_phase(qv, sv2, k=40)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         svs2 = jax.device_put(sv2, NamedSharding(mesh, P("data")))
         with mesh:
             sh2 = eng.sharded_two_phase(qv, svs2, mesh, axes=("data",),
@@ -598,7 +601,7 @@ def test_sharded_two_phase_bit_identical():
         queries = vecs[:5] + 0.05 * jax.random.normal(
             jax.random.PRNGKey(6), (5, 24))
         local = mem.search(state, queries, mcfg, two_phase=True, k=16)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         sstate = mem.shard_state(state, mesh, ("data",))
         with mesh:
             dist = mem.distributed_search(sstate, queries, mcfg, mesh,
@@ -633,7 +636,7 @@ def test_sharded_two_phase_bit_identical():
                                       np.asarray(new_local.votes))
         np.testing.assert_array_equal(np.asarray(pre_labels),
                                       np.asarray(new_local.labels))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rsharded = rstore.shard(mesh, ("data",))
         assert rsharded.capacity == 104, rsharded.capacity
         with mesh:

@@ -8,6 +8,7 @@ import pytest
 from repro.core import memory as mem
 from repro.core.avss import SearchConfig
 from repro.core.memory import MemoryConfig
+from repro.launch.mesh import make_mesh
 
 # Legacy-API suite: the deprecation shims legitimately fire here, so the
 # suite-wide promotion to errors (tests/conftest.py) is scoped back.
@@ -80,7 +81,7 @@ def test_ring_buffer_overwrite():
 @pytest.mark.slow
 def test_distributed_search_matches_local():
     cfg, state, centers = _toy_memory(dim=24)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sstate = mem.shard_state(state, mesh, ("data", "model"))
     q = centers + 0.05 * jax.random.normal(jax.random.PRNGKey(5),
                                            centers.shape)

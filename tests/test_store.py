@@ -25,6 +25,7 @@ from repro.core.memory import MemoryConfig
 from repro.engine import (MemoryStore, RetrievalEngine, SearchRequest,
                           SearchResult)
 from repro.kernels import ops as kernel_ops
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -238,7 +239,7 @@ def test_empty_store_predicts_sentinel_every_mode_and_backend():
             assert (np.asarray(res.predict()) == -1).all(), (mode, backend)
             assert np.isneginf(np.asarray(res.votes)).all(), (mode, backend)
     # sharded dispatch (two_phase + ideal go through shard_map)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sstore = store.shard(mesh, ("data",))
     eng = RetrievalEngine(cfg.search)
     for mode in ("two_phase", "ideal"):
@@ -266,6 +267,17 @@ def test_request_backend_override_engine_is_cached():
 # ---------------------------------------------------------------------------
 
 
+def test_shard_refuses_explicit_axis_mesh():
+    """jax.make_mesh defaults to Explicit axes; the store's auto-sharded
+    writes need Auto ones, so shard() says so instead of failing later."""
+    cfg = MemoryConfig(capacity=8, dim=4, search=SearchConfig(
+        "mtmc", cl=4, mode="avss", use_kernel="ref"))
+    store = MemoryStore.create(cfg)
+    with pytest.raises(ValueError, match="Auto axes"):
+        store.shard(jax.make_mesh((1,), ("data",)))
+    assert store.shard(make_mesh((1,), ("data",))).n_shards == 1
+
+
 def test_single_shard_write_dispatches_to_scatter():
     """A 1-shard mesh gives the shard_map write-through nothing to
     parallelise: there is no collective to avoid, and its per-row ring
@@ -279,7 +291,7 @@ def test_single_shard_write_dispatches_to_scatter():
     vecs = jax.random.normal(jax.random.PRNGKey(0), (22, 8))
     labs = jnp.arange(22, dtype=jnp.int32)
     base = MemoryStore.create(cfg).calibrate(vecs)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sstore = base.shard(mesh, ("data",))
     assert sstore.n_shards == 1 and base.n_shards == 1
     f = jax.jit(lambda st, v, l: st.write(v, l))
@@ -325,6 +337,7 @@ def test_streaming_write_8dev_no_collectives_ragged_wraparound():
     mesh) idempotency fix and the fully-pad-row predict() sentinel."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import Mesh
         from repro.analysis import hlo_contracts as hc
         from repro.core.avss import SearchConfig
@@ -338,7 +351,7 @@ def test_streaming_write_8dev_no_collectives_ragged_wraparound():
         vecs = jax.random.normal(jax.random.PRNGKey(0), (130, 24))
         labs = jnp.arange(130, dtype=jnp.int32) % 9
         base = MemoryStore.create(cfg).calibrate(vecs)
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
 
         # (a) compiled write HLO: no collectives, no scatter of any form
         sstore = base.shard(mesh8, ("data",))
@@ -427,6 +440,7 @@ def test_ragged_3way_split_capacity_100():
     labels bit-identical to the unsharded search."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.avss import SearchConfig
         from repro.core.memory import MemoryConfig
         from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
@@ -440,7 +454,7 @@ def test_ragged_3way_split_capacity_100():
         q = vecs[:6] + 0.05 * jax.random.normal(jax.random.PRNGKey(1),
                                                 (6, 24))
         eng = RetrievalEngine(cfg.search)
-        mesh = jax.make_mesh((3,), ("data",))
+        mesh = make_mesh((3,), ("data",))
         sstore = store.shard(mesh, ("data",))
         assert sstore.capacity == 102, sstore.capacity
         assert int((sstore.labels < 0).sum()) == 12  # 10 empty + 2 pad

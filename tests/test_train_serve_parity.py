@@ -20,6 +20,7 @@ import pytest
 from repro.core.avss import SearchConfig, class_mean_votes
 from repro.core.memory import MemoryConfig
 from repro.engine import (MemoryStore, RetrievalEngine, SearchRequest)
+from repro.launch.mesh import make_mesh
 
 
 def _episode(dim=16, n=12, b=5, seed=0):
@@ -81,7 +82,7 @@ def test_episode_votes_match_two_phase_candidates(backend, sharded):
     ep = eng.episode_votes(q, s, noisy=False)
     store = _programmed_store(cfg, q, s, labels)
     if sharded:
-        store = store.shard(jax.make_mesh((1,), ("data",)))
+        store = store.shard(make_mesh((1,), ("data",)))
     res = eng.search(store, q, SearchRequest(
         mode="two_phase", k=s.shape[0], backend=backend, noisy=False))
     votes = jnp.take_along_axis(ep["votes"], res.indices, axis=1)
