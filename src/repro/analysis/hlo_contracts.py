@@ -24,6 +24,7 @@ repro/kernels/shortlist.py).
 
 from __future__ import annotations
 
+import dataclasses
 import re
 
 # Cross-device collectives that must never appear in a shard-local write
@@ -49,6 +50,29 @@ FUSED_SCOPE_TAG = "shortlist_fused"
 # (repro/engine/router.route_scores): present in compiled HLO iff a
 # search routes through the per-shard summary sketch (nprobe < n_shards).
 ROUTER_SCOPE_TAG = "router_sketch"
+
+# The layers of a search and of a store write, one jax.named_scope each,
+# in the order a request passes through them. Every op the engine traces
+# sits under one of them (repro/engine/), so a compiled program's ops map to
+# layers (`op_layers`) and a profile's device time splits by layer
+# (repro/analysis/profile.py). The tags above nest inside them.
+QUANTIZE_SCOPE = "search_quantize"     # MemoryStore.quantize_queries
+ROUTE_SCOPE = "search_route"           # phase 0 (router_sketch inside)
+SHORTLIST_SCOPE = "search_shortlist"   # phase 1, whatever implements it
+RESCORE_SCOPE = "search_rescore"       # phase 2 and `full` mode's search
+MERGE_SCOPE = "search_merge"           # sharded all-gather + top-k merge
+ANSWER_SCOPE = "search_answer"         # label gather, vote mask, predict
+WRITE_SCOPE = "store_write"            # MemoryStore.write
+CALIBRATE_SCOPE = "store_calibrate"    # MemoryStore.calibrate
+LAYER_SCOPES = (QUANTIZE_SCOPE, ROUTE_SCOPE, SHORTLIST_SCOPE, RESCORE_SCOPE,
+                MERGE_SCOPE, ANSWER_SCOPE, WRITE_SCOPE, CALIBRATE_SCOPE)
+
+# Opcodes that move or name data without computing it. One that carries no
+# layer of its own (a copy or bitcast that layout assignment inserted, an
+# async copy's start/done pair, a parameter) takes the layer of its user.
+PLUMBING_OPCODES = frozenset({
+    "parameter", "constant", "copy", "copy-start", "copy-done", "bitcast",
+    "tuple", "get-tuple-element"})
 
 # Double-precision leak marker: no search/write/training-forward program
 # may promote to f64 (jax runs x64-disabled; this guards explicit leaks).
@@ -86,6 +110,112 @@ def scoped_lines(hlo: str, tag: str) -> list[str]:
                 seen.add(s)
                 out.append(s)
     return out
+
+
+# -- op -> layer map ----------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class HloOp:
+    """One instruction of a compiled program, as far as its layer needs."""
+    name: str
+    opcode: str
+    op_name: str                 # "" where the compiler made the op up
+    operands: tuple[str, ...]
+    computation: str
+    calls: tuple[str, ...]       # the computations it calls
+
+
+# a computation's header starts in the first column and opens a block; its
+# instructions are indented `[ROOT ]%name = <shape> opcode(...), attrs`
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_REFERENCE = re.compile(r"%([\w.\-]+)")
+# ops of these run where the trace can see them: the entry computation and
+# the bodies of control flow (fused and reducer computations do not)
+_CONTROL_FLOW = frozenset({"while", "conditional", "call"})
+
+
+def parse_hlo(hlo: str) -> tuple[str, list[HloOp]]:
+    """(entry computation, every instruction) of compiled HLO text."""
+    blocks: list[tuple[str, list[tuple[str, str]]]] = []
+    entry = ""
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            blocks.append((m.group(2), []))
+            entry = m.group(2) if m.group(1) else entry
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and blocks:
+            blocks[-1][1].append((m.group(1), m.group(2)))
+    computations = {name for name, _ in blocks}
+    ops = []
+    for comp, body in blocks:
+        names = {n for n, _ in body}
+        for name, rhs in body:
+            opcode = _OPCODE.search(rhs)
+            op_name = _OP_NAME.search(rhs)
+            refs = _REFERENCE.findall(rhs)
+            ops.append(HloOp(
+                name, opcode.group(1) if opcode else "",
+                op_name.group(1) if op_name else "",
+                tuple(r for r in refs if r in names), comp,
+                tuple(r for r in refs if r in computations)))
+    return entry, ops
+
+
+def innermost_layer(op_name: str) -> str | None:
+    """The innermost `LAYER_SCOPES` component of an op_name scope path."""
+    found = [t for t in _SCOPE_TOKEN.findall(op_name) if t in LAYER_SCOPES]
+    return found[-1] if found else None
+
+
+def layers_of(entry: str, ops: list[HloOp]) -> dict[str, str | None]:
+    """{instruction: layer} over the ops a trace can show (`op_layers`)."""
+    visible, todo = set(), [entry]
+    calls = {}
+    for o in ops:
+        if o.opcode in _CONTROL_FLOW:
+            calls.setdefault(o.computation, []).extend(o.calls)
+    while todo:
+        comp = todo.pop()
+        if comp not in visible:
+            visible.add(comp)
+            todo.extend(calls.get(comp, ()))
+    ops = [o for o in ops if o.computation in visible]
+    layer = {o.name: innermost_layer(o.op_name) for o in ops}
+    users: dict[str, list[str]] = {}
+    for o in ops:
+        for a in o.operands:
+            users.setdefault(a, []).append(o.name)
+    pending = [o for o in ops if layer[o.name] is None
+               and (not o.op_name or o.opcode in PLUMBING_OPCODES)]
+    # users first, to a fixed point (a copy -> bitcast -> fusion chain takes
+    # the fusion's layer); then operands for what no user names (a root
+    # tuple, a copy of a result)
+    for near in (lambda o: users.get(o.name, []),
+                 lambda o: users.get(o.name, []) + list(o.operands)):
+        while pending:
+            for o in pending:
+                layer[o.name] = next((layer[n] for n in near(o)
+                                      if layer.get(n)), None)
+            left = [o for o in pending if layer[o.name] is None]
+            if len(left) == len(pending):
+                break
+            pending = left
+    return layer
+
+
+def op_layers(hlo: str) -> dict[str, str | None]:
+    """{instruction: layer} of a compiled program's text, for every op that
+    can appear in a device trace: the innermost `LAYER_SCOPES` component of
+    its op_name; a compiler-made op (no op_name) or a plumbing op
+    (`PLUMBING_OPCODES`) without one takes its user's layer, failing that
+    its operand's. None: an op of the program's own that no layer scope
+    covers."""
+    return layers_of(*parse_hlo(hlo))
 
 
 # -- checkers: [] == invariant holds ----------------------------------------

@@ -31,6 +31,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import hlo_contracts as hc
+
 MODES = ("full", "two_phase", "ideal")
 
 
@@ -152,9 +154,10 @@ class SearchResult:
         """(B,) position of the best candidate per query: max votes, vote
         ties broken exactly by ideal digital distance, then by index
         (stable argmin) -- the paper's retrieval rule."""
-        top = self.votes.max(axis=-1, keepdims=True)
-        return jnp.argmin(jnp.where(self.votes == top, self.dist, jnp.inf),
-                          axis=-1)
+        with jax.named_scope(hc.ANSWER_SCOPE):
+            top = self.votes.max(axis=-1, keepdims=True)
+            return jnp.argmin(jnp.where(self.votes == top, self.dist,
+                                        jnp.inf), axis=-1)
 
     def predict(self) -> jax.Array:
         """(B,) 1-NN label prediction: the label of `best()` per query.
@@ -175,7 +178,9 @@ class SearchResult:
         >>> int(empty.predict()[0])        # no valid candidate -> sentinel
         -1
         """
-        return jnp.take_along_axis(self.labels, self.best()[:, None], 1)[:, 0]
+        with jax.named_scope(hc.ANSWER_SCOPE):
+            return jnp.take_along_axis(self.labels, self.best()[:, None],
+                                       1)[:, 0]
 
     def asdict(self) -> dict[str, jax.Array | int]:
         """Legacy result-dict view (the pre-redesign contract)."""

@@ -31,6 +31,7 @@ from typing import TYPE_CHECKING, Any, Hashable, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import hlo_contracts as hc
 from repro.core import avss as avss_lib
 from repro.core import encodings as enc_lib
 from repro.core import mcam as mcam_lib
@@ -183,7 +184,6 @@ class RetrievalEngine:
                 "shards into device memory -- or re-shard with "
                 "residency='device'.")
         q = store.quantize_queries(queries)
-        valid = store.valid
         iters = eng._iterations(q.shape[-1])
 
         # phase-0 routing: engaged iff the request asks for FEWER shards
@@ -204,6 +204,8 @@ class RetrievalEngine:
         backend = eng.resolved_backend
         if req.mode == "two_phase":
             from repro.engine import sharded
+            with jax.named_scope(hc.SHORTLIST_SCOPE):
+                valid = store.valid
             res = sharded.sharded_two_phase_search(
                 q, store.values, eng.cfg, store.mesh, axes=axes,
                 k=req.k, valid=valid, labels=store.labels,
@@ -212,19 +214,22 @@ class RetrievalEngine:
                 backend=backend, fused_min_rows=fmr)
             # labels come from the per-shard fold (-1 on empty/pad
             # rows): mask their votes without any global gather
-            votes = jnp.where(res["labels"] >= 0, res["votes"],
-                              -jnp.inf)
+            with jax.named_scope(hc.ANSWER_SCOPE):
+                votes = jnp.where(res["labels"] >= 0, res["votes"],
+                                  -jnp.inf)
             return SearchResult(votes, res["dist"], res["indices"],
                                 res["labels"], res["iterations"])
         from repro.engine import sharded
         from repro.kernels import ops as kernel_ops
-        q1h = kernel_ops.query_onehot(q, jnp.float32)
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            q1h = kernel_ops.query_onehot(q, jnp.float32)
         res = sharded.sharded_ideal_search(
             q1h, store.proj, store.labels, store.mesh, axes=axes,
             k=req.k, backend=backend, fused_min_rows=fmr,
             packed=store.proj_packed, pack_bits=store.pack_bits,
             enc=eng.cfg.enc)
-        votes = jnp.where(res["labels"] >= 0, res["votes"], -jnp.inf)
+        with jax.named_scope(hc.ANSWER_SCOPE):
+            votes = jnp.where(res["labels"] >= 0, res["votes"], -jnp.inf)
         return SearchResult(votes, res["dist"], res["indices"],
                             res["labels"], iters)
 
@@ -239,15 +244,19 @@ class RetrievalEngine:
         leaves it None (arange(B)), `search_tenants` passes each query's
         rank within its tenant group so the vmapped dispatch is
         bit-identical to per-tenant solo calls."""
-        valid = store.valid
+        with jax.named_scope(hc.SHORTLIST_SCOPE):   # phase 1's row mask
+            valid = store.valid
         iters = self._iterations(q.shape[-1])
         if req.mode == "full":
             res = self.full(q, store.values, s_grid=store.s_grid,
                             noise_qidx=noise_qidx)
-            votes = jnp.where(valid[None, :], res["votes"], -jnp.inf)
-            indices = jnp.broadcast_to(
-                jnp.arange(store.capacity, dtype=jnp.int32), votes.shape)
-            labels = jnp.broadcast_to(store.labels, votes.shape)
+            with jax.named_scope(hc.ANSWER_SCOPE):
+                votes = jnp.where(store.valid[None, :], res["votes"],
+                                  -jnp.inf)
+                indices = jnp.broadcast_to(
+                    jnp.arange(store.capacity, dtype=jnp.int32),
+                    votes.shape)
+                labels = jnp.broadcast_to(store.labels, votes.shape)
             return SearchResult(votes, res["dist"], indices, labels,
                                 res["iterations"])
         if req.mode == "two_phase":
@@ -257,8 +266,9 @@ class RetrievalEngine:
                                  pack_bits=store.pack_bits,
                                  fused_min_rows=self._fused_threshold(req),
                                  noise_qidx=noise_qidx)
-            labels = store.labels[res["indices"]]      # -1 on empty slots
-            votes = jnp.where(labels >= 0, res["votes"], -jnp.inf)
+            with jax.named_scope(hc.ANSWER_SCOPE):
+                labels = store.labels[res["indices"]]  # -1 on empty slots
+                votes = jnp.where(labels >= 0, res["votes"], -jnp.inf)
             return SearchResult(votes, res["dist"], res["indices"], labels,
                                 res["iterations"])
         # ideal: top-k by the exact integer-valued digital distance against
@@ -271,20 +281,23 @@ class RetrievalEngine:
         from repro.kernels import ops as kernel_ops
         k = min(req.k, store.capacity)
         backend = self.resolved_backend
-        if backend != "ref" and (store.capacity >= self._fused_threshold(req)
-                                 or backend == "fused"):
-            dist, idx = kernel_ops.lut_shortlist(
-                q, store.values, self.cfg.enc, k, valid=valid,
-                proj=store.proj, packed=store.proj_packed,
-                pack_bits=store.pack_bits)
-        else:
-            # same dense block shortlist the sharded paths use per shard
-            from repro.engine.sharded import _local_shortlist
-            q1h = kernel_ops.query_onehot(q, jnp.float32)
-            dist, idx = _local_shortlist(q1h, store.proj, valid, k,
-                                         fused=False)
-        labels = store.labels[idx]
-        votes = jnp.where(labels >= 0, -dist, -jnp.inf)
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            if backend != "ref" and (
+                    store.capacity >= self._fused_threshold(req)
+                    or backend == "fused"):
+                dist, idx = kernel_ops.lut_shortlist(
+                    q, store.values, self.cfg.enc, k, valid=valid,
+                    proj=store.proj, packed=store.proj_packed,
+                    pack_bits=store.pack_bits)
+            else:
+                # same dense block shortlist the sharded paths use per shard
+                from repro.engine.sharded import _local_shortlist
+                q1h = kernel_ops.query_onehot(q, jnp.float32)
+                dist, idx = _local_shortlist(q1h, store.proj, valid, k,
+                                             fused=False)
+        with jax.named_scope(hc.ANSWER_SCOPE):
+            labels = store.labels[idx]
+            votes = jnp.where(labels >= 0, -dist, -jnp.inf)
         return SearchResult(votes, dist, idx, labels, iters)
 
     # -- routed (phase-0) search -------------------------------------------
@@ -300,20 +313,24 @@ class RetrievalEngine:
         from repro.engine import router as router_lib
         s = store.n_shards
         rows = store.capacity // s
-        scores = router_lib.route_scores(q, store.sketch_sums,
-                                         store.sketch_counts, self.cfg.enc)
-        sids = router_lib.top_shards(scores, int(req.nprobe or 0))
-        # per-shard block tables (S, rows, ...); on a mesh-sharded store
-        # these reshapes stay sharded and XLA inserts the per-query block
-        # gathers (the single-device / logical-partition path is the one
-        # the routed contract cells pin collective-free)
-        packed_t = (None if store.proj_packed is None
-                    else store.proj_packed.reshape(s, rows, -1))
-        return self._routed_block_search(
-            q, sids, jnp.arange(s, dtype=jnp.int32),
-            store.proj.reshape(s, rows, -1), packed_t,
-            store.s_grid.reshape((s, rows) + store.s_grid.shape[1:]),
-            store.labels.reshape(s, rows), req, store.pack_bits)
+        # the visited blocks' gathers stay in this layer; phase 1 and 2
+        # inside them take their own scopes
+        with jax.named_scope(hc.ROUTE_SCOPE):
+            scores = router_lib.route_scores(q, store.sketch_sums,
+                                             store.sketch_counts,
+                                             self.cfg.enc)
+            sids = router_lib.top_shards(scores, int(req.nprobe or 0))
+            # per-shard block tables (S, rows, ...); on a mesh-sharded
+            # store these reshapes stay sharded and XLA inserts the
+            # per-query block gathers (the single-device / logical-partition
+            # path is the one the routed contract cells pin collective-free)
+            packed_t = (None if store.proj_packed is None
+                        else store.proj_packed.reshape(s, rows, -1))
+            return self._routed_block_search(
+                q, sids, jnp.arange(s, dtype=jnp.int32),
+                store.proj.reshape(s, rows, -1), packed_t,
+                store.s_grid.reshape((s, rows) + store.s_grid.shape[1:]),
+                store.labels.reshape(s, rows), req, store.pack_bits)
 
     def _routed_block_search(self, q: jax.Array, slot_ids: jax.Array,
                              shard_of: jax.Array, proj_t: jax.Array,
@@ -348,11 +365,13 @@ class RetrievalEngine:
         fused = _use_fused(self.resolved_backend, rows_vis,
                            self._fused_threshold(req))
         two_phase = req.mode == "two_phase"
-        q1h = kernel_ops.query_onehot(q, jnp.float32)
-        q_grid = avss_lib.layout_query(q, cfg.enc, "avss",
-                                       cfg.mcam.string_len)
-        weights = cfg.enc.weights_array()
-        thresholds = jnp.asarray(cfg.mcam.thresholds())
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            q1h = kernel_ops.query_onehot(q, jnp.float32)
+        with jax.named_scope(hc.RESCORE_SCOPE):
+            q_grid = avss_lib.layout_query(q, cfg.enc, "avss",
+                                           cfg.mcam.string_len)
+            weights = cfg.enc.weights_array()
+            thresholds = jnp.asarray(cfg.mcam.thresholds())
         if noise_qidx is None:
             noise_qidx = jnp.arange(q.shape[0], dtype=jnp.uint32)
 
@@ -370,16 +389,18 @@ class RetrievalEngine:
             # are ascending-shard-ordered, block i covers global rows
             # [shard_of[sl_b[i]] * rows, ...)
             gidx = shard_of[sl_b][li // rows] * rows + li % rows
-            lab = lab_vis[li]
             if two_phase:
-                sg_vis = sgrid_t[sl_b].reshape((rows_vis,)
-                                               + sgrid_t.shape[2:])
-                votes = kernel_ops.rescore_shortlist(
-                    qgrid_b[None], sg_vis, li, weights, cfg, thresholds,
-                    noise_idx=gidx, noise_qidx=qi_b[None])
+                with jax.named_scope(hc.RESCORE_SCOPE):
+                    sg_vis = sgrid_t[sl_b].reshape((rows_vis,)
+                                                   + sgrid_t.shape[2:])
+                    votes = kernel_ops.rescore_shortlist(
+                        qgrid_b[None], sg_vis, li, weights, cfg,
+                        thresholds, noise_idx=gidx, noise_qidx=qi_b[None])
             else:
                 votes = -dist
-            votes = jnp.where(lab >= 0, votes, -jnp.inf)
+            with jax.named_scope(hc.ANSWER_SCOPE):
+                lab = lab_vis[li]
+                votes = jnp.where(lab >= 0, votes, -jnp.inf)
             return votes[0], dist[0], gidx[0], lab[0]
 
         votes, dist, indices, labels = jax.vmap(one)(
@@ -587,21 +608,23 @@ class RetrievalEngine:
         Returns {votes (B, N), dist (B, N), iterations}.
         """
         cfg = self.cfg
-        q_grid, s_grid, weights, thresholds = self._grids(q_values, s_values,
-                                                          s_grid)
-        if noise_qidx is None:
-            noise_qidx = jnp.arange(q_grid.shape[0], dtype=jnp.uint32)
-        if self.resolved_backend == "ref":
-            fn = partial(avss_lib._search_one_query, weights=weights,
-                         cfg=cfg, thresholds=thresholds)
-            votes, dist = jax.lax.map(
-                lambda args: fn(args[0], s_grid, args[1]),
-                (q_grid, noise_qidx.astype(jnp.uint32)),
-                batch_size=min(cfg.query_chunk, q_grid.shape[0]))
-        else:  # pallas / mxu / fused all use the fused VPU search kernel
-            from repro.kernels import ops as kernel_ops
-            votes, dist = kernel_ops.mcam_search(
-                q_grid, s_grid, weights, cfg, thresholds, qidx=noise_qidx)
+        with jax.named_scope(hc.RESCORE_SCOPE):
+            q_grid, s_grid, weights, thresholds = self._grids(
+                q_values, s_values, s_grid)
+            if noise_qidx is None:
+                noise_qidx = jnp.arange(q_grid.shape[0], dtype=jnp.uint32)
+            if self.resolved_backend == "ref":
+                fn = partial(avss_lib._search_one_query, weights=weights,
+                             cfg=cfg, thresholds=thresholds)
+                votes, dist = jax.lax.map(
+                    lambda args: fn(args[0], s_grid, args[1]),
+                    (q_grid, noise_qidx.astype(jnp.uint32)),
+                    batch_size=min(cfg.query_chunk, q_grid.shape[0]))
+            else:  # pallas / mxu / fused all use the fused VPU search kernel
+                from repro.kernels import ops as kernel_ops
+                votes, dist = kernel_ops.mcam_search(
+                    q_grid, s_grid, weights, cfg, thresholds,
+                    qidx=noise_qidx)
         return {"votes": votes, "dist": dist,
                 "iterations": self._iterations(q_values.shape[-1])}
 
@@ -650,23 +673,23 @@ class RetrievalEngine:
         backend = self.resolved_backend
         if fused_min_rows is None:
             fused_min_rows = self.fused_min_rows
-        if backend == "fused" or (backend != "ref"
-                                  and s_values.shape[0] >= fused_min_rows):
-            return kernel_ops.lut_shortlist(q_values, s_values, cfg.enc, k,
-                                            valid=valid, proj=proj,
-                                            packed=packed,
-                                            pack_bits=pack_bits)
-        if backend == "ref":
-            lut = jnp.asarray(enc_lib.avss_sum_lut(cfg.enc), jnp.float32)
-            dist = ref_kernels.avss_dist_ref(q_values, s_values, lut)
-        else:  # pallas / mxu: LUT matmul kernel
-            dist = kernel_ops.avss_ideal_dist(q_values, s_values, cfg.enc,
-                                              proj=proj)
-        if valid is not None:
-            dist = dist + jnp.where(valid, 0.0,
-                                    kernel_ops.SHORTLIST_MASK_PENALTY)[None]
-        neg, idx = jax.lax.top_k(-dist, k)
-        return -neg, idx
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            if backend == "fused" or (
+                    backend != "ref" and s_values.shape[0] >= fused_min_rows):
+                return kernel_ops.lut_shortlist(
+                    q_values, s_values, cfg.enc, k, valid=valid, proj=proj,
+                    packed=packed, pack_bits=pack_bits)
+            if backend == "ref":
+                lut = jnp.asarray(enc_lib.avss_sum_lut(cfg.enc), jnp.float32)
+                dist = ref_kernels.avss_dist_ref(q_values, s_values, lut)
+            else:  # pallas / mxu: LUT matmul kernel
+                dist = kernel_ops.avss_ideal_dist(q_values, s_values,
+                                                  cfg.enc, proj=proj)
+            if valid is not None:
+                dist = dist + jnp.where(
+                    valid, 0.0, kernel_ops.SHORTLIST_MASK_PENALTY)[None]
+            neg, idx = jax.lax.top_k(-dist, k)
+            return -neg, idx
 
     # -- two-phase search --------------------------------------------------
 
@@ -698,11 +721,12 @@ class RetrievalEngine:
                                    proj=proj, packed=packed,
                                    pack_bits=pack_bits,
                                    fused_min_rows=fused_min_rows)
-        q_grid, s_grid, weights, thresholds = self._grids(q_values, s_values,
-                                                          s_grid)
-        votes = kernel_ops.rescore_shortlist(
-            q_grid, s_grid, idx, weights, cfg, thresholds,
-            noise_qidx=noise_qidx)
+        with jax.named_scope(hc.RESCORE_SCOPE):
+            q_grid, s_grid, weights, thresholds = self._grids(
+                q_values, s_values, s_grid)
+            votes = kernel_ops.rescore_shortlist(
+                q_grid, s_grid, idx, weights, cfg, thresholds,
+                noise_qidx=noise_qidx)
         return {"votes": votes, "dist": dist, "indices": idx,
                 "iterations": self._iterations(q_values.shape[-1])}
 

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.analysis import hlo_contracts as hc
+
 if TYPE_CHECKING:
     from jax.sharding import Mesh
 
@@ -102,20 +104,21 @@ def _local_shortlist(q1h: jax.Array, proj_loc: jax.Array,
     (the kernel reproduces lax.top_k's (distance, row) order). When the
     store provides its bit-packed projection (`packed`/`pack_bits`), the
     fused kernel streams that 4-8x smaller operand instead of proj_loc."""
-    if fused:
-        from repro.kernels import shortlist as shortlist_kernel
-        if packed is not None:
+    with jax.named_scope(hc.SHORTLIST_SCOPE):
+        if fused:
+            from repro.kernels import shortlist as shortlist_kernel
+            if packed is not None:
+                return shortlist_kernel.lut_shortlist_pallas(
+                    q1h, None, k_loc, valid=valid_loc, packed=packed,
+                    pack_bits=pack_bits)
             return shortlist_kernel.lut_shortlist_pallas(
-                q1h, None, k_loc, valid=valid_loc, packed=packed,
-                pack_bits=pack_bits)
-        return shortlist_kernel.lut_shortlist_pallas(
-            q1h, proj_loc, k_loc, valid=valid_loc)
-    from repro.kernels import ops as kernel_ops
-    dist = q1h @ proj_loc.astype(jnp.float32).T            # (B, N_loc)
-    dist = dist + jnp.where(valid_loc, 0.0,
-                            kernel_ops.SHORTLIST_MASK_PENALTY)[None]
-    neg, idx = jax.lax.top_k(-dist, k_loc)
-    return -neg, idx
+                q1h, proj_loc, k_loc, valid=valid_loc)
+        from repro.kernels import ops as kernel_ops
+        dist = q1h @ proj_loc.astype(jnp.float32).T        # (B, N_loc)
+        dist = dist + jnp.where(valid_loc, 0.0,
+                                kernel_ops.SHORTLIST_MASK_PENALTY)[None]
+        neg, idx = jax.lax.top_k(-dist, k_loc)
+        return -neg, idx
 
 
 def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
@@ -170,17 +173,20 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
     k_loc = min(k, N // n_shards)
     fused = _use_fused(backend, N // n_shards, fused_min_rows)
 
-    q1h = kernel_ops.query_onehot(q_values, jnp.float32)       # (B, 4d)
-    q_grid = avss_lib.layout_query(q_values, enc, "avss", sl)
-    weights = enc.weights_array()
-    thresholds = jnp.asarray(cfg.mcam.thresholds())
+    with jax.named_scope(hc.SHORTLIST_SCOPE):
+        q1h = kernel_ops.query_onehot(q_values, jnp.float32)   # (B, 4d)
+    with jax.named_scope(hc.RESCORE_SCOPE):
+        q_grid = avss_lib.layout_query(q_values, enc, "avss", sl)
+        weights = enc.weights_array()
+        thresholds = jnp.asarray(cfg.mcam.thresholds())
     # LUT built eagerly OUTSIDE the shard_map trace (it is a compile-time
     # constant of the encoding) and closed over by the local function.
     from repro.core.encodings import avss_sum_lut
     lut = jnp.asarray(avss_sum_lut(enc), jnp.float32)          # (4, levels)
     if valid is None:
         # keep the shard_map arity fixed; +0.0 is exact, parity unaffected
-        valid = jnp.ones((N,), bool)
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            valid = jnp.ones((N,), bool)
     # optional row-sharded extras keep the arity dynamic but the specs tied
     extras: list[jax.Array] = []
     extra_specs: list[P] = []
@@ -214,23 +220,25 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
         s_grid_loc = rest_l.pop(0) if s_grid is not None else None
         proj_loc = rest_l.pop(0) if proj is not None else None
         packed_loc = rest_l.pop(0) if packed is not None else None
-        offset = _shard_index(mesh, axes) * jnp.int32(s_loc.shape[0])
         # phase 1 on local rows: exact integer-valued distances, fused
         # kernel or dense MXU matmul (same LUT projection as
         # kernels/ops.support_projection, materialised at write time when
         # the store provides `proj` / its bit-packed `packed` twin)
-        if proj_loc is None:
-            proj_loc = lut.T[s_loc].reshape(s_loc.shape[0], -1)  # (N_loc, 4d)
-        d_loc, idx_loc = _local_shortlist(q1h_, proj_loc, valid_loc, k_loc,
-                                          fused=fused, packed=packed_loc,
-                                          pack_bits=pack_bits)
-        gidx = idx_loc + offset
+        with jax.named_scope(hc.SHORTLIST_SCOPE):
+            offset = _shard_index(mesh, axes) * jnp.int32(s_loc.shape[0])
+            if proj_loc is None:                       # (N_loc, 4d)
+                proj_loc = lut.T[s_loc].reshape(s_loc.shape[0], -1)
+            d_loc, idx_loc = _local_shortlist(
+                q1h_, proj_loc, valid_loc, k_loc, fused=fused,
+                packed=packed_loc, pack_bits=pack_bits)
+            gidx = idx_loc + offset
         # phase 2 on local candidates, GLOBAL indices for the noise counters
-        if s_grid_loc is None:                         # read-time layout
-            s_grid_loc = avss_lib.layout_support(s_loc, enc, sl)
-        votes = kernel_ops.rescore_shortlist(
-            q_grid_, s_grid_loc, idx_loc, weights, cfg, thresholds,
-            noise_idx=gidx)
+        with jax.named_scope(hc.RESCORE_SCOPE):
+            if s_grid_loc is None:                     # read-time layout
+                s_grid_loc = avss_lib.layout_support(s_loc, enc, sl)
+            votes = kernel_ops.rescore_shortlist(
+                q_grid_, s_grid_loc, idx_loc, weights, cfg, thresholds,
+                noise_idx=gidx)
         # merge: all-gather ONLY the per-shard (dist, global index[, label])
         # triplet -- a stable sort by distance == (distance, global row)
         # order selects the global top-k. Votes never ride the gather: each
@@ -239,26 +247,30 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
         # ownership-masked partial sum holds that shard's rescored vote and
         # zeros elsewhere, and one (B, k) psum recovers the merged votes
         # exactly (adding f32 zeros is exact -- bit-parity preserved).
-        d_all = _gather_candidates(d_loc, axes)
-        i_all = _gather_candidates(gidx, axes)
-        order = jnp.argsort(d_all, axis=-1, stable=True)[:, :k]
-        take = lambda x: jnp.take_along_axis(x, order, axis=1)
-        d_k, i_k = take(d_all), take(i_all)
-        own = i_k[:, :, None] == gidx[:, None, :]         # (B, k, k_loc)
-        v_part = jnp.sum(jnp.where(own, votes[:, None, :], 0.0), axis=2)
-        v_k = jax.lax.psum(v_part, ax)
-        outs = (v_k, d_k, i_k)
-        if labels_loc is not None:
-            l_all = _gather_candidates(labels_loc[idx_loc], axes)
-            outs = outs + (take(l_all),)
+        with jax.named_scope(hc.MERGE_SCOPE):
+            d_all = _gather_candidates(d_loc, axes)
+            i_all = _gather_candidates(gidx, axes)
+            order = jnp.argsort(d_all, axis=-1, stable=True)[:, :k]
+            take = lambda x: jnp.take_along_axis(x, order, axis=1)
+            d_k, i_k = take(d_all), take(i_all)
+            own = i_k[:, :, None] == gidx[:, None, :]     # (B, k, k_loc)
+            v_part = jnp.sum(jnp.where(own, votes[:, None, :], 0.0), axis=2)
+            v_k = jax.lax.psum(v_part, ax)
+            outs = (v_k, d_k, i_k)
+            if labels_loc is not None:
+                l_all = _gather_candidates(labels_loc[idx_loc], axes)
+                outs = outs + (take(l_all),)
         return outs
 
-    out = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(), P(), P(axes), P(axes), *extra_specs),
-        out_specs=(P(),) * (3 + (labels is not None)),
-        check_vma=False,
-    )(q1h, q_grid, s_values, valid, *extras)
+    # ops XLA hoists out of the per-shard body carry the shard_map call's
+    # own metadata: they convert phase 1's operands
+    with jax.named_scope(hc.SHORTLIST_SCOPE):
+        out = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(), P(), P(axes), P(axes), *extra_specs),
+            out_specs=(P(),) * (3 + (labels is not None)),
+            check_vma=False,
+        )(q1h, q_grid, s_values, valid, *extras)
     res = {"votes": out[0], "dist": out[1], "indices": out[2],
            "iterations": avss_lib.search_iterations(
                q_values.shape[-1], enc, "avss", sl)}
@@ -310,23 +322,25 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
               *rest: jax.Array
               ) -> tuple[jax.Array, jax.Array, jax.Array]:
         packed_loc = rest[0] if rest else None
-        offset = _shard_index(mesh, axes) * jnp.int32(proj_loc.shape[0])
         kk = min(k, proj_loc.shape[0])
         d_loc, idx = _local_shortlist(qr, proj_loc, labels_loc >= 0, kk,
                                       fused=fused, packed=packed_loc,
                                       pack_bits=pack_bits)
-        d_all = _gather_candidates(d_loc, axes)
-        l_all = _gather_candidates(labels_loc[idx], axes)
-        i_all = _gather_candidates(idx + offset, axes)
-        order = jnp.argsort(d_all, axis=-1, stable=True)[:, :k]
-        take = lambda x: jnp.take_along_axis(x, order, axis=1)
-        return take(d_all), take(l_all), take(i_all)
+        with jax.named_scope(hc.MERGE_SCOPE):
+            offset = _shard_index(mesh, axes) * jnp.int32(proj_loc.shape[0])
+            d_all = _gather_candidates(d_loc, axes)
+            l_all = _gather_candidates(labels_loc[idx], axes)
+            i_all = _gather_candidates(idx + offset, axes)
+            order = jnp.argsort(d_all, axis=-1, stable=True)[:, :k]
+            take = lambda x: jnp.take_along_axis(x, order, axis=1)
+            return take(d_all), take(l_all), take(i_all)
 
-    dist, labels_out, indices = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(), P(axes), P(axes), *extra_specs),
-        out_specs=(P(), P(), P()),
-        check_vma=False,
-    )(q_onehot, proj, labels, *extras)
+    with jax.named_scope(hc.SHORTLIST_SCOPE):     # as in the two-phase
+        dist, labels_out, indices = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(), P(axes), P(axes), *extra_specs),
+            out_specs=(P(), P(), P()),
+            check_vma=False,
+        )(q_onehot, proj, labels, *extras)
     return {"dist": dist, "labels": labels_out, "votes": -dist,
             "indices": indices}

@@ -66,6 +66,7 @@ import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.analysis import hlo_contracts as hc
 from repro.core import avss as avss_lib
 from repro.core import quantization as quant_lib
 from repro.core.avss import SearchConfig
@@ -349,7 +350,8 @@ class MemoryStore:
         # the SAME std-clipped range hardware-aware training computes
         # (quantization.clip_range): calibrating on the sample the trainer
         # quantized over reproduces its range bit-for-bit
-        lo, hi = quant_lib.clip_range(vectors, self.cfg.clip_std)
+        with jax.named_scope(hc.CALIBRATE_SCOPE):
+            lo, hi = quant_lib.clip_range(vectors, self.cfg.clip_std)
         return dataclasses.replace(self, lo=lo, hi=hi, calibrated=True)
 
     def write(self, vectors: jax.Array, labels: jax.Array) -> "MemoryStore":
@@ -380,12 +382,14 @@ class MemoryStore:
                 "program garbage words; call store.calibrate(sample) before "
                 "the first write (already-quantized supports go through "
                 "MemoryStore.from_quantized instead).")
-        v = _quantize(vectors, self.cfg.search.enc.levels, self.lo, self.hi)
-        if self.mesh is not None and self.n_shards > 1:
-            return self._program_streamed(v, labels, n)
-        start = self.size % ring
-        idx = (start + jnp.arange(n)) % ring
-        return self._program(idx, v, labels, n)
+        with jax.named_scope(hc.WRITE_SCOPE):
+            v = _quantize(vectors, self.cfg.search.enc.levels, self.lo,
+                          self.hi)
+            if self.mesh is not None and self.n_shards > 1:
+                return self._program_streamed(v, labels, n)
+            start = self.size % ring
+            idx = (start + jnp.arange(n)) % ring
+            return self._program(idx, v, labels, n)
 
     def _program(self, idx: jax.Array, v: jax.Array, labels: jax.Array,
                  n: int) -> "MemoryStore":
@@ -512,7 +516,8 @@ class MemoryStore:
                 "integer queries.")
         cfg = self.cfg.search
         levels = 4 if cfg.mode == "avss" else cfg.enc.levels
-        return _quantize(queries, levels, self.lo, self.hi)
+        with jax.named_scope(hc.QUANTIZE_SCOPE):
+            return _quantize(queries, levels, self.lo, self.hi)
 
     # -- sharding ------------------------------------------------------------
 

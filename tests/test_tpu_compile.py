@@ -93,3 +93,51 @@ def test_mcam_search_kernel_compiles(one_chip, noisy):
                           sds((N, S, SL), jnp.int8), sds((S,), jnp.float32),
                           sds((cfg.n_thresholds,), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_two_phase_search_ops_map_to_layers(one_chip):
+    """The served two_phase search at real widths, compiled for the chip:
+    every op that does work, the copies the TPU compiler inserts among
+    them, maps to a layer of the program (analysis.hlo_contracts
+    .op_layers), and the relayout of `s_grid` the rescore gathers from is
+    counted in the rescore."""
+    import dataclasses
+
+    from repro.analysis import hlo_contracts as hc
+    from repro.core.avss import SearchConfig
+    from repro.core.memory import MemoryConfig
+    from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+
+    cfg = SearchConfig("mtmc", cl=32, mode="avss", mcam=MCAMConfig(
+        string_len=SL, rho=8.0, sigma_device=0.12, sigma_read=0.04,
+        n_thresholds=8))
+    shapes = dataclasses.replace(jax.eval_shape(lambda: MemoryStore.create(
+        MemoryConfig(capacity=N, dim=K4D // 4, search=cfg))),
+        calibrated=True)
+    store = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    q = jax.ShapeDtypeStruct((B, K4D // 4), jnp.float32, sharding=one_chip)
+    eng = RetrievalEngine(cfg)
+
+    def search(st, q):
+        res = eng.search(st, q, SearchRequest(mode="two_phase", k=TOPK))
+        return res, res.predict()
+    with pytest.MonkeyPatch.context() as mp:
+        # the kernels take their compiled branch for the described chip
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        text = _compiled_text(search, store, q)
+    assert "tpu_custom_call" in text
+    _, ops = hc.parse_hlo(text)
+    layers = hc.op_layers(text)
+    opcode = {o.name: o.opcode for o in ops}
+    assert [n for n, layer in layers.items()
+            if layer is None and opcode[n] != "parameter"] == []
+    assert {hc.QUANTIZE_SCOPE, hc.SHORTLIST_SCOPE, hc.RESCORE_SCOPE,
+            hc.ANSWER_SCOPE} == set(layers.values()) - {None}
+    grid_copies = [o.name for o in ops if o.opcode == "copy"
+                   and f"s8[{N},{S},{SL}]" in next(
+                       line for line in text.splitlines()
+                       if f"%{o.name} = " in line)]
+    assert grid_copies and all(layers[c] == hc.RESCORE_SCOPE
+                               for c in grid_copies)
