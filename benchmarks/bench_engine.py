@@ -27,9 +27,8 @@ from repro.engine import (MemoryStore, RetrievalEngine, SearchRequest)
 from repro.launch.mesh import make_mesh
 
 # PINNED to the acceptance shape of the PR-5/6 shortlist baselines
-# (BENCH_shortlist.json compares against rows at this exact N) -- do NOT
-# follow IDEAL_FUSED_MIN_ROWS, which dropped to its measured crossover
-# (1024) in PR 10 and is a dispatch knob, not a benchmark shape.
+# (BENCH_shortlist.json compares against rows at this exact N) -- a
+# benchmark shape, not the engine's phase-1 dispatch rule.
 N_IDEAL = 4096
 N, B, D, K = 2048, 16, 48, 64
 W = 256                              # streaming-write batch rows

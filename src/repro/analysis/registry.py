@@ -54,6 +54,8 @@ from repro.launch.mesh import make_mesh
 
 #: k used by every search cell (small, so cells compile in milliseconds).
 CELL_K = 16
+#: queries per search-cell batch (the shared fixture's `qv`).
+CELL_B = 5
 #: fused_min_rows values forcing each side of the dispatch rule.
 FMR_FORCE_FUSED = 1
 FMR_FORCE_DENSE = 1 << 30
@@ -139,7 +141,7 @@ def _fix():
     labels = jnp.where(jnp.arange(72) % 4 == 0, -1,
                        jnp.arange(72)).astype(jnp.int32)   # masked rows
     store = MemoryStore.from_quantized(sv, labels, cfg)
-    qv = jax.random.randint(jax.random.PRNGKey(1), (5, 20), 0, 4)
+    qv = jax.random.randint(jax.random.PRNGKey(1), (CELL_B, 20), 0, 4)
 
     mcfg = MemoryConfig(capacity=32, dim=16,
                         search=SearchConfig("mtmc", cl=4, mode="avss",
@@ -199,12 +201,13 @@ def _unpacked(store):
     return dataclasses.replace(store, proj_packed=None)
 
 
-def _expect_fused(backend: str, rows_loc: int, mode: str, fmr: int) -> bool:
+def _expect_fused(backend: str, rows_loc: int, mode: str, fmr: int,
+                  batch: int = CELL_B) -> bool:
     """The registry's expectation IS the engine's dispatch rule."""
     from repro.engine.sharded import _use_fused
     if mode == "full":
         return False
-    return _use_fused(backend, rows_loc, fmr)
+    return _use_fused(backend, batch, rows_loc, fmr)
 
 
 # -- cell builders ----------------------------------------------------------
@@ -230,7 +233,8 @@ def _search_cell(mode: str, backend: str, fmr: int, packed: bool,
             mesh=mesh)
         rows_loc = store.capacity // (n_shards if sharded else 1)
         art = {"hlo": compiled.as_text(), "compiled": compiled,
-               "expect_fused": _expect_fused(backend, rows_loc, mode, fmr)}
+               "expect_fused": _expect_fused(backend, rows_loc, mode, fmr,
+                                             batch=qv.shape[0])}
         if mode == "ideal" and art["expect_fused"] and not sharded:
             art["hbm"] = _hbm_stats(compiled, qv.shape[0], CELL_K,
                                     store.capacity, store.dim)
@@ -330,7 +334,7 @@ def _tenant_search_cell(mode: str, backend: str, fmr: int,
         # row count -- that is the rows_loc the dispatch rule acts on
         return {"hlo": compiled.as_text(), "compiled": compiled,
                 "expect_fused": _expect_fused(backend, tstore.n_pad,
-                                              mode, fmr)}
+                                              mode, fmr, batch=qv.shape[0])}
 
     # the tenant axis is a pure batch axis: beyond the solo-search
     # invariants, the vmapped program must introduce ZERO collectives

@@ -35,21 +35,22 @@ The serving contract (PR 2) is store-centric:
 from repro.engine.api import SearchRequest, SearchResult
 from repro.engine.backends import (BACKENDS, kernels_available,
                                    resolve_backend)
-from repro.engine.engine import IDEAL_FUSED_MIN_ROWS, RetrievalEngine
+from repro.engine.engine import RetrievalEngine
 from repro.engine.pager import ShardPager
 from repro.engine.router import (ROUTER_BUCKETS, build_sketch, route_scores,
                                  sketch_centroids, top_shards)
-from repro.engine.sharded import (sharded_ideal_search,
+from repro.engine.sharded import (SHORTLIST_DENSE_MAX_BYTES,
+                                  sharded_ideal_search,
                                   sharded_two_phase_search)
 from repro.engine.store import MemoryStore
 from repro.engine.tenant import TenantStore, tenant_query_rank
 
 __all__ = [
     "BACKENDS",
-    "IDEAL_FUSED_MIN_ROWS",
     "MemoryStore",
     "ROUTER_BUCKETS",
     "RetrievalEngine",
+    "SHORTLIST_DENSE_MAX_BYTES",
     "SearchRequest",
     "SearchResult",
     "ShardPager",

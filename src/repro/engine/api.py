@@ -54,11 +54,13 @@ class SearchRequest:
     fused_min_rows: per-request override of the engine's fused-shortlist
              row threshold (None defers to the engine). Shortlists -- the
              'ideal' mode and phase 1 of 'two_phase', per SHARD-LOCAL block
-             on a sharded store -- stream through the fused Pallas kernel
-             (repro/kernels/shortlist.py) once the row count reaches this
-             threshold; results are bit-identical either way, so this is
-             purely a performance knob (e.g. for applying a measured TPU
-             dense-vs-fused crossover without a code change).
+             on a sharded store -- take the dense XLA dot + top_k while
+             their (B, N) f32 block fits
+             `sharded.SHORTLIST_DENSE_MAX_BYTES`, the fused Pallas kernel
+             (repro/kernels/shortlist.py) beyond it, and also once the
+             row count reaches this threshold when one is set; results
+             are bit-identical either way, so this is purely a
+             performance knob.
     noisy:   per-request override of SearchConfig.noisy (None defers to
              the config). noisy=False serves the NOISELESS hardware
              forward on any mode/backend/sharding -- the serving side of

@@ -5,10 +5,11 @@ performance/hardware decision, see the parity suite in tests/test_engine.py):
 
   ref     pure-jnp reference (kernels/ref.py semantics); always available.
   pallas  fused Pallas VPU search kernel (kernels/mcam_search.py) for the
-          full search; Pallas MXU LUT matmul for shortlists.
-  mxu     alias of `pallas` for the full search; for two-phase shortlists it
-          names the unfused LUT matmul + lax.top_k pipeline.
-  fused   two-phase shortlists via the fused distance+top-k Pallas kernel
+          full search; shortlists by the shared dispatch rule
+          (engine/sharded._use_fused): XLA's dense dot + lax.top_k, or the
+          fused shortlist kernel for a (B, N) block over the budget.
+  mxu     alias of `pallas`.
+  fused   shortlists always via the fused distance+top-k Pallas kernel
           (kernels/shortlist.py); full search as `pallas`.
 """
 

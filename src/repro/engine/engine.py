@@ -65,21 +65,6 @@ def _noise_stream(key: jax.Array | int | None) -> jax.Array | None:
         s = mcam_lib._mix(s ^ arr[i])
     return s
 
-# Default row threshold above which shortlists (the `ideal` mode and the
-# two-phase phase 1 -- unsharded, or PER SHARD-LOCAL BLOCK when sharded)
-# route through the fused Pallas shortlist kernel (kernels/shortlist.py)
-# instead of materialising the dense (B, N) distance matrix -- HBM traffic
-# drops from O(B*N) to O(B*k + N*4d), bit-identically (the fused kernel
-# reproduces lax.top_k's (distance, row) order exactly, ties included).
-# 1024 is the MEASURED dense-vs-fused crossover from the PR-6 shortlist
-# rework (BENCH_shortlist.json / benchmarks/autotune_shortlist.py, CPU
-# interpret mode). Still a knob, not a constant: override without code
-# change via RetrievalEngine(fused_min_rows=...) or
-# SearchRequest.fused_min_rows, and re-run the autotune sweep on real TPU
-# to rewrite it there (ROADMAP item 3 note).
-IDEAL_FUSED_MIN_ROWS = 1024
-
-
 @dataclasses.dataclass(frozen=True)
 class RetrievalEngine:
     """Dispatches AVSS/SVSS searches to a selected backend.
@@ -88,15 +73,19 @@ class RetrievalEngine:
               noise). `cfg.use_kernel` is honoured as a fallback preference.
     backend:  'auto' | 'ref' | 'pallas' | 'mxu' | 'fused'; overrides
               cfg.use_kernel when not 'auto'.
-    fused_min_rows: row threshold for the fused-shortlist dispatch (per
-              shard-local block on sharded stores); 'fused' always fuses
-              and 'ref' never does. `SearchRequest.fused_min_rows`
-              overrides this per request.
+    fused_min_rows: optional row count from which shortlists run the
+              fused Pallas kernel (per shard-local block on sharded
+              stores). None leaves phase 1 to the shape rule
+              (`sharded._use_fused`): the dense XLA route while its
+              (B, N) f32 block fits `sharded.SHORTLIST_DENSE_MAX_BYTES`,
+              the kernel beyond. 'fused' always fuses and 'ref' never
+              does. `SearchRequest.fused_min_rows` overrides this per
+              request.
     """
 
     cfg: SearchConfig
     backend: str = "auto"
-    fused_min_rows: int = IDEAL_FUSED_MIN_ROWS
+    fused_min_rows: int | None = None
 
     @property
     def resolved_backend(self) -> str:
@@ -135,9 +124,11 @@ class RetrievalEngine:
         return self._cached_replace(
             ("noisy", noisy), cfg=dataclasses.replace(self.cfg, noisy=noisy))
 
-    def _fused_threshold(self, request: SearchRequest | None = None) -> int:
+    def _fused_threshold(self, request: SearchRequest | None = None
+                         ) -> int | None:
         """Effective fused-shortlist row threshold: the request override
-        when set, else this engine's `fused_min_rows`."""
+        when set, else this engine's `fused_min_rows` (None: the shape
+        rule alone decides)."""
         if request is not None and request.fused_min_rows is not None:
             return request.fused_min_rows
         return self.fused_min_rows
@@ -159,8 +150,8 @@ class RetrievalEngine:
 
         Results are bit-identical to the raw-array methods below for every
         mode/backend/sharding (tests/test_engine.py, tests/test_store.py)
-        -- including whether a shortlist ran the fused Pallas kernel or
-        the dense reference (`fused_min_rows` is purely a perf knob).
+        -- including whether a shortlist ran the dense XLA dot + top_k or
+        the fused Pallas kernel (the dispatch is purely a perf decision).
 
         >>> import jax.numpy as jnp
         >>> from repro.core.avss import SearchConfig
@@ -196,9 +187,8 @@ class RetrievalEngine:
         if store.mesh is None or req.mode == "full":
             return eng._search_unsharded(store, q, req)
 
-        # per-shard shortlists share the unsharded dispatch rule: the
-        # fused Pallas kernel engages once a shard's LOCAL rows reach
-        # the threshold (engine/sharded._use_fused)
+        # per-shard shortlists share the unsharded dispatch rule, on each
+        # shard's LOCAL (B, N_loc) block (engine/sharded._use_fused)
         axes = req.axes if req.axes is not None else store.axes
         fmr = eng._fused_threshold(req)
         backend = eng.resolved_backend
@@ -235,15 +225,18 @@ class RetrievalEngine:
 
     def _search_unsharded(self, store: MemoryStore, q: jax.Array,
                           req: SearchRequest,
-                          noise_qidx: jax.Array | None = None
-                          ) -> SearchResult:
+                          noise_qidx: jax.Array | None = None,
+                          batch: int | None = None) -> SearchResult:
         """The unsharded (single-block) search body shared by `search` and
         `search_tenants`: `self` must already carry the request's backend
         and noisy overrides, `q` is already quantized. `noise_qidx` (B,)
         overrides the per-query noise coordinates (see `full`); `search`
         leaves it None (arange(B)), `search_tenants` passes each query's
         rank within its tenant group so the vmapped dispatch is
-        bit-identical to per-tenant solo calls."""
+        bit-identical to per-tenant solo calls. `batch` is the query count
+        of the whole dense block the shortlist dispatch sizes (default
+        q's; `search_tenants` vmaps this body one query at a time)."""
+        from repro.engine.sharded import _local_shortlist, _use_fused
         with jax.named_scope(hc.SHORTLIST_SCOPE):   # phase 1's row mask
             valid = store.valid
         iters = self._iterations(q.shape[-1])
@@ -259,12 +252,13 @@ class RetrievalEngine:
                 labels = jnp.broadcast_to(store.labels, votes.shape)
             return SearchResult(votes, res["dist"], indices, labels,
                                 res["iterations"])
+        fused = _use_fused(self.resolved_backend, batch or q.shape[0],
+                           store.capacity, self._fused_threshold(req))
         if req.mode == "two_phase":
             res = self.two_phase(q, store.values, k=req.k, valid=valid,
                                  s_grid=store.s_grid, proj=store.proj,
                                  packed=store.proj_packed,
-                                 pack_bits=store.pack_bits,
-                                 fused_min_rows=self._fused_threshold(req),
+                                 pack_bits=store.pack_bits, fused=fused,
                                  noise_qidx=noise_qidx)
             with jax.named_scope(hc.ANSWER_SCOPE):
                 labels = store.labels[res["indices"]]  # -1 on empty slots
@@ -274,25 +268,19 @@ class RetrievalEngine:
         # ideal: top-k by the exact integer-valued digital distance against
         # the write-time LUT projection. Masked rows carry the integer-exact
         # SHORTLIST_MASK_PENALTY (the same contract as two_phase / the
-        # sharded ideal path), so every route below is bit-identical. Large
-        # stores stream through the fused Pallas shortlist kernel -- HBM
-        # O(B*k + N*4d) instead of the dense (B, N) matrix; small stores and
-        # the ref backend keep the dense matmul as the readable reference.
+        # sharded ideal path), so both routes below are bit-identical: the
+        # dense XLA dot + top_k while its (B, N) block fits the budget, the
+        # fused Pallas kernel (HBM O(B*k + N*4d)) beyond it.
         from repro.kernels import ops as kernel_ops
         k = min(req.k, store.capacity)
-        backend = self.resolved_backend
         with jax.named_scope(hc.SHORTLIST_SCOPE):
-            if backend != "ref" and (
-                    store.capacity >= self._fused_threshold(req)
-                    or backend == "fused"):
+            if fused:
                 dist, idx = kernel_ops.lut_shortlist(
                     q, store.values, self.cfg.enc, k, valid=valid,
                     proj=store.proj, packed=store.proj_packed,
                     pack_bits=store.pack_bits)
             else:
-                # same dense block shortlist the sharded paths use per shard
-                from repro.engine.sharded import _local_shortlist
-                q1h = kernel_ops.query_onehot(q, jnp.float32)
+                q1h = kernel_ops.query_onehot(q, store.proj.dtype)
                 dist, idx = _local_shortlist(q1h, store.proj, valid, k,
                                              fused=False)
         with jax.named_scope(hc.ANSWER_SCOPE):
@@ -362,7 +350,7 @@ class RetrievalEngine:
         rows = proj_t.shape[1]
         rows_vis = p * rows
         k = min(req.k, rows_vis)
-        fused = _use_fused(self.resolved_backend, rows_vis,
+        fused = _use_fused(self.resolved_backend, q.shape[0], rows_vis,
                            self._fused_threshold(req))
         two_phase = req.mode == "two_phase"
         with jax.named_scope(hc.SHORTLIST_SCOPE):
@@ -465,7 +453,8 @@ class RetrievalEngine:
         def one(store_b: MemoryStore, q_b: jax.Array,
                 rank_b: jax.Array) -> SearchResult:
             return eng._search_unsharded(store_b, q_b[None], req,
-                                         noise_qidx=rank_b[None])
+                                         noise_qidx=rank_b[None],
+                                         batch=q.shape[0])
 
         res = jax.vmap(one)(view, q, rank)
         # drop the inner singleton query axis: (B, 1, K) -> (B, K)
@@ -635,7 +624,7 @@ class RetrievalEngine:
                   proj: jax.Array | None = None,
                   packed: jax.Array | None = None,
                   pack_bits: int | None = None,
-                  fused_min_rows: int | None = None
+                  fused: bool | None = None
                   ) -> tuple[jax.Array, jax.Array]:
         """Top-k supports by ideal digital AVSS distance.
 
@@ -649,7 +638,7 @@ class RetrievalEngine:
         and sharding bit-parity). Their returned dist includes the penalty.
 
         proj: optional write-time LUT projection (MemoryStore.proj) for the
-        mxu/fused backends; identical to recomputing it from s_values (the
+        kernel backends; identical to recomputing it from s_values (the
         projection is a deterministic function of the values), just hoisted
         out of the search. The ref backend always recomputes -- it is the
         readable reference, and its distances are bit-identical anyway.
@@ -661,30 +650,35 @@ class RetrievalEngine:
         .pack_bits); required whenever `packed` is given without the
         matching `proj` (the width depends on the packing dtype).
 
-        Dispatch mirrors every other shortlist site: the fused Pallas
-        kernel engages on the 'fused' backend, and on any kernel backend
-        once N reaches the fused threshold (`fused_min_rows`, overridable
-        per call); 'ref' and small N keep the dense matmul + lax.top_k.
+        fused: run the fused Pallas kernel (True) or the dense route
+        (False); None applies the dispatch rule every shortlist site
+        shares (`sharded._use_fused`) to this call's (B, N) with the
+        engine's `fused_min_rows`. The dense route on a kernel backend is
+        the same XLA dot against `proj` + lax.top_k as every other site
+        (`sharded._local_shortlist`); 'ref' keeps its readable
+        recomputation from s_values.
         """
+        from repro.engine.sharded import _local_shortlist, _use_fused
         from repro.kernels import ops as kernel_ops
         cfg = self.cfg
         assert cfg.mode == "avss", "shortlists use the AVSS LUT"
         k = min(k, s_values.shape[0])
         backend = self.resolved_backend
-        if fused_min_rows is None:
-            fused_min_rows = self.fused_min_rows
+        if fused is None:
+            fused = _use_fused(backend, q_values.shape[0], s_values.shape[0],
+                               self.fused_min_rows)
         with jax.named_scope(hc.SHORTLIST_SCOPE):
-            if backend == "fused" or (
-                    backend != "ref" and s_values.shape[0] >= fused_min_rows):
+            if fused:
                 return kernel_ops.lut_shortlist(
                     q_values, s_values, cfg.enc, k, valid=valid, proj=proj,
                     packed=packed, pack_bits=pack_bits)
-            if backend == "ref":
-                lut = jnp.asarray(enc_lib.avss_sum_lut(cfg.enc), jnp.float32)
-                dist = ref_kernels.avss_dist_ref(q_values, s_values, lut)
-            else:  # pallas / mxu: LUT matmul kernel
-                dist = kernel_ops.avss_ideal_dist(q_values, s_values,
-                                                  cfg.enc, proj=proj)
+            if backend != "ref":
+                if proj is None:
+                    proj = kernel_ops.support_projection(s_values, cfg.enc)
+                q1h = kernel_ops.query_onehot(q_values, proj.dtype)
+                return _local_shortlist(q1h, proj, valid, k, fused=False)
+            lut = jnp.asarray(enc_lib.avss_sum_lut(cfg.enc), jnp.float32)
+            dist = ref_kernels.avss_dist_ref(q_values, s_values, lut)
             if valid is not None:
                 dist = dist + jnp.where(
                     valid, 0.0, kernel_ops.SHORTLIST_MASK_PENALTY)[None]
@@ -699,15 +693,15 @@ class RetrievalEngine:
                   proj: jax.Array | None = None,
                   packed: jax.Array | None = None,
                   pack_bits: int | None = None,
-                  fused_min_rows: int | None = None,
+                  fused: bool | None = None,
                   noise_qidx: jax.Array | None = None
                   ) -> dict[str, jax.Array]:
         """Shortlist + exact noisy rescore (beyond-paper TPU pipeline).
 
         s_grid / proj: optional write-time layouts (MemoryStore fields);
         omitted -> recomputed here, read-time, with identical results.
-        fused_min_rows: phase-1 fused-kernel threshold override (see
-        `shortlist`); None defers to the engine's field.
+        fused: phase-1 route (see `shortlist`); None applies the dispatch
+        rule.
         noise_qidx: optional (B,) per-query noise coordinates for the
         rescore (see `full`); default arange(B).
         Returns {votes (B, k), dist (B, k) ideal shortlist distances
@@ -719,8 +713,7 @@ class RetrievalEngine:
         cfg = self.cfg
         dist, idx = self.shortlist(q_values, s_values, k, valid=valid,
                                    proj=proj, packed=packed,
-                                   pack_bits=pack_bits,
-                                   fused_min_rows=fused_min_rows)
+                                   pack_bits=pack_bits, fused=fused)
         with jax.named_scope(hc.RESCORE_SCOPE):
             q_grid, s_grid, weights, thresholds = self._grids(
                 q_values, s_values, s_grid)
@@ -739,8 +732,8 @@ class RetrievalEngine:
         """Two-phase search with the store row-sharded over mesh `axes`.
 
         Bit-identical to `two_phase` on a single device: each shard
-        shortlists its rows (fused Pallas kernel above the engine's
-        `fused_min_rows` threshold, dense matmul below), rescores its local
+        shortlists its rows (dense XLA dot + top_k, or the fused Pallas
+        kernel where `sharded._use_fused` says so), rescores its local
         candidates with GLOBAL support indices feeding the noise counters,
         and the candidate sets are all-gathered and merged by (distance,
         global index). See repro/engine/sharded.py for the exactness

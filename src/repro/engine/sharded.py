@@ -18,14 +18,16 @@ Two flavours:
                              path formerly inlined in core/memory.py).
 
 Both paths share ONE per-shard shortlist implementation with the unsharded
-engine: when a shard's local rows reach `fused_min_rows` (or the backend is
-'fused'), phase 1 runs the fused Pallas shortlist kernel
-(kernels/shortlist.py, HBM O(B*k_loc + N_loc*4d)) inside the shard_map
-body -- masked rows (ragged pads, empty slots) are penalised NATIVELY in
-the kernel with the integer-exact SHORTLIST_MASK_PENALTY, and ragged
-(non-tile-aligned) local blocks are padded inside the kernel wrapper.
-Below the threshold (and on the 'ref' backend) the readable dense local
-matmul + lax.top_k remains, bit-identically.
+engine (`_use_fused` + `_local_shortlist`): phase 1 is one MXU dot of the
+query one-hots against the shard's LUT projection, the mask penalty and
+XLA's exact lax.top_k, wherever the (B, N_loc) f32 distance block fits
+SHORTLIST_DENSE_MAX_BYTES. Only a block over that budget (or the 'fused'
+backend, or an explicit `fused_min_rows`) runs the fused Pallas shortlist
+kernel (kernels/shortlist.py, HBM O(B*k_loc + N_loc*4d)) inside the
+shard_map body -- masked rows (ragged pads, empty slots) are penalised
+NATIVELY in the kernel with the integer-exact SHORTLIST_MASK_PENALTY, and
+ragged (non-tile-aligned) local blocks are padded inside the kernel
+wrapper. Both routes are bit-identical.
 
 Exactness argument for the two-phase path (verified by
 tests/test_engine.py::test_sharded_two_phase_bit_identical):
@@ -79,31 +81,52 @@ def _gather_candidates(x: jax.Array, axes: Sequence[str]) -> jax.Array:
     return jnp.moveaxis(stacked, 0, 1).reshape(x.shape[0], -1)
 
 
-def _use_fused(backend: str, rows_loc: int,
+# Largest (B, N_loc) f32 distance block the dense phase-1 route may
+# materialise before lax.top_k. On a TPU v5e XLA's dense dot + exact top_k
+# beats the fused Pallas kernel by 11x at B=64 over 1,048,576 rows (9.0 ms
+# against 101.0 ms), so the dense route runs wherever its block fits: 1 GiB
+# is under 7% of a v5e's 15.75 GiB of HBM, room the store and the phase-2
+# operands leave over. A larger block (a big multi-tenant flush, a huge
+# shard) streams through the kernel, whose HBM stays O(B*k + N*4d).
+SHORTLIST_DENSE_MAX_BYTES = 1 << 30
+
+
+def _use_fused(backend: str, batch: int, rows_loc: int,
                fused_min_rows: int | None) -> bool:
-    """Shared shard-local dispatch rule: the fused Pallas shortlist kernel
-    engages on any kernel backend once a shard's local rows reach the
-    threshold, and always on the 'fused' backend; the 'ref' backend (and
-    fused_min_rows=None, the raw-array default) keeps the dense local
-    matmul as the readable reference."""
+    """Shared phase-1 dispatch rule, applied to the shape of the block one
+    shortlist call ranks (per shard inside the shard_map bodies): the
+    fused Pallas kernel engages on the 'fused' backend, and on any other
+    kernel backend when the dense (batch, rows_loc) f32 distance block
+    would exceed SHORTLIST_DENSE_MAX_BYTES, or when an explicit
+    `fused_min_rows` is reached. 'ref' always keeps the dense route as the
+    readable reference."""
     if backend == "fused":
         return True
-    return (backend != "ref" and fused_min_rows is not None
-            and rows_loc >= fused_min_rows)
+    if backend == "ref":
+        return False
+    if fused_min_rows is not None and rows_loc >= fused_min_rows:
+        return True
+    return batch * rows_loc * 4 > SHORTLIST_DENSE_MAX_BYTES
 
 
 def _local_shortlist(q1h: jax.Array, proj_loc: jax.Array,
-                     valid_loc: jax.Array, k_loc: int, *, fused: bool,
-                     packed: jax.Array | None = None,
+                     valid_loc: jax.Array | None, k_loc: int, *,
+                     fused: bool, packed: jax.Array | None = None,
                      pack_bits: int | None = None
                      ) -> tuple[jax.Array, jax.Array]:
     """Block shortlist shared by every dispatch site (per shard inside the
-    shard_map bodies here, and the unsharded dense `ideal` route in
-    engine.py): top-k_loc of the rows by exact integer LUT distance
-    (+ native mask penalty), fused or dense -- bit-identical either way
+    shard_map bodies here, the unsharded engine, the routed blocks):
+    top-k_loc of the rows by exact integer LUT distance (+ native mask
+    penalty on rows `valid_loc` masks), fused or dense -- bit-identical
     (the kernel reproduces lax.top_k's (distance, row) order). When the
     store provides its bit-packed projection (`packed`/`pack_bits`), the
-    fused kernel streams that 4-8x smaller operand instead of proj_loc."""
+    fused kernel streams that 4-8x smaller operand instead of proj_loc.
+
+    The dense route dots against proj_loc as stored, accumulating in f32:
+    the one-hot is 0/1 and every stored entry is a value of proj_loc's own
+    dtype, so each product is exact and so is any sum below 2**24. A bf16
+    projection therefore needs no per-call f32 copy; an f32 one asks for
+    full precision, which the TPU's default one-pass bf16 would round."""
     with jax.named_scope(hc.SHORTLIST_SCOPE):
         if fused:
             from repro.kernels import shortlist as shortlist_kernel
@@ -114,9 +137,14 @@ def _local_shortlist(q1h: jax.Array, proj_loc: jax.Array,
             return shortlist_kernel.lut_shortlist_pallas(
                 q1h, proj_loc, k_loc, valid=valid_loc)
         from repro.kernels import ops as kernel_ops
-        dist = q1h @ proj_loc.astype(jnp.float32).T        # (B, N_loc)
-        dist = dist + jnp.where(valid_loc, 0.0,
-                                kernel_ops.SHORTLIST_MASK_PENALTY)[None]
+        exact = (None if proj_loc.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+        dist = jax.lax.dot_general(                        # (B, N_loc)
+            q1h.astype(proj_loc.dtype), proj_loc, (((1,), (1,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32)
+        if valid_loc is not None:
+            dist = dist + jnp.where(valid_loc, 0.0,
+                                    kernel_ops.SHORTLIST_MASK_PENALTY)[None]
         neg, idx = jax.lax.top_k(-dist, k_loc)
         return -neg, idx
 
@@ -152,7 +180,7 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
     MemoryStore.proj_packed); the fused per-shard shortlist then streams
     this 4-8x smaller operand instead of proj, bit-identically.
     backend / fused_min_rows: per-shard shortlist dispatch (see
-    `_use_fused`); the default (ref, None) keeps the dense local matmul.
+    `_use_fused`); the default 'ref' keeps the dense local matmul.
     Returns {votes (B, k), dist (B, k), indices (B, k) global rows
     [, labels (B, k)], iterations} -- bit-identical to
     RetrievalEngine.two_phase(q, s, k, valid) on a single device,
@@ -171,7 +199,8 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
         f"(MemoryStore.shard pads ragged splits)")
     k = min(k, N)
     k_loc = min(k, N // n_shards)
-    fused = _use_fused(backend, N // n_shards, fused_min_rows)
+    fused = _use_fused(backend, q_values.shape[0], N // n_shards,
+                       fused_min_rows)
 
     with jax.named_scope(hc.SHORTLIST_SCOPE):
         q1h = kernel_ops.query_onehot(q_values, jnp.float32)   # (B, 4d)
@@ -296,8 +325,8 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
     stay bit-identical to the single-device fused/dense ideal search even
     when masked rows reach the top-k).
     backend / fused_min_rows: per-shard shortlist dispatch (see
-    `_use_fused`); above the threshold each shard streams through the fused
-    Pallas shortlist kernel instead of the dense (B, N_loc) local matmul.
+    `_use_fused`); a shard whose dense (B, N_loc) block is over the budget
+    streams through the fused Pallas shortlist kernel instead.
     packed / enc: optional bit-packed projection (row-sharded,
     MemoryStore.proj_packed) and its encoding; the fused path then streams
     the 4-8x smaller operand, bit-identically.
@@ -306,7 +335,7 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
     """
 
     rows_loc = proj.shape[0] // int(np.prod([mesh.shape[a] for a in axes]))
-    fused = _use_fused(backend, rows_loc, fused_min_rows)
+    fused = _use_fused(backend, q_onehot.shape[0], rows_loc, fused_min_rows)
     extras: list[jax.Array] = []
     extra_specs: list[P] = []
     if packed is not None and (pack_bits is not None or enc is not None):

@@ -237,8 +237,9 @@ def main(argv=None):
                     choices=["auto", "ref", "pallas", "mxu", "fused"])
     ap.add_argument("--retrieval-k", type=int, default=32)
     ap.add_argument("--retrieval-fused-min-rows", type=int, default=None,
-                    help="override the fused-shortlist row threshold "
-                         "(engine.IDEAL_FUSED_MIN_ROWS default; applies "
+                    help="row count from which shortlists run the fused "
+                         "kernel (default: only a dense block over "
+                         "engine.SHORTLIST_DENSE_MAX_BYTES does; applies "
                          "per shard-local block on sharded stores) -- a "
                          "perf knob, results are bit-identical either way")
     ap.add_argument("--retrieval-shards", type=int, default=None,

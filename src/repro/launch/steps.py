@@ -428,8 +428,9 @@ def make_serve_step_with_mcam(cfg: ModelConfig, rules: Rules, mem_cfg,
                    distribution reflects the simulated hardware's
                    similarity judgement, not the ideal distance.
       'ideal'      top-k by exact digital distance only (votes == -dist on
-                   valid candidates) -- the cheapest serving path; at
-                   N >= engine.IDEAL_FUSED_MIN_ROWS it streams through the
+                   valid candidates) -- the cheapest serving path; a
+                   (B, N) distance block over
+                   engine.SHORTLIST_DENSE_MAX_BYTES streams through the
                    fused shortlist kernel instead of the dense matmul.
     nprobe: shards visited per query when the store is partitioned
     (`MemoryStore.shard`); nprobe < n_shards engages the phase-0 router

@@ -179,40 +179,56 @@ def test_ideal_fused_matches_dense_tie_heavy():
 
 
 def test_ideal_routes_through_fused_kernel_at_large_n(monkeypatch):
-    """Acceptance (ISSUE 3): at N >= IDEAL_FUSED_MIN_ROWS the unsharded
-    ideal mode streams through kernels/shortlist.py instead of
-    materialising the dense (B, N) matrix; small stores and the ref
-    backend keep the dense reference."""
-    from repro.engine import MemoryStore, SearchRequest
-    from repro.engine.engine import IDEAL_FUSED_MIN_ROWS
+    """Phase 1 follows the size of the dense block: under
+    SHORTLIST_DENSE_MAX_BYTES a kernel backend takes XLA's dense dot +
+    lax.top_k (no fused kernel call, no `shortlist_fused` tag in the
+    compiled search), over it (the budget forced small) the fused Pallas
+    kernel; the ref backend never fuses. Both routes are bit-identical to
+    ref on a ragged (1,100-row), tie-heavy store whose masked rows reach
+    the top-k, in `ideal` and in `two_phase`."""
+    from repro.engine import MemoryStore, SearchRequest, sharded
     from repro.kernels import ops as kernel_ops
     cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="auto")
-    N = IDEAL_FUSED_MIN_ROWS
-    sv = jnp.tile(jax.random.randint(jax.random.PRNGKey(0), (128, 16), 0,
-                                     cfg.enc.levels), (N // 128, 1))
-    store = MemoryStore.from_quantized(
-        sv, jnp.arange(N, dtype=jnp.int32) % 17, cfg)
-    small = MemoryStore.from_quantized(
-        sv[:64], jnp.arange(64, dtype=jnp.int32), cfg)
+    N = 1100                    # past the old 1024-row threshold, ragged
+    sv = jnp.tile(jax.random.randint(jax.random.PRNGKey(0), (100, 16), 0,
+                                     cfg.enc.levels), (N // 100, 1))
+    labels = jnp.where(jnp.arange(N) % 4 == 0, -1,
+                       jnp.arange(N) % 17).astype(jnp.int32)
+    store = MemoryStore.from_quantized(sv, labels, cfg)
     qv = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, 4)
-    req = SearchRequest(mode="ideal", k=16)
-
     calls = []
     orig = kernel_ops.lut_shortlist
     monkeypatch.setattr(kernel_ops, "lut_shortlist",
                         lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
     eng = RetrievalEngine(cfg)                  # auto -> pallas (kernels)
-    assert eng.resolved_backend != "ref"
-    fused_res = eng.search(store, qv, req)
-    assert len(calls) == 1, "large-N ideal must use the fused shortlist"
-    eng.search(small, qv, req)
-    assert len(calls) == 1, "small-N ideal keeps the dense matmul"
-    ref_res = RetrievalEngine(cfg, backend="ref").search(store, qv, req)
-    assert len(calls) == 1, "ref backend keeps the dense reference"
-    for key in ("votes", "dist", "indices", "labels"):
-        np.testing.assert_array_equal(np.asarray(getattr(ref_res, key)),
-                                      np.asarray(getattr(fused_res, key)),
-                                      err_msg=key)
+    assert eng.resolved_backend != "ref" and eng.fused_min_rows is None
+    ref_eng = RetrievalEngine(cfg, backend="ref")
+    budget = sharded.SHORTLIST_DENSE_MAX_BYTES
+    for mode in ("ideal", "two_phase"):
+        calls.clear()
+        monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", budget)
+        req = SearchRequest(mode=mode, k=840)    # 825 valid rows: masked
+        ref_res = ref_eng.search(store, qv, req)  # rows reach the top-k
+        assert np.isneginf(np.asarray(ref_res.votes)).any(), mode
+        compiled = lambda r=req: jax.jit(
+            lambda st, q: eng.search(st, q, r).votes
+        ).lower(store, qv).compile().as_text()
+
+        dense_res = eng.search(store, qv, req)
+        assert not calls, "a block under the budget takes the dense route"
+        hc.assert_fused_tag(compiled(), False)
+        monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                            3 * N * 4 - 1)
+        fused_res = eng.search(store, qv, req)
+        assert len(calls) == 1, "a block over the budget streams the kernel"
+        ref_eng.search(store, qv, req)
+        assert len(calls) == 1, "ref backend keeps the dense reference"
+        hc.assert_fused_tag(compiled(), True)
+        for got in (dense_res, fused_res):
+            for key in ("votes", "dist", "indices", "labels"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(ref_res, key)),
+                    np.asarray(getattr(got, key)), err_msg=f"{mode}/{key}")
 
 
 # ---------------------------------------------------------------------------
@@ -373,12 +389,12 @@ def test_sharded_fused_shortlist_matches_dense_and_unsharded():
 
 
 def test_fused_min_rows_knob_engine_and_request(monkeypatch):
-    """IDEAL_FUSED_MIN_ROWS is a default, not a constant: the engine field
-    and the per-request SearchRequest.fused_min_rows override both steer
-    the dispatch (request wins), so a TPU-measured crossover applies with
-    no code change."""
-    from repro.engine import MemoryStore, SearchRequest
-    from repro.engine.engine import IDEAL_FUSED_MIN_ROWS
+    """The default is no row threshold (the shape rule decides), and an
+    explicit one still forces the kernel: the engine field and the
+    per-request SearchRequest.fused_min_rows override both steer the
+    dispatch (request wins), the 'fused' backend always fuses, and a
+    block over the budget fuses whatever the threshold."""
+    from repro.engine import MemoryStore, SearchRequest, sharded
     from repro.kernels import ops as kernel_ops
     cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="auto")
     sv = jax.random.randint(jax.random.PRNGKey(0), (64, 16), 0,
@@ -391,9 +407,9 @@ def test_fused_min_rows_knob_engine_and_request(monkeypatch):
     monkeypatch.setattr(kernel_ops, "lut_shortlist",
                         lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
     default_eng = RetrievalEngine(cfg)
-    assert default_eng.fused_min_rows == IDEAL_FUSED_MIN_ROWS
+    assert default_eng.fused_min_rows is None
     req = SearchRequest(mode="ideal", k=8)
-    default_eng.search(store, qv, req)           # 64 < 4096: dense
+    default_eng.search(store, qv, req)           # 3 x 64 block: dense
     assert not calls
     low_eng = RetrievalEngine(cfg, fused_min_rows=8)
     low_eng.search(store, qv, req)               # 64 >= 8: fused
@@ -410,6 +426,13 @@ def test_fused_min_rows_knob_engine_and_request(monkeypatch):
                        SearchRequest(mode="two_phase", k=8,
                                      fused_min_rows=16))
     assert len(calls) == 3
+    RetrievalEngine(cfg, backend="fused").search(store, qv, req)
+    assert len(calls) == 4
+    # over the budget the kernel runs even under a threshold not reached
+    monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", 3 * 64 * 4 - 1)
+    low_eng.search(store, qv,
+                   SearchRequest(mode="ideal", k=8, fused_min_rows=1 << 30))
+    assert len(calls) == 5
 
 
 @pytest.mark.slow

@@ -95,15 +95,11 @@ def test_mcam_search_kernel_compiles(one_chip, noisy):
     assert "tpu_custom_call" in text
 
 
-def test_two_phase_search_ops_map_to_layers(one_chip):
-    """The served two_phase search at real widths, compiled for the chip:
-    every op that does work, the copies the TPU compiler inserts among
-    them, maps to a layer of the program (analysis.hlo_contracts
-    .op_layers), and the relayout of `s_grid` the rescore gathers from is
-    counted in the rescore."""
+def _two_phase_search_text(one_chip) -> str:
+    """The served two_phase search at real widths (B=64, k=32 over N
+    rows), compiled for the chip."""
     import dataclasses
 
-    from repro.analysis import hlo_contracts as hc
     from repro.core.avss import SearchConfig
     from repro.core.memory import MemoryConfig
     from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
@@ -126,9 +122,27 @@ def test_two_phase_search_ops_map_to_layers(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         # the kernels take their compiled branch for the described chip
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        text = _compiled_text(search, store, q)
-    assert "tpu_custom_call" in text
+        return _compiled_text(search, store, q)
+
+
+def test_two_phase_search_ops_map_to_layers(one_chip):
+    """The served two_phase search at real widths, compiled for the chip:
+    its (B, N) distance block is under the dense budget, so phase 1 is
+    XLA's dot and exact top-k with no Pallas kernel at all; every op that
+    does work, the copies the TPU compiler inserts among them, maps to a
+    layer of the program (analysis.hlo_contracts.op_layers), and the
+    relayout of `s_grid` the rescore gathers from is counted in the
+    rescore."""
+    from repro.analysis import hlo_contracts as hc
+
+    text = _two_phase_search_text(one_chip)
+    assert hc.scoped_lines(text, hc.FUSED_SCOPE_TAG) == []
+    assert "tpu_custom_call" not in text
     _, ops = hc.parse_hlo(text)
+    phase1 = [o for o in ops
+              if hc.innermost_layer(o.op_name) == hc.SHORTLIST_SCOPE]
+    assert any(o.opcode in ("dot", "convolution") for o in phase1)
+    assert any(o.op_name.endswith("/top_k") for o in phase1)
     layers = hc.op_layers(text)
     opcode = {o.name: o.opcode for o in ops}
     assert [n for n, layer in layers.items()
@@ -141,3 +155,18 @@ def test_two_phase_search_ops_map_to_layers(one_chip):
                        if f"%{o.name} = " in line)]
     assert grid_copies and all(layers[c] == hc.RESCORE_SCOPE
                                for c in grid_copies)
+
+
+def test_two_phase_search_over_budget_compiles_fused_kernel(one_chip):
+    """The same search with its (B, N) block over the dense budget (the
+    budget cut to just under B * N * 4 bytes) compiles the fused Pallas
+    shortlist kernel into phase 1."""
+    from repro.analysis import hlo_contracts as hc
+    from repro.engine import sharded
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", B * N * 4 - 1)
+        text = _two_phase_search_text(one_chip)
+    fused = hc.scoped_lines(text, hc.FUSED_SCOPE_TAG)
+    assert fused and all(hc.SHORTLIST_SCOPE in line for line in fused)
+    assert any("tpu_custom_call" in line for line in fused)
