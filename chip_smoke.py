@@ -15,7 +15,9 @@
     queries, and the `ideal` top-k against an independent float32 brute
     force over the stored values.
 (b) kNN-LM decode: `launch.serve.serve("starcoder2-3b", smoke=False, ...)`
-    at its published widths with two-phase MCAM retrieval, batch 4.
+    at its published widths, batch 4: prompts prefilled into the sliding-
+    window ring, a d_model-wide datastore of the decoder's own hidden
+    states sized to the free HBM, two-phase MCAM retrieval every step.
 
 `--chips 4` runs only the four-chip path: a streaming write into a store
 row-sharded over a 4-device mesh, `two_phase` and `ideal` search on it, and
@@ -246,6 +248,7 @@ def phase_retrieval(sizes: Sizes, key) -> None:
 
 
 def phase_decode(sizes: Sizes) -> None:
+    import numpy as np
     from repro.launch.serve import serve
     out = serve("starcoder2-3b", smoke=sizes.decode_smoke,
                 batch=sizes.decode_batch, steps=sizes.decode_steps,
@@ -254,10 +257,13 @@ def phase_decode(sizes: Sizes) -> None:
     toks = out["tokens"]
     if toks.shape != (sizes.decode_batch, sizes.decode_steps):
         raise AssertionError(f"decode returned tokens {toks.shape}")
+    warm = np.asarray(out["latencies_s"][1:]) * 1e3
     log(f"decode starcoder2-3b smoke={sizes.decode_smoke} batch "
-        f"{sizes.decode_batch} retrieval two-phase: first step "
+        f"{sizes.decode_batch}, prompt {sizes.prompt_len} prefilled, "
+        f"retrieval two-phase over a d_model store: first step "
         f"{out['compile_s']:.3f} s (compile included), "
-        f"{sizes.decode_steps} steps in {out['decode_s']:.3f} s "
+        f"{sizes.decode_steps} steps in {out['decode_s']:.3f} s, median "
+        f"warm step {float(np.median(warm)) if warm.size else 0.0:.3f} ms "
         f"(smoke timing, not a benchmark)")
 
 

@@ -1,7 +1,7 @@
 """End-to-end SERVING driver (the paper's kind: efficient retrieval):
-a small LM decodes batched requests with an MCAM-backed kNN memory fused
-into the logits -- the production `serve_step` the 40-cell dry-run lowers,
-executed for real at reduced scale.
+a small LM decodes batched requests with an MCAM-backed kNN-LM datastore
+fused into its distribution -- the production serve step the dry-run
+lowers, executed for real at reduced scale.
 
     PYTHONPATH=src python examples/serve_retrieval.py \
         [--arch starcoder2-3b] [--batch 4] [--steps 12] [--lam 0.3]
@@ -15,9 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import load_config
-from repro.core.avss import SearchConfig
-from repro.core.memory import MemoryConfig
-from repro.engine import MemoryStore
+from repro.launch import serve as serve_lib
 from repro.launch import steps as steps_lib
 from repro.models import transformer as tfm
 from repro.models.sharding import Rules
@@ -29,6 +27,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--steps", type=int, default=12)
     ap.add_argument("--prompt-len", type=int, default=16)
+    ap.add_argument("--rows", type=int, default=1024)
     ap.add_argument("--lam", type=float, default=0.3)
     args = ap.parse_args()
 
@@ -39,49 +38,52 @@ def main():
     B, P = args.batch, args.prompt_len
     max_seq = P + args.steps
 
-    # --- the MCAM memory: token-labelled embedding store (kNN-LM head) ---
-    # programmed ONCE at write time (quantized values + LUT projection +
-    # string-grid layout); the decode loop jits against the constants
-    mem_cfg = MemoryConfig(
-        capacity=1024, dim=min(48, cfg.d_model),
-        search=SearchConfig("mtmc", cl=8, mode="avss", use_kernel="ref"))
-    demo_vecs = jax.random.normal(jax.random.PRNGKey(7), (256, mem_cfg.dim))
-    demo_tok = jax.random.randint(jax.random.PRNGKey(8), (256,), 0,
-                                  cfg.vocab_size)
-    mstate = (MemoryStore.create(mem_cfg).calibrate(demo_vecs)
-              .write(demo_vecs, demo_tok))
+    # --- the kNN-LM datastore: the decoder's own hidden states over a
+    # token stream, each keyed to the next token, programmed ONCE into the
+    # MCAM store (quantized values + LUT projection + string-grid layout)
+    mem_cfg = serve_lib.datastore_config(cfg, args.rows)
+    chunk = min(serve_lib.DATASTORE_CHUNK, args.rows)
+    stream = jax.random.randint(jax.random.PRNGKey(8), (args.rows + 1,), 0,
+                                cfg.vocab_size)
+    t0 = time.time()
+    keys = serve_lib.datastore_keys(params, cfg, rules, stream, args.rows,
+                                    chunk)
+    store = serve_lib.build_datastore(mem_cfg, keys, stream[1:args.rows + 1],
+                                      min(256, args.rows))
+    print(f"datastore: {args.rows} rows of d={cfg.d_model} in "
+          f"{time.time() - t0:.1f}s")
 
-    serve_step = steps_lib.make_serve_step_with_mcam(cfg, rules, mem_cfg,
-                                                     lam=args.lam)
-    jstep = jax.jit(serve_step)
-    plain_step = jax.jit(steps_lib.make_serve_step(cfg, rules))
+    step = steps_lib.make_serve_step_with_mcam(cfg, rules, mem_cfg,
+                                               lam=args.lam)
+    prefill = steps_lib.make_prefill_into_step(cfg, rules, max_seq)
 
-    # --- batched requests: prefill then decode ---
+    # --- batched requests: each prompt prefilled into the decode cache
     prompts = jax.random.randint(key, (B, P), 0, cfg.vocab_size)
     caches = tfm.init_cache(cfg, B, max_seq)
-    print(f"prefilling {B} requests of {P} tokens ...")
-    t0 = time.time()
-    for t in range(P):  # teacher-forced prefill through the decode path
-        logits, caches = plain_step(params, caches,
-                                    {"tokens": prompts[:, t:t + 1]},
-                                    jnp.int32(t))
-    print(f"  prefill {time.time()-t0:.1f}s")
+    first = []
+    for b in range(B):
+        logits, caches = prefill(params, caches, prompts[b:b + 1],
+                                 jnp.int32(b))
+        first.append(jnp.argmax(logits[:, 0], -1))
+    tok = jnp.concatenate(first).astype(jnp.int32)[:, None]
 
-    tok = jnp.argmax(logits[:, 0], -1)[:, None]
-    outs = [tok]
+    outs, moved = [tok], 0
     t0 = time.time()
     for i in range(args.steps):
-        logits, caches = jstep(params, caches, {"tokens": tok},
-                               jnp.int32(P + i), mstate)
-        tok = jnp.argmax(logits[:, 0], -1)[:, None]
+        out, caches = step(params, caches, {"tokens": tok},
+                           jnp.int32(P + i), store)
+        moved += int(np.sum(np.asarray(out["token"]) != np.asarray(
+            jnp.argmax(out["lm_logprobs"], -1))))
+        tok = out["token"][:, None]
         outs.append(tok)
     dt = time.time() - t0
     gen = np.asarray(jnp.concatenate(outs, 1))
     print(f"decoded {args.steps} steps x {B} requests in {dt:.1f}s "
-          f"({args.steps * B / dt:.1f} tok/s on CPU, MCAM-fused logits)")
+          f"({args.steps * B / dt:.1f} tok/s on CPU); the kNN head changed "
+          f"{moved} of {args.steps * B} greedy tokens")
     for b in range(B):
         print(f"  req{b}: {gen[b].tolist()}")
-    assert np.isfinite(np.asarray(logits, np.float32)).all()
+    assert np.isfinite(np.asarray(out["logprobs"])).all()
     print("OK: serve_step_with_mcam end-to-end")
 
 

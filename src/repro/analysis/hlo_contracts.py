@@ -64,8 +64,17 @@ MERGE_SCOPE = "search_merge"           # sharded all-gather + top-k merge
 ANSWER_SCOPE = "search_answer"         # label gather, vote mask, predict
 WRITE_SCOPE = "store_write"            # MemoryStore.write
 CALIBRATE_SCOPE = "store_calibrate"    # MemoryStore.calibrate
+# The kNN-LM decode step (launch/steps.make_serve_step_with_mcam) and the
+# serving prefill: the decoder (embedding, every layer, final norm, LM head
+# and its log-softmax), the kNN head around the store search (query, vote
+# softmax, mixture, greedy token; the search keeps its own scopes inside),
+# and the prompt forward that fills the decode cache.
+DECODER_SCOPE = "decoder"              # models/transformer.decode_step
+KNN_SCOPE = "knn_head"                 # the kNN-LM head of the serve step
+PREFILL_SCOPE = "prefill"              # models/transformer.prefill
 LAYER_SCOPES = (QUANTIZE_SCOPE, ROUTE_SCOPE, SHORTLIST_SCOPE, RESCORE_SCOPE,
-                MERGE_SCOPE, ANSWER_SCOPE, WRITE_SCOPE, CALIBRATE_SCOPE)
+                MERGE_SCOPE, ANSWER_SCOPE, WRITE_SCOPE, CALIBRATE_SCOPE,
+                DECODER_SCOPE, KNN_SCOPE, PREFILL_SCOPE)
 
 # Opcodes that move or name data without computing it. One that carries no
 # layer of its own (a copy or bitcast that layout assignment inserted, an

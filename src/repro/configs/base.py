@@ -59,10 +59,12 @@ class ModelConfig:
     window: int = 0                # sliding-window size for 'swa' layers
     # flavour flags
     qkv_bias: bool = False
+    o_bias: bool = False           # bias on the attention output projection
     mlp_bias: bool = False
     mlp_gated: bool = True         # SwiGLU vs plain 2-matrix MLP
     mlp_act: str = "silu"
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     parallel_block: bool = False   # command-r style attn || mlp
     tie_embeddings: bool = False
     rope_type: str = "rope"        # rope | mrope | none

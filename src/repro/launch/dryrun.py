@@ -123,7 +123,8 @@ def _compile_step(cfg, shape, mesh, rules, tc, retrieval, unroll=False):
                 from repro.engine import MemoryStore
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
-                mem_cfg = MemoryConfig(capacity=131072, dim=48)
+                # the kNN-LM datastore: d_model-wide hidden-state keys
+                mem_cfg = MemoryConfig(capacity=131072, dim=cfg.d_model)
                 # calibrate the abstract store: the serving store is always
                 # calibrated before decode, and quantize_queries refuses
                 # float queries on a never-calibrated store

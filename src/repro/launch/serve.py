@@ -1,11 +1,21 @@
-"""Serving launcher: batched-request decode loop with optional MCAM
-retrieval fusion (reduced configs run on CPU; the dry-run lowers the same
-serve_step for the production meshes).
+"""Serving launcher: batched-request decode with an optional kNN-LM head
+served from the MCAM store (reduced configs run on CPU; the dry-run lowers
+the same serve step for the production meshes).
 
     PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b --smoke \
         --batch 4 --steps 16 [--retrieval]
 
-`--no-smoke` serves the published config at its full widths.
+`--no-smoke` serves the published config at its full widths. A request's
+prompt is prefilled in one forward pass straight into the decode cache
+(`steps.make_prefill_into_step`; a sliding-window layer's cache is a ring
+of `window` slots), then every step decodes one token for every request
+through the donated cache. With `--retrieval` the datastore is built first
+(`datastore_keys`, `build_datastore`): the decoder's post-final-norm
+hidden states over a seeded token stream standing for the corpus, each
+keyed to the token that follows it, programmed into a d_model-wide
+`MemoryStore` sized to the HBM the weights and the cache leave; each step
+then searches it with the step's own hidden state and mixes the vote
+distribution into the LM's (`steps.make_serve_step_with_mcam`).
 
 Multi-tenant retrieval serving (PR 9): `TenantServer` below is the minimal
 coalescing shell over `RetrievalEngine.search_tenants` -- concurrent
@@ -136,83 +146,163 @@ def serve_tenants(n_tenants: int, steps: int, batch: int, dim: int = 16,
     return preds
 
 
+DATASTORE_CHUNK = 6144       # tokens per datastore prefill
+DATASTORE_WRITE = 2048       # rows per MemoryStore.write
+STORE_ROW_MULTIPLE = 8192    # a store sized to free HBM is a multiple of this
+# HBM kept free beside a d_model store: the temporaries of its write
+# (4.5 GiB compiled for a v5e at 49,152-65,536 rows of d = 3,072)
+WRITE_HEADROOM_BYTES = 4.5 * 2 ** 30
+
+
+def datastore_config(cfg, rows: int, search=None):
+    """MemoryConfig of a kNN-LM datastore keyed by d_model hidden states
+    (MTMC CL=8, AVSS queries unless `search` says otherwise)."""
+    from repro.core.avss import SearchConfig
+    from repro.core.memory import MemoryConfig
+    search = search or SearchConfig("mtmc", cl=8, mode="avss")
+    return MemoryConfig(capacity=rows, dim=cfg.d_model, search=search)
+
+
+def sized_store_rows(cfg, batch: int, max_seq: int) -> int | None:
+    """Store rows that fit the device memory the weights (already placed)
+    and a batch's decode cache leave, with WRITE_HEADROOM_BYTES to spare:
+    a multiple of STORE_ROW_MULTIPLE; None where the device reports no
+    memory."""
+    from repro.engine import MemoryStore
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    nbytes = lambda tree: sum(a.size * a.dtype.itemsize
+                              for a in jax.tree_util.tree_leaves(tree))
+    cache = nbytes(jax.eval_shape(lambda: tfm.init_cache(cfg, batch,
+                                                         max_seq)))
+    one = jax.eval_shape(lambda: MemoryStore.create(
+        datastore_config(cfg, STORE_ROW_MULTIPLE)))
+    row = nbytes(one) / STORE_ROW_MULTIPLE
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0) - cache \
+        - WRITE_HEADROOM_BYTES
+    return max(0, int(free / row)) // STORE_ROW_MULTIPLE * STORE_ROW_MULTIPLE
+
+
+def datastore_keys(params, cfg, rules, stream, rows: int,
+                   chunk: int = DATASTORE_CHUNK) -> np.ndarray:
+    """The keys of a kNN-LM datastore over a token stream (at least
+    ceil(rows / chunk) * chunk tokens): each chunk of the stream is
+    prefilled as a sequence of its own, and the post-final-norm hidden
+    state at each position is a key. Returns (rows, d_model) float32 on
+    the host; the row at stream position t is labelled stream[t + 1]."""
+    keys_of = jax.jit(lambda p, t: tfm.prefill(
+        p, cfg, {"tokens": t}, t.shape[1], rules)[1][0].astype(jnp.float32))
+    return np.concatenate([np.asarray(keys_of(params,
+                                              stream[None, c0:c0 + chunk]))
+                           for c0 in range(0, rows, chunk)])[:rows]
+
+
+def build_datastore(mem_cfg, keys, labels,
+                    write_batch: int = DATASTORE_WRITE):
+    """A new `MemoryStore` holding keys (N, d) with labels (N,), N the
+    store's capacity: calibrated on the first write batch and programmed
+    through `MemoryStore.write`, `write_batch` rows at a time."""
+    from repro.engine import MemoryStore
+    n = mem_cfg.capacity
+    if n % write_batch:
+        raise ValueError(f"write batches of {write_batch} rows must tile "
+                         f"the store ({n})")
+    write = jax.jit(lambda st, v, lab: st.write(v, lab), donate_argnums=0)
+    store = jax.jit(lambda: MemoryStore.create(mem_cfg))()
+    store = store.calibrate(jnp.asarray(keys[:write_batch]))
+    for s in range(0, n, write_batch):
+        store = write(store, keys[s:s + write_batch],
+                      labels[s:s + write_batch])
+    return store
+
+
 def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
           retrieval: bool = False, retrieval_mode: str = "two-phase",
           retrieval_backend: str = "auto", retrieval_k: int = 32,
           retrieval_fused_min_rows: int | None = None,
           retrieval_shards: int | None = None,
           retrieval_nprobe: int | None = None) -> dict:
-    """Decode `steps` tokens for `batch` requests after a `prompt_len`
-    random prompt. Returns the tokens (batch, steps), `compile_s` (the
-    first step, compile included, to its result) and `decode_s` (the
-    decode loop, which reads every token back to the host)."""
+    """Prefill `batch` seeded prompts of `prompt_len` tokens into the decode
+    cache, then greedily decode `steps` tokens for each, reading every
+    step's tokens back to the host before the next. With `retrieval`, a
+    kNN-LM datastore sized to the free HBM (1,024 rows on a device that
+    reports no memory) is built first. Returns the tokens (batch, steps),
+    `compile_s` (the first step, compile included, to its tokens),
+    `decode_s` (the whole loop) and `latencies_s`, each step's time from
+    issue to tokens on the host."""
     cfg = load_config(arch, smoke=smoke)
     rules = Rules(batch=(), fsdp=(), tensor=(), expert=())
+    key = jax.random.PRNGKey(0)
     # jitted so a published-width init never materialises its f32 draws
-    params = jax.jit(partial(tfm.init, cfg=cfg))(jax.random.PRNGKey(0))
+    params = jax.jit(partial(tfm.init, cfg=cfg))(jax.random.fold_in(key, 0))
     max_seq = prompt_len + steps
-    caches = tfm.init_cache(cfg, batch, max_seq)
-    step_fn = jax.jit(steps_lib.make_serve_step(cfg, rules))
+    vocab = cfg.vocab_size
 
-    mstate = mem_cfg = None
+    store = None
     if retrieval:
-        from repro.core.avss import SearchConfig
-        from repro.core.memory import MemoryConfig
-        from repro.engine import MemoryStore, RetrievalEngine
-        mem_cfg = MemoryConfig(capacity=1024, dim=min(48, cfg.d_model),
-                               search=SearchConfig("mtmc", cl=8, mode="avss",
-                                                   use_kernel="ref"))
-        vecs = jax.random.normal(jax.random.PRNGKey(7), (256, mem_cfg.dim))
-        toks = jax.random.randint(jax.random.PRNGKey(8), (256,), 0,
-                                  cfg.vocab_size)
-        # program once at write time (values + proj + s_grid); the decode
-        # loop below jits against the store's constant layouts
-        mstate = MemoryStore.create(mem_cfg).calibrate(vecs).write(vecs, toks)
+        from repro.engine import RetrievalEngine
+        rows = sized_store_rows(cfg, batch, max_seq) or 1024
+        mem_cfg = datastore_config(cfg, rows)
+        chunk = min(DATASTORE_CHUNK, rows)
+        n_tok = -(-rows // chunk) * chunk + 1
+        stream = jax.random.randint(jax.random.fold_in(key, 1), (n_tok,), 0,
+                                    vocab, jnp.int32)
+        keys = datastore_keys(params, cfg, rules, stream, rows, chunk)
+        store = build_datastore(mem_cfg, keys, stream[1:rows + 1],
+                                min(DATASTORE_WRITE, rows))
         if retrieval_shards:
             # logical row partition; with --retrieval-nprobe < shards the
             # decode loop routes through the per-shard sketch
             # (repro/engine/router.py) instead of searching every shard
-            mstate = mstate.shard(n_shards=retrieval_shards)
-        # fused-threshold override (e.g. a TPU-measured dense-vs-fused
-        # crossover) applies engine-wide without a code change
+            store = store.shard(n_shards=retrieval_shards)
         eng_kw = {} if retrieval_fused_min_rows is None else \
             {"fused_min_rows": retrieval_fused_min_rows}
-        engine = (RetrievalEngine(mem_cfg.search, backend=retrieval_backend,
-                                  **eng_kw)
-                  if retrieval_mode in ("two-phase", "ideal") else None)
+        engine = RetrievalEngine(mem_cfg.search, backend=retrieval_backend,
+                                 **eng_kw)
         mode = "ideal" if retrieval_mode == "ideal" else "two_phase"
-        step_fn = jax.jit(steps_lib.make_serve_step_with_mcam(
+        step = steps_lib.make_serve_step_with_mcam(
             cfg, rules, mem_cfg, engine=engine, k=retrieval_k, mode=mode,
-            nprobe=retrieval_nprobe))
+            nprobe=retrieval_nprobe)
+    else:
+        plain = steps_lib.make_serve_step(cfg, rules)
 
-    key = jax.random.PRNGKey(1)
-    tok = jax.random.randint(key, (batch, 1), 0, cfg.vocab_size)
-    compile_s = 0.0
-    for t in range(prompt_len):  # warm the cache with a random prompt
-        t0 = time.perf_counter()
-        args = (params, caches, {"tokens": tok}, jnp.int32(t))
-        out = step_fn(*args, mstate) if retrieval else step_fn(*args)
-        logits, caches = out
-        if t == 0:                                # the first step compiles
-            jax.block_until_ready(logits)
-            compile_s = time.perf_counter() - t0
-        tok = jax.random.randint(jax.random.fold_in(key, t), (batch, 1), 0,
-                                 cfg.vocab_size)
-    t0 = time.perf_counter()
-    toks = []
+        def greedy(params, caches, b, pos):
+            logits, caches = plain(params, caches, b, pos)
+            return {"token": jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+                    }, caches
+        greedy = jax.jit(greedy, donate_argnums=1)
+        step = lambda params, caches, b, pos, _store: greedy(
+            params, caches, b, pos)
+
+    caches = jax.jit(lambda: tfm.init_cache(cfg, batch, max_seq))()
+    prefill = steps_lib.make_prefill_into_step(cfg, rules, max_seq)
+    prompts = jax.random.randint(jax.random.fold_in(key, 2),
+                                 (batch, prompt_len), 0, vocab, jnp.int32)
+    first = []
+    for b in range(batch):
+        logits, caches = prefill(params, caches, prompts[b:b + 1],
+                                 jnp.int32(b))
+        first.append(jnp.argmax(logits[:, 0], -1))
+    tok = jnp.concatenate(first).astype(jnp.int32)[:, None]
+
+    toks, latencies = [], []
+    t_loop = time.perf_counter()
     for i in range(steps):
-        args = (params, caches, {"tokens": tok}, jnp.int32(prompt_len + i))
-        out = step_fn(*args, mstate) if retrieval else step_fn(*args)
-        logits, caches = out
-        tok = jnp.argmax(logits[:, 0], -1)[:, None]
-        toks.append(np.asarray(tok))
-    dt = time.perf_counter() - t0
-    assert np.isfinite(np.asarray(logits, np.float32)).all()
-    print(f"{arch}: first step {compile_s:.2f}s (compile included); "
+        t0 = time.perf_counter()
+        out, caches = step(params, caches, {"tokens": tok},
+                           jnp.int32(prompt_len + i), store)
+        toks.append(np.asarray(out["token"]))
+        latencies.append(time.perf_counter() - t0)
+        tok = out["token"][:, None]
+    dt = time.perf_counter() - t_loop
+    if retrieval:
+        assert np.isfinite(np.asarray(out["logprobs"])).all()
+    print(f"{arch}: first step {latencies[0]:.2f}s (compile included); "
           f"{steps} steps x {batch} reqs in {dt:.2f}s "
           f"({steps * batch / dt:.1f} tok/s)")
-    return {"tokens": np.concatenate(toks, 1), "compile_s": compile_s,
-            "decode_s": dt}
+    return {"tokens": np.stack(toks, 1), "compile_s": latencies[0],
+            "decode_s": dt, "latencies_s": latencies}
 
 
 def main(argv=None):
@@ -225,14 +315,15 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=8)
-    ap.add_argument("--retrieval", action="store_true")
+    ap.add_argument("--retrieval", action="store_true",
+                    help="kNN-LM head: a d_model-wide MCAM datastore of the "
+                         "decoder's own hidden states, sized to the free "
+                         "HBM, searched by every step")
     ap.add_argument("--retrieval-mode", default="two-phase",
-                    choices=["dense", "two-phase", "ideal"],
-                    help="dense: softmax over the whole store (legacy "
-                         "comparison path); two-phase: engine shortlist + "
-                         "exact noisy rescore; ideal: engine top-k by exact "
-                         "digital distance only (cheapest; streams through "
-                         "the fused shortlist kernel at large N)")
+                    choices=["two-phase", "ideal"],
+                    help="two-phase: engine shortlist + exact noisy "
+                         "rescore; ideal: engine top-k by exact digital "
+                         "distance only")
     ap.add_argument("--retrieval-backend", default="auto",
                     choices=["auto", "ref", "pallas", "mxu", "fused"])
     ap.add_argument("--retrieval-k", type=int, default=32)

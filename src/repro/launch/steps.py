@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.analysis import hlo_contracts as hc
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.models import transformer as tfm
 from repro.models.sharding import Rules, rules_for_mesh
@@ -391,11 +392,35 @@ def make_hat_train_steps(apply_fn, hat_cfg, pre_optimizer,
 
 
 def make_prefill_step(cfg: ModelConfig, rules: Rules):
+    """(params, batch) -> (last logits, caches in the decode layout for the
+    batch's own length)."""
     def prefill_step(params, batch):
-        logits, aux, caches = tfm.forward(params, cfg, batch, rules,
-                                          return_cache=True, last_only=True)
+        S = jax.tree_util.tree_leaves(batch)[0].shape[1]
+        logits, _, caches = tfm.prefill(params, cfg, batch, S, rules)
         return logits, caches
     return prefill_step
+
+
+def make_prefill_into_step(cfg: ModelConfig, rules: Rules, max_seq: int):
+    """Serving prefill of one prompt into a batch of decode caches:
+    (params, caches, tokens (1, S), row) -> (last logits (1, 1, V),
+    caches), the prompt's decode-layout cache written at batch row `row`
+    of `caches` (donated: the batch's caches are updated in place).
+    Position slots (`kpos`) are shared by the batch: every row's prompt
+    has the same length."""
+    def put(a, n, row):
+        if a.shape == n.shape:
+            return n.astype(a.dtype)
+        return jax.lax.dynamic_update_slice(
+            a, n.astype(a.dtype), (0, row) + (0,) * (a.ndim - 2))
+
+    def prefill_into(params, caches, tokens, row):
+        logits, _, one = tfm.prefill(params, cfg, {"tokens": tokens},
+                                     max_seq, rules)
+        caches = jax.tree_util.tree_map(lambda a, n: put(a, n, row),
+                                        caches, one)
+        return logits, caches
+    return jax.jit(prefill_into, donate_argnums=1)
 
 
 def make_serve_step(cfg: ModelConfig, rules: Rules):
@@ -406,67 +431,77 @@ def make_serve_step(cfg: ModelConfig, rules: Rules):
     return serve_step
 
 
+# p_kNN is the softmax of the candidates' noisy votes / this
+KNN_VOTE_TEMPERATURE = 10.0
+
+
+def knn_distribution(votes, labels, vocab_size: int):
+    """kNN-LM p_kNN (B, V): the softmax of the candidates' votes /
+    KNN_VOTE_TEMPERATURE, each candidate's weight on its label (the stored
+    next token); invalid candidates (label -1) weigh nothing, and a query
+    with none has an all-zero row."""
+    valid = labels >= 0
+    w = jax.nn.softmax(
+        jnp.where(valid, votes / KNN_VOTE_TEMPERATURE, -1e30), -1)
+    w = jnp.where(valid, w, 0.0)
+    rows = jnp.arange(labels.shape[0])[:, None]
+    return jnp.zeros((labels.shape[0], vocab_size), jnp.float32).at[
+        rows, jnp.where(valid, labels, 0)].add(w)
+
+
 def make_serve_step_with_mcam(cfg: ModelConfig, rules: Rules, mem_cfg,
                               lam: float = 0.3, engine=None, k: int = 32,
                               mode: str = "two_phase",
                               nprobe: int | None = None):
-    """Paper-integrated serving: the decoded hidden state queries the MCAM
-    memory and the vote distribution over memory labels (token ids) mixes
-    with the LM softmax -- a kNN-LM head served from the simulated NAND-CAM.
+    """kNN-LM decode step served from the simulated NAND-CAM (Khandelwal
+    et al., arXiv:1911.00172): the decoder's post-final-norm hidden state
+    (the whole d_model vector the LM head reads) queries the MCAM store,
+    whose rows are such states keyed to the token that followed them, and
+    the served distribution is (1 - lam) p_LM + lam p_kNN.
 
-    The memory argument is a `repro.engine.MemoryStore` (registered pytree):
-    its write-time `proj` / `s_grid` layouts are jit constants of the decode
-    loop, so no step re-runs `layout_support` or `support_projection`.
+    Returns the jitted step (params, caches, batch, pos, store) -> (out,
+    caches), the caches donated (updated in place). `out` holds what the
+    served token came from: `logprobs` (B, V) the served mixture's log,
+    `token` (B,) its argmax (greedy decode), `lm_logprobs` (B, V) the
+    decoder's own, `query` (B, d_model) float32 the vector searched, and the
+    search's k candidates' store rows `indices`, `labels`, `votes` and
+    `dist` (B, k).
 
-    engine=None (default): dense ideal-distance softmax over the whole
-    LUT-projected store (one bf16 matmul, rows sharded over the mesh) --
-    the legacy comparison path; it materialises the (B, N) distance matrix.
-    engine=RetrievalEngine: retrieval through the unified
-    `engine.search(store, q, SearchRequest)` with `mode`:
-      'two_phase'  MXU shortlist + exact noisy vote rescore; the mixture
-                   weights come from the NOISY MCAM VOTES, so the served
-                   distribution reflects the simulated hardware's
+    The store is a `repro.engine.MemoryStore` at `mem_cfg.dim == d_model`;
+    its write-time layouts are arguments of the step, so no step re-runs
+    `layout_support` or `support_projection`. Retrieval is
+    `engine.search(store, q, SearchRequest(mode, k, nprobe))` (default
+    engine: `RetrievalEngine(mem_cfg.search)`):
+      'two_phase'  MXU shortlist + exact noisy vote rescore; p_kNN is the
+                   softmax of the NOISY MCAM VOTES / KNN_VOTE_TEMPERATURE: the
+                   served distribution reflects the simulated hardware's
                    similarity judgement, not the ideal distance.
-      'ideal'      top-k by exact digital distance only (votes == -dist on
-                   valid candidates) -- the cheapest serving path; a
-                   (B, N) distance block over
-                   engine.SHORTLIST_DENSE_MAX_BYTES streams through the
-                   fused shortlist kernel instead of the dense matmul.
+      'ideal'      top-k by exact digital distance only (votes == -dist).
     nprobe: shards visited per query when the store is partitioned
-    (`MemoryStore.shard`); nprobe < n_shards engages the phase-0 router
-    (repro/engine/router.py) -- bit-identical to brute force restricted to
-    the visited shards; None keeps the exhaustive search."""
-    from repro.engine import SearchRequest
+    (`MemoryStore.shard`); nprobe < n_shards engages the phase-0 router;
+    None searches every shard."""
+    from repro.engine import RetrievalEngine, SearchRequest
+    if mem_cfg.dim != cfg.d_model:
+        raise ValueError(f"the kNN-LM store keys are d_model = "
+                         f"{cfg.d_model} hidden states; the store holds "
+                         f"dim {mem_cfg.dim}")
+    engine = engine or RetrievalEngine(mem_cfg.search)
     request = SearchRequest(mode=mode, k=k, nprobe=nprobe)
 
     def serve_step(params, caches, batch, pos, store):
         logits, caches, hidden = tfm.decode_step(
             params, cfg, batch, caches, pos, rules, return_hidden=True)
-        q = hidden[:, 0][:, :mem_cfg.dim]                     # (B, dim)
-        if engine is None:
-            from repro.kernels import ops as kops
-            # ideal AVSS digital distance: one bf16 matmul against the
-            # LUT-projected store (rows sharded over the whole mesh)
-            q1h = kops.query_onehot(store.quantize_queries(q), jnp.float32)
-            dist = q1h @ store.proj.astype(jnp.float32).T     # (B, N)
-            w = jax.nn.softmax(-dist / 10.0, axis=-1)
-            onehot = jax.nn.one_hot(store.labels, cfg.vocab_size,
-                                    dtype=w.dtype)
-            p_mem = w @ onehot                                # (B, V)
-        else:
+        with jax.named_scope(hc.DECODER_SCOPE):
+            lm = jax.nn.log_softmax(logits[:, 0].astype(jnp.float32), -1)
+        with jax.named_scope(hc.KNN_SCOPE):
+            q = hidden[:, 0].astype(jnp.float32)              # (B, d_model)
             res = engine.search(store, q, request)
-            valid = res.labels >= 0                           # (B, k)
-            # weight by the exact noisy votes (higher = more similar); the
-            # -1e30 fill + post-mask keeps an all-invalid row (store
-            # sparser than k) a harmless zero contribution instead of NaN
-            w = jax.nn.softmax(
-                jnp.where(valid, res.votes / 10.0, -1e30), axis=-1)
-            w = w * valid
-            labels = jnp.where(valid, res.labels, 0)
-            onehot = jax.nn.one_hot(labels, cfg.vocab_size, dtype=w.dtype)
-            p_mem = jnp.einsum("bk,bkv->bv", w, onehot)       # (B, V)
-        p_lm = jax.nn.softmax(logits[:, 0], axis=-1)
-        mixed = jnp.log((1 - lam) * p_lm + lam * p_mem + 1e-20)
-        return mixed[:, None], caches
+            p_knn = knn_distribution(res.votes, res.labels, cfg.vocab_size)
+            mixed = jnp.log((1 - lam) * jnp.exp(lm) + lam * p_knn + 1e-20)
+            token = jnp.argmax(mixed, -1).astype(jnp.int32)
+        out = {"logprobs": mixed, "token": token, "lm_logprobs": lm,
+               "query": q, "indices": res.indices, "labels": res.labels,
+               "votes": res.votes, "dist": res.dist}
+        return out, caches
 
-    return serve_step
+    return jax.jit(serve_step, donate_argnums=1)

@@ -116,7 +116,8 @@ def norm_init(cfg: ModelConfig, dim=None):
 _OPT = int(_os.environ.get("REPRO_OPT", "0") or 0)
 
 
-def apply_norm(p, x, cfg: ModelConfig, eps=1e-6):
+def apply_norm(p, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     if _OPT >= 4:
         # Perf iteration 4: statistics in f32, MULTIPLY in the compute dtype
         # -- keeps the residual stream free of f32 consumers so the
@@ -273,6 +274,8 @@ def attn_init(key, cfg: ModelConfig, dtype):
         p["bq"] = jnp.zeros((cfg.n_heads, hd), dtype)
         p["bk"] = jnp.zeros((cfg.n_kv_heads, hd), dtype)
         p["bv"] = jnp.zeros((cfg.n_kv_heads, hd), dtype)
+    if cfg.o_bias:
+        p["bo"] = jnp.zeros((cfg.d_model,), dtype)
     return p
 
 
@@ -311,25 +314,65 @@ def attn_apply(p, x, cfg: ModelConfig, *, layer_window=0, cache=None,
                           softcap=cfg.logit_softcap)
         new_cache = {"k": k, "v": v, "kpos": qpos}
     else:
-        # decode: write this step's k/v at slot (ring for SWA layers)
+        # decode: attend over the cache as it stands, its slot for this
+        # position masked, and over this step's own k/v; the caller writes
+        # k/v into the slot afterwards (`attn_cache_write`), so a donated
+        # cache is updated in place
         T = cache["k"].shape[1]
-        slot = (pos0 % T) if layer_window else jnp.minimum(pos0, T - 1)
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-        kp = jax.lax.dynamic_update_slice(cache["kpos"],
-                                          qpos.astype(cache["kpos"].dtype),
-                                          (slot,))
-        valid = (kp <= pos0)
+        slot = cache_slot(T, pos0, layer_window)
+        kp = cache["kpos"]
+        valid = (kp <= pos0) & (jnp.arange(T) != slot)
         if layer_window:
             valid &= kp > pos0 - layer_window
-        y = dot_attention(q, ck, cv, qpos=qpos, kpos=kp, window=layer_window,
-                          chunk=0, kv_valid=jnp.broadcast_to(valid, (B, T)),
-                          softcap=cfg.logit_softcap)
-        new_cache = {"k": ck, "v": cv, "kpos": kp}
+        y = _decode_attention(q, cache["k"], cache["v"], k, v, valid,
+                              cfg.logit_softcap)
+        new_cache = {"k": k, "v": v}
     y = aconstrain(y, "batch", None, "tensor", None)
     y = jnp.einsum("bshk,hkd->bsd", y, p["wo"])
+    if cfg.o_bias:
+        y = y + p["bo"]
     y = aconstrain(y, "batch", None, None)
     return y, new_cache
+
+
+def cache_slot(T: int, pos0, layer_window: int):
+    """Cache slot of position `pos0` in a T-slot layer cache: a ring
+    (`pos0 % T`) for a windowed layer, else the position itself."""
+    return (pos0 % T) if layer_window else jnp.minimum(pos0, T - 1)
+
+
+def _decode_attention(q, ck, cv, k, v, valid, softcap=0.0):
+    """One query position against the cache (B, T, KV, D) where `valid`
+    (T,), plus the step's own key/value (B, 1, KV, D): one softmax over
+    the T + 1 scores. Returns (B, 1, H, D)."""
+    B, _, H, D = q.shape
+    KV = ck.shape[2]
+    qg = q.reshape(B, 1, KV, H // KV, D) * (1.0 / math.sqrt(D))
+    s_c = jnp.einsum("bskgd,btkd->bkgst", qg, ck).astype(jnp.float32)
+    s_n = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
+    if softcap:
+        s_c = jnp.tanh(s_c / softcap) * softcap
+        s_n = jnp.tanh(s_n / softcap) * softcap
+    s_c = jnp.where(valid, s_c, -1e30)
+    m = jnp.maximum(s_c.max(-1, keepdims=True), s_n)
+    p_c, p_n = jnp.exp(s_c - m), jnp.exp(s_n - m)
+    den = p_c.sum(-1, keepdims=True) + p_n
+    o = (jnp.einsum("bkgst,btkd->bskgd", (p_c / den).astype(cv.dtype), cv)
+         + jnp.einsum("bkgst,btkd->bskgd", (p_n / den).astype(v.dtype), v))
+    return o.reshape(B, 1, H, cv.shape[-1]).astype(q.dtype)
+
+
+def attn_cache_write(cache, new, pos0, layer_window: int):
+    """Write one decode step's k/v into stacked layer caches: `cache` k/v
+    (L, B, T, KV, D) and kpos (L, T), `new` k/v (L, B, 1, KV, D)."""
+    T = cache["k"].shape[2]
+    slot = cache_slot(T, pos0, layer_window)
+    at = lambda a, n: jax.lax.dynamic_update_slice(
+        a, n.astype(a.dtype), (0, 0, slot) + (0,) * (a.ndim - 3))
+    kp = jnp.full((cache["kpos"].shape[0], 1), pos0, cache["kpos"].dtype)
+    return {"k": at(cache["k"], new["k"]), "v": at(cache["v"], new["v"]),
+            "kpos": jax.lax.dynamic_update_slice(cache["kpos"], kp,
+                                                 (0, slot))}
 
 
 def attn_cache_init(cfg: ModelConfig, batch, max_seq, layer_window, dtype):

@@ -10,11 +10,18 @@ seconds on the multi-pod mesh.
 
 Public API (all pure functions):
   init(key, cfg)                         -> params
-  forward(params, cfg, batch, rules)     -> (logits, aux)        train/prefill
+  forward(params, cfg, batch, rules)     -> (logits, aux)        train
   loss_fn(params, cfg, batch, rules)     -> (loss, metrics)
   init_cache(cfg, batch, max_seq)        -> cache
+  prefill(params, cfg, batch, max_seq, rules) -> (logits, hidden, cache)
   decode_step(params, cfg, tok, cache, pos, rules) -> (logits, cache)
   abstract_params(cfg) / shardings(cfg, mesh, rules)
+
+`prefill` hands `decode_step` its cache in the decode layout: a sliding-
+window layer keeps a ring of min(window, max_seq) slots, position p at
+slot p % slots; a full-attention layer keeps max_seq slots. The decode
+step reads the cache as it stands and writes the step's k/v into its slot
+after the layer scan, so a donated cache is updated in place.
 """
 
 from __future__ import annotations
@@ -25,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
+from repro.analysis import hlo_contracts as hc
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as moe_lib
@@ -132,7 +140,7 @@ def _layer_apply(p, x, cfg: ModelConfig, ltype: str, is_moe: bool,
     h = L.apply_norm(p["norm1"], x, cfg)
     new_cache = cache
     if ltype in ("attn", "swa", "hymba", "hymba_g"):
-        window = cfg.window if ltype in ("swa", "hymba") else 0
+        window = _window(cfg, ltype)
         is_hymba = ltype.startswith("hymba")
         acache = (cache["attn"] if (is_hymba and cache is not None)
                   else cache)
@@ -176,6 +184,10 @@ def _layer_apply(p, x, cfg: ModelConfig, ltype: str, is_moe: bool,
         h2 = L.apply_norm(p["norm2"], x, cfg)
         x = x + _barrier(_ffn(p, h2, cfg, is_moe, rules, aux))
     return _decode_stream(x, rules, decode), new_cache, aux
+
+
+def _window(cfg: ModelConfig, ltype: str) -> int:
+    return cfg.window if ltype in ("swa", "hymba") else 0
 
 
 def _decode_stream(x, rules, decode):
@@ -273,27 +285,30 @@ def _embed_in(params, cfg: ModelConfig, batch, rules: Rules, pos0=0):
     return constrain(x, rules, "batch", None, None)
 
 
-def _logits_out(params, cfg: ModelConfig, x, rules: Rules):
-    """Vocab-sharded logits, kept in the compute dtype: consumers must not
-    gather the full vocab axis (loss_fn uses vocab-parallel CE)."""
-    x = L.apply_norm(params["final_norm"], x, cfg)
+def _final_hidden(params, cfg: ModelConfig, x):
+    """The residual stream after the final norm: what the LM head reads."""
+    return L.apply_norm(params["final_norm"], x, cfg)
+
+
+def _head(params, cfg: ModelConfig, h, rules: Rules):
+    """Vocab-sharded logits of post-norm hidden states, kept in the compute
+    dtype: consumers must not gather the full vocab axis (loss_fn uses
+    vocab-parallel CE)."""
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
     else:
-        logits = x @ params["unembed"]
+        logits = h @ params["unembed"]
     if cfg.logit_softcap:
         logits = (jnp.tanh(logits.astype(jnp.float32) / cfg.logit_softcap)
                   * cfg.logit_softcap).astype(logits.dtype)
     return constrain(logits, rules, "batch", None, "tensor")
 
 
-def forward(params, cfg: ModelConfig, batch, rules: Rules | None = None,
-            return_cache: bool = False, last_only: bool = False):
-    """Train/prefill forward. batch: tokens (B,S) | embeddings (B,S,D)
-    [+ positions3 (B,S,3) for mrope]. Returns (logits, aux[, cache]).
-    last_only: compute logits for the final position only (prefill serving;
-    avoids the (B,S,V) fp32 tensor)."""
-    rules = rules or Rules(batch=(), fsdp=(), tensor=(), expert=())
+def _trunk(params, cfg: ModelConfig, batch, rules: Rules,
+           collect_cache: bool):
+    """Embedding and every layer group: (x, caches, load_balance, z_loss),
+    the caches in the layout the layers produce (attention: the whole
+    sequence's k/v)."""
     x = _embed_in(params, cfg, batch, rules)
     positions3 = batch.get("positions3")
     lb = zl = jnp.zeros((), jnp.float32)
@@ -302,17 +317,69 @@ def forward(params, cfg: ModelConfig, batch, rules: Rules | None = None,
                                                 cfg.layer_groups()):
         x, new_c, l, z = _scan_group(params_g, x, cfg, ltype, is_moe, rules,
                                      positions3=positions3,
-                                     collect_cache=return_cache)
-        if return_cache:
-            caches.append(new_c)
+                                     collect_cache=collect_cache)
+        caches.append(new_c)
         lb, zl = lb + l, zl + z
-    if last_only:
-        x = x[:, -1:]
-    logits = _logits_out(params, cfg, x, rules)
-    aux = {"load_balance": lb, "z_loss": zl}
-    if return_cache:
-        return logits, aux, caches
-    return logits, aux
+    return x, caches, lb, zl
+
+
+def forward(params, cfg: ModelConfig, batch, rules: Rules | None = None):
+    """Train forward. batch: tokens (B,S) | embeddings (B,S,D)
+    [+ positions3 (B,S,3) for mrope]. Returns (logits, aux). Serving
+    prefills through `prefill`."""
+    rules = rules or Rules(batch=(), fsdp=(), tensor=(), expert=())
+    x, _, lb, zl = _trunk(params, cfg, batch, rules, False)
+    logits = _head(params, cfg, _final_hidden(params, cfg, x), rules)
+    return logits, {"load_balance": lb, "z_loss": zl}
+
+
+def _ring(a, axis: int, S: int, T: int, fill):
+    """Sequence axis `axis` of length S -> T decode slots: position p at
+    slot p % T, the last T positions kept; unwritten slots hold `fill`."""
+    if S >= T:
+        a = jax.lax.slice_in_dim(a, S - T, S, axis=axis)
+        return jnp.roll(a, (S - T) % T, axis=axis)
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, T - S)
+    return jnp.pad(a, pad, constant_values=fill)
+
+
+def _attn_decode_layout(c, window: int, S: int, max_seq: int):
+    """Stacked prefill k/v (L, B, S, KV, D) and kpos (L, S) -> the cache
+    `init_cache` lays out for `max_seq` positions."""
+    T = min(window, max_seq) if window else max_seq
+    if not window and S > T:
+        raise ValueError(f"a prompt of {S} positions does not fit a "
+                         f"{T}-slot full-attention cache")
+    empty = jnp.iinfo(jnp.int32).max
+    return {"k": _ring(c["k"], 2, S, T, 0), "v": _ring(c["v"], 2, S, T, 0),
+            "kpos": _ring(c["kpos"].astype(jnp.int32), 1, S, T, empty)}
+
+
+def prefill(params, cfg: ModelConfig, batch, max_seq: int,
+            rules: Rules | None = None):
+    """Serving prefill of a prompt batch (B, S), one forward pass.
+    Returns (logits at the last position (B,1,V), post-final-norm hidden
+    states (B,S,D), caches in the decode layout of
+    `init_cache(cfg, B, max_seq)`), so decoding continues at position S.
+    Attention (and hymba's attention) caches are converted; other layer
+    states are the forward's own."""
+    rules = rules or Rules(batch=(), fsdp=(), tensor=(), expert=())
+    S = jax.tree_util.tree_leaves(batch)[0].shape[1]
+    with jax.named_scope(hc.PREFILL_SCOPE):
+        x, caches, _, _ = _trunk(params, cfg, batch, rules, True)
+        h = _final_hidden(params, cfg, x)
+        logits = _head(params, cfg, h[:, -1:], rules)
+        out = []
+        for c, (ltype, _, _) in zip(caches, cfg.layer_groups()):
+            w = _window(cfg, ltype)
+            if ltype in ("attn", "swa"):
+                c = _attn_decode_layout(c, w, S, max_seq)
+            elif ltype in ("hymba", "hymba_g"):
+                c = {**c, "attn": _attn_decode_layout(c["attn"], w, S,
+                                                      max_seq)}
+            out.append(c)
+    return logits, h, out
 
 
 def loss_fn(params, cfg: ModelConfig, batch, rules: Rules | None = None):
@@ -376,21 +443,31 @@ def decode_step(params, cfg: ModelConfig, batch, caches, pos,
                 rules: Rules | None = None, return_hidden: bool = False):
     """One token for every sequence. batch: tokens (B,1) | embeddings
     (B,1,D) [+ positions3 (B,1,3)]; pos: scalar int32 current position.
-    Returns (logits (B,1,V), new_caches[, hidden (B,1,D)])."""
+    Returns (logits (B,1,V), new_caches[, hidden (B,1,D)]), hidden being
+    the post-final-norm state the LM head reads."""
     rules = rules or Rules(batch=(), fsdp=(), tensor=(), expert=())
-    x = _embed_in(params, cfg, batch, rules, pos0=pos)
-    x = _decode_stream(x, rules, True)
-    positions3 = batch.get("positions3")
-    new_caches = []
-    for params_g, caches_g, (ltype, is_moe, count) in zip(
-            params["groups"], caches, cfg.layer_groups()):
-        x, nc, _, _ = _scan_group(params_g, x, cfg, ltype, is_moe, rules,
-                                  caches=caches_g, pos0=pos,
-                                  positions3=positions3, decode=True)
-        new_caches.append(nc)
-    logits = _logits_out(params, cfg, x, rules)
+    with jax.named_scope(hc.DECODER_SCOPE):
+        x = _embed_in(params, cfg, batch, rules, pos0=pos)
+        x = _decode_stream(x, rules, True)
+        positions3 = batch.get("positions3")
+        new_caches = []
+        for params_g, caches_g, (ltype, is_moe, count) in zip(
+                params["groups"], caches, cfg.layer_groups()):
+            x, nc, _, _ = _scan_group(params_g, x, cfg, ltype, is_moe,
+                                      rules, caches=caches_g, pos0=pos,
+                                      positions3=positions3, decode=True)
+            w = _window(cfg, ltype)
+            if ltype in ("attn", "swa"):
+                nc = L.attn_cache_write(caches_g, nc, pos, w)
+            elif ltype in ("hymba", "hymba_g"):
+                nc = {"attn": L.attn_cache_write(caches_g["attn"],
+                                                 nc["attn"], pos, w),
+                      "ssm": nc["ssm"]}
+            new_caches.append(nc)
+        h = _final_hidden(params, cfg, x)
+        logits = _head(params, cfg, h, rules)
     if return_hidden:
-        return logits, new_caches, x
+        return logits, new_caches, h
     return logits, new_caches
 
 

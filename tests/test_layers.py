@@ -102,6 +102,35 @@ def test_write_and_calibrate_ops_map_to_their_layers(toy):
     assert layers_in(calibrate) == {hc.CALIBRATE_SCOPE}
 
 
+def test_knnlm_decode_step_and_prefill_ops_map_to_layers():
+    """The kNN-LM serve step splits into the decoder, the kNN head and the
+    search's own layers inside it; the serving prefill is one layer."""
+    from repro.configs import load_config
+    from repro.launch import serve as serve_lib
+    from repro.launch import steps as steps_lib
+    from repro.models import transformer as tfm
+    from repro.models.sharding import Rules
+
+    cfg = load_config("starcoder2-3b", smoke=True)
+    rules = Rules(batch=(), fsdp=(), tensor=(), expert=())
+    mem_cfg = serve_lib.datastore_config(cfg, 64)
+    params = jax.eval_shape(lambda: tfm.init(jax.random.PRNGKey(0), cfg))
+    store = jax.eval_shape(lambda: MemoryStore.create(mem_cfg).calibrate(
+        jnp.zeros((4, cfg.d_model))))
+    caches = jax.eval_shape(lambda: tfm.init_cache(cfg, 2, 16))
+    step = steps_lib.make_serve_step_with_mcam(cfg, rules, mem_cfg, k=8)
+    text = step.lower(params, caches, {"tokens": jnp.zeros((2, 1), jnp.int32)},
+                      jnp.int32(3), store).compile().as_text()
+    assert unmapped(text) == []
+    assert {hc.DECODER_SCOPE, hc.KNN_SCOPE, hc.SHORTLIST_SCOPE,
+            hc.RESCORE_SCOPE} <= layers_in(text)
+    prefill = steps_lib.make_prefill_into_step(cfg, rules, 16)
+    text = prefill.lower(params, caches, jnp.zeros((1, 12), jnp.int32),
+                         jnp.int32(1)).compile().as_text()
+    assert unmapped(text) == []
+    assert layers_in(text) == {hc.PREFILL_SCOPE}
+
+
 def test_sharded_search_and_write_ops_map_to_layers():
     """Four forced host devices (a subprocess: the device count is fixed
     at JAX start-up): the mesh-sharded search on both shortlist paths and

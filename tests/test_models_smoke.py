@@ -108,6 +108,9 @@ def test_full_configs_match_assignment():
     assert load_config("deepseek-v3-671b").moe.n_routed == 256
     assert load_config("deepseek-moe-16b").moe.top_k == 6
     assert load_config("hymba-1.5b").ssm.d_state == 16
+    sc2 = load_config("starcoder2-3b")     # hf bigcode/starcoder2-3b config
+    assert (sc2.default_layer, sc2.window) == ("swa", 4096)
+    assert sc2.norm_eps == 1e-5 and sc2.rope_theta == 999999.4420358813
 
 
 @pytest.mark.slow

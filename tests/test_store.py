@@ -165,7 +165,8 @@ def test_serve_decode_step_no_layout_under_jit():
 
     cfg = load_config("starcoder2-3b", smoke=True)
     rules = Rules(batch=(), fsdp=(), tensor=(), expert=())
-    mem_cfg = MemoryConfig(capacity=64, dim=min(48, cfg.d_model),
+    # the kNN-LM keys are whole d_model hidden states
+    mem_cfg = MemoryConfig(capacity=64, dim=cfg.d_model,
                            search=SearchConfig("mtmc", cl=8, mode="avss",
                                                use_kernel="ref"))
     vecs = jax.random.normal(jax.random.PRNGKey(7), (32, mem_cfg.dim))
