@@ -200,13 +200,20 @@ def rescore_shortlist(q_grid: jax.Array, s_grid: jax.Array,
     query (default arange(B), the batch position). `engine.search_tenants`
     passes each query's rank within its tenant group, so a batch mixing
     tenants rescores bit-identically to per-tenant solo calls.
+
+    Only the B*K candidate rows of `s_grid` are read: they are gathered
+    from the grid as stored and then flattened. The order matters on the
+    chip, where the stored grid keeps N as its minor dimension: flattening
+    all N rows first makes XLA relayout the whole grid on every call, while
+    the gather reads the candidate rows in place. Gathering and then
+    flattening gives the same array as flattening and then gathering.
     Returns votes (B, K).
     """
-    L = s_grid.shape[2]
+    B, K = short_idx.shape
+    _, seg, L, sl = s_grid.shape
+    S = seg * L
     q = flatten_strings(broadcast_query(q_grid, L))        # (B, S, sl)
-    s = flatten_strings(s_grid)                            # (N, S, sl)
-    B, S, sl = q.shape
-    sg = s[short_idx]                                      # (B, K, S, sl)
+    sg = s_grid[short_idx].reshape(B, K, S, sl)            # (B, K, S, sl)
     m = jnp.abs(q[:, None].astype(jnp.int32) - sg.astype(jnp.int32))
     m = m.astype(jnp.float32)                              # (B, K, S, sl)
     if noise_idx is None:
@@ -227,7 +234,6 @@ def rescore_shortlist(q_grid: jax.Array, s_grid: jax.Array,
     r = mcam_lib.string_resistance(m, mc, device_noise=dev)
     cur = mcam_lib.current_from_resistance(r, sl, mc, read_noise=rd)
     v = (cur[..., None] > thresholds).sum(-1).astype(jnp.float32)
-    seg = s_grid.shape[1]
     w_flat = jnp.tile(weights.astype(jnp.float32), seg)
     return (v * w_flat[None, None, :]).sum(-1)             # (B, K)
 

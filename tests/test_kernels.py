@@ -129,3 +129,82 @@ def test_two_phase_winner_agreement():
     assert agree[64] >= 0.95, agree
     assert agree[32] >= 0.5, agree
     assert agree[64] >= agree[32]
+
+
+def _rescore_flatten_then_gather(q_grid, s_grid, short_idx, weights, cfg,
+                                 thresholds, noise_idx=None, noise_qidx=None):
+    """`ops.rescore_shortlist` as it read before the gather moved ahead of
+    the flatten: all N rows flattened to (N, S, sl), then gathered."""
+    from repro.core import mcam as mcam_lib
+    L = s_grid.shape[2]
+    q = ops.flatten_strings(ops.broadcast_query(q_grid, L))
+    s = ops.flatten_strings(s_grid)
+    B, S, sl = q.shape
+    sg = s[short_idx]
+    m = jnp.abs(q[:, None].astype(jnp.int32) - sg.astype(jnp.int32))
+    m = m.astype(jnp.float32)
+    if noise_idx is None:
+        noise_idx = short_idx
+    if noise_qidx is None:
+        noise_qidx = jnp.arange(B, dtype=jnp.uint32)
+    string_id = (noise_idx.astype(jnp.uint32)[..., None] * jnp.uint32(S)
+                 + jnp.arange(S, dtype=jnp.uint32)[None, None, :])
+    b_idx = noise_qidx.astype(jnp.uint32)[:, None, None]
+    mc = cfg.mcam
+    dev = rd = None
+    if cfg.noisy:
+        cell = jnp.arange(sl, dtype=jnp.uint32)
+        dev = mcam_lib.hash_normal(b_idx[..., None], string_id[..., None],
+                                   cell, seed=mc.seed)
+        rd = mcam_lib.hash_normal(b_idx, string_id,
+                                  seed=mc.seed + ref.READ_SEED_OFFSET)
+    r = mcam_lib.string_resistance(m, mc, device_noise=dev)
+    cur = mcam_lib.current_from_resistance(r, sl, mc, read_noise=rd)
+    v = (cur[..., None] > thresholds).sum(-1).astype(jnp.float32)
+    w_flat = jnp.tile(weights.astype(jnp.float32), s_grid.shape[1])
+    return (v * w_flat[None, None, :]).sum(-1)
+
+
+@pytest.mark.parametrize("case", ["noisy", "noiseless", "duplicates",
+                                  "tiny_store", "sharded", "noise_qidx"])
+def test_rescore_shortlist_matches_flatten_then_gather(case):
+    """Gathering the candidate rows of the stored (N, seg, L, sl) grid and
+    flattening only them gives votes bit-identical to flattening every row
+    and then gathering, in each form the callers use: noise on and off,
+    repeated candidates, more candidates than rows, a shard's grid with
+    shard-local rows and global noise rows, per-query noise coordinates."""
+    cfg = SearchConfig(encoding="mtmc", cl=6, mode="avss",
+                       noisy=case != "noiseless",
+                       mcam=MCAMConfig(sigma_device=0.1, sigma_read=0.05))
+    enc = cfg.enc
+    N, B, K = {"tiny_store": (3, 4, 5)}.get(case, (40, 6, 8))
+    qv, sv = _layouts("avss", enc, 10, N=N, B=B, seed=3)
+    sl = cfg.mcam.string_len
+    s_grid = avss_lib.layout_support(sv, enc, sl)
+    q_grid = avss_lib.layout_query(qv, enc, "avss", sl)
+    rng = np.random.default_rng(7)
+    short_idx = rng.integers(0, N, (B, K))
+    if case == "duplicates":
+        short_idx[:, K // 2:] = short_idx[:, :K // 2]
+        short_idx[1] = short_idx[1, 0]
+    kw = {}
+    if case == "sharded":
+        # the third of four equal shards: local rows, global noise rows
+        rows = N // 4
+        s_grid = s_grid[2 * rows:3 * rows]
+        short_idx = short_idx % rows
+        kw["noise_idx"] = jnp.asarray(short_idx + 2 * rows, jnp.int32)
+    if case == "noise_qidx":
+        kw["noise_qidx"] = jnp.asarray([5, 0, 0, 2, 9, 1], jnp.uint32)
+    short_idx = jnp.asarray(short_idx, jnp.int32)
+    args = (q_grid, s_grid, short_idx, enc.weights_array(), cfg,
+            jnp.asarray(cfg.mcam.thresholds()))
+    new = jax.jit(ops.rescore_shortlist, static_argnums=4)(*args, **kw)
+    old = jax.jit(_rescore_flatten_then_gather, static_argnums=4)(*args,
+                                                                  **kw)
+    assert new.shape == (B, K)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+    if case == "duplicates":
+        v = np.asarray(new)
+        np.testing.assert_array_equal(v[:, K // 2:], v[:, :K // 2])
+        assert (v[1] == v[1, 0]).all()

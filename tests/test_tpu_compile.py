@@ -12,6 +12,7 @@ compiler library at a time.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,25 +96,26 @@ def test_mcam_search_kernel_compiles(one_chip, noisy):
     assert "tpu_custom_call" in text
 
 
-def _two_phase_search_text(one_chip) -> str:
-    """The served two_phase search at real widths (B=64, k=32 over N
-    rows), compiled for the chip."""
+def _two_phase_search_text(one_chip, n: int = N, dim: int = K4D // 4,
+                           cl: int = 32, batch: int = B) -> str:
+    """The served two_phase search at real widths (k=32 over `n` rows of
+    `dim` values, MTMC `cl`, `batch` queries), compiled for the chip."""
     import dataclasses
 
     from repro.core.avss import SearchConfig
     from repro.core.memory import MemoryConfig
     from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
 
-    cfg = SearchConfig("mtmc", cl=32, mode="avss", mcam=MCAMConfig(
+    cfg = SearchConfig("mtmc", cl=cl, mode="avss", mcam=MCAMConfig(
         string_len=SL, rho=8.0, sigma_device=0.12, sigma_read=0.04,
         n_thresholds=8))
     shapes = dataclasses.replace(jax.eval_shape(lambda: MemoryStore.create(
-        MemoryConfig(capacity=N, dim=K4D // 4, search=cfg))),
+        MemoryConfig(capacity=n, dim=dim, search=cfg))),
         calibrated=True)
     store = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         shapes)
-    q = jax.ShapeDtypeStruct((B, K4D // 4), jnp.float32, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((batch, dim), jnp.float32, sharding=one_chip)
     eng = RetrievalEngine(cfg)
 
     def search(st, q):
@@ -125,17 +127,42 @@ def _two_phase_search_text(one_chip) -> str:
         return _compiled_text(search, store, q)
 
 
-def test_two_phase_search_ops_map_to_layers(one_chip):
-    """The served two_phase search at real widths, compiled for the chip:
-    its (B, N) distance block is under the dense budget, so phase 1 is
-    XLA's dot and exact top-k with no Pallas kernel at all; every op that
-    does work, the copies the TPU compiler inserts among them, maps to a
-    layer of the program (analysis.hlo_contracts.op_layers), and the
-    relayout of `s_grid` the rescore gathers from is counted in the
-    rescore."""
+# (rows, dim, MTMC CL, batch) of each benchmark cell's store search: the
+# omniglot cell's 1,572,864 rows of d=48 at CL=32, B=64, and the kNN-LM
+# decode cell's 65,536 rows of d=3,072 at CL=8, one query per sequence of
+# its batch of 16
+CELL_GEOMETRIES = {"omniglot-1536K": (1572864, 48, 32, 64),
+                   "starcoder2-3b-knnlm": (65536, 3072, 8, 16)}
+# an instruction's name and the result type before its opcode
+_RESULT = re.compile(
+    r"\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s[a-z][\w\-]*\(")
+_DIMS = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _result_rows(text: str) -> dict[str, set[int]]:
+    """{instruction: leading dims of the arrays it yields} of HLO text."""
+    rows = {}
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m:
+            rows[m.group(1)] = {int(d.split(",")[0])
+                                for d in _DIMS.findall(m.group(2)) if d}
+    return rows
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_GEOMETRIES))
+def test_two_phase_search_ops_map_to_layers(one_chip, cell):
+    """The served two_phase search at each cell's store geometry, compiled
+    for the chip: its (B, N) distance block is under the dense budget, so
+    phase 1 is XLA's dot and exact top-k with no Pallas kernel at all;
+    every op that does work, the copies the TPU compiler inserts among
+    them, maps to a layer of the program (analysis.hlo_contracts.op_layers),
+    and no op of the rescore yields N rows: it gathers the B*k candidate
+    rows from `s_grid` as stored, and relayouts only those."""
     from repro.analysis import hlo_contracts as hc
 
-    text = _two_phase_search_text(one_chip)
+    n, dim, cl, batch = CELL_GEOMETRIES[cell]
+    text = _two_phase_search_text(one_chip, n=n, dim=dim, cl=cl, batch=batch)
     assert hc.scoped_lines(text, hc.FUSED_SCOPE_TAG) == []
     assert "tpu_custom_call" not in text
     _, ops = hc.parse_hlo(text)
@@ -145,16 +172,14 @@ def test_two_phase_search_ops_map_to_layers(one_chip):
     assert any(o.op_name.endswith("/top_k") for o in phase1)
     layers = hc.op_layers(text)
     opcode = {o.name: o.opcode for o in ops}
-    assert [n for n, layer in layers.items()
-            if layer is None and opcode[n] != "parameter"] == []
+    assert [name for name, layer in layers.items()
+            if layer is None and opcode[name] != "parameter"] == []
     assert {hc.QUANTIZE_SCOPE, hc.SHORTLIST_SCOPE, hc.RESCORE_SCOPE,
             hc.ANSWER_SCOPE} == set(layers.values()) - {None}
-    grid_copies = [o.name for o in ops if o.opcode == "copy"
-                   and f"s8[{N},{S},{SL}]" in next(
-                       line for line in text.splitlines()
-                       if f"%{o.name} = " in line)]
-    assert grid_copies and all(layers[c] == hc.RESCORE_SCOPE
-                               for c in grid_copies)
+    rows = _result_rows(text)
+    assert [name for name, layer in layers.items()
+            if layer == hc.RESCORE_SCOPE and n in rows[name]
+            and opcode[name] not in ("parameter", "bitcast")] == []
 
 
 def test_two_phase_search_over_budget_compiles_fused_kernel(one_chip):
