@@ -26,9 +26,7 @@ from repro.core.memory import MemoryConfig
 from repro.engine import (MemoryStore, RetrievalEngine, SearchRequest)
 from repro.launch.mesh import make_mesh
 
-# PINNED to the acceptance shape of the PR-5/6 shortlist baselines
-# (BENCH_shortlist.json compares against rows at this exact N) -- a
-# benchmark shape, not the engine's phase-1 dispatch rule.
+# the large-N ideal rows' store: a benchmark shape, not a phase-1 rule
 N_IDEAL = 4096
 N, B, D, K = 2048, 16, 48, 64
 W = 256                              # streaming-write batch rows
@@ -55,7 +53,7 @@ def run():
 
     # two-phase: MXU shortlist + exact rescore, per shortlist backend
     votes_tp = {}
-    for backend in ("ref", "mxu", "fused"):
+    for backend in ("ref", "pallas"):
         eng = RetrievalEngine(cfg, backend=backend)
         f_tp = jax.jit(lambda q, s, e=eng: e.two_phase(q, s, k=K)["votes"])
         st_tp, votes_tp[backend] = time_percentiles(f_tp, qv, sv, iters=3)
@@ -63,9 +61,9 @@ def run():
         rows.append((f"engine/two_phase_k{K}_{backend}", us_tp,
                      qps(us_tp) + f";speedup_vs_full={us_full / us_tp:.1f}x",
                      st_tp))
-    for backend in ("mxu", "fused"):  # backends must agree bit-exactly
-        np.testing.assert_array_equal(np.asarray(votes_tp["ref"]),
-                                      np.asarray(votes_tp[backend]))
+    # backends must agree bit-exactly
+    np.testing.assert_array_equal(np.asarray(votes_tp["ref"]),
+                                  np.asarray(votes_tp["pallas"]))
 
     # unified API: engine.search over a programmed MemoryStore (write-time
     # proj + s_grid layouts -- the serving path). Must be bit-identical to
@@ -74,7 +72,7 @@ def run():
     labels = jnp.arange(N, dtype=jnp.int32) % 128
     store = MemoryStore.from_quantized(sv, labels, cfg)
     req = SearchRequest(mode="two_phase", k=K)
-    for backend in ("ref", "mxu", "fused"):
+    for backend in ("ref", "pallas"):
         eng = RetrievalEngine(cfg, backend=backend)
         f_st = jax.jit(lambda st, q, e=eng: e.search(st, q, req).votes)
         st_st, votes_st = time_percentiles(f_st, store, qv, iters=3)
@@ -138,29 +136,24 @@ def run():
     np.testing.assert_array_equal(np.asarray(f_w(base, wvecs, wlabs)),
                                   np.asarray(vals_ws))
 
-    # large-N ideal serving: dense (B, N) matmul vs the fused shortlist
-    # kernel (HBM O(B*k + N*4d)); bit-parity asserted
+    # large-N ideal serving: the ref distance vs the dot against the
+    # write-time projection; bit-parity asserted
     isv = jax.random.randint(jax.random.PRNGKey(3), (N_IDEAL, D), 0,
                              enc.levels)
     istore = MemoryStore.from_quantized(
         isv, jnp.arange(N_IDEAL, dtype=jnp.int32) % 128, cfg)
     ireq = SearchRequest(mode="ideal", k=K)
     f_id = {b: jax.jit(lambda st, q, e=RetrievalEngine(cfg, backend=b):
-                       e.search(st, q, ireq)) for b in ("ref", "fused")}
-    st_dense, res_dense = time_percentiles(f_id["ref"], istore, qv, iters=3)
-    us_dense = st_dense["us"]
-    rows.append((f"engine/ideal_dense_N{N_IDEAL}", us_dense, qps(us_dense),
-                 st_dense))
-    st_fused, res_fused = time_percentiles(f_id["fused"], istore, qv,
-                                           iters=3)
-    us_fused = st_fused["us"]
-    rows.append((f"engine/ideal_fused_N{N_IDEAL}", us_fused,
-                 qps(us_fused)
-                 + f";speedup_vs_dense={us_dense / us_fused:.1f}x",
-                 st_fused))
+                       e.search(st, q, ireq)) for b in ("ref", "pallas")}
+    res_id = {}
+    for b, f in f_id.items():
+        st_id, res_id[b] = time_percentiles(f, istore, qv, iters=3)
+        rows.append((f"engine/ideal_{b}_N{N_IDEAL}", st_id["us"],
+                     qps(st_id["us"]), st_id))
     for key in ("votes", "dist", "indices", "labels"):
-        np.testing.assert_array_equal(np.asarray(getattr(res_dense, key)),
-                                      np.asarray(getattr(res_fused, key)))
+        np.testing.assert_array_equal(np.asarray(getattr(res_id["ref"], key)),
+                                      np.asarray(getattr(res_id["pallas"],
+                                                         key)))
 
     # multi-tenant serving (PR 9): B queries spread over T stacked stores,
     # ONE coalesced vmapped search_tenants call vs T sequential solo

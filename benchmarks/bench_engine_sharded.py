@@ -4,9 +4,7 @@ The in-process `engine` suite runs its sharded row on however many devices
 the host exposes (1 on a plain CPU run). This suite forces an 8-device host
 mesh the way tests/test_distributed.py does -- XLA_FLAGS must precede jax
 init, so it MUST be a subprocess -- and sweeps shard counts over a fixed
-store so the sharded scaling shape lands in the perf trajectory, plus the
-per-shard shortlist dense-vs-fused comparison (steered purely by the
-SearchRequest.fused_min_rows knob, bit-parity asserted). Results
+store so the sharded scaling shape lands in the perf trajectory. Results
 are printed as harness rows AND written to results/bench_engine_sharded.json
 (uploaded as a CI artifact by the weekly full job).
 
@@ -71,46 +69,6 @@ _WORKER = """
                         "us_per_call": us, "shards": n_dev,
                         "qps": B / us * 1e6,
                         "speedup_vs_1dev": us1 / us})
-
-    # sharded per-shard shortlist: dense local matmul vs the fused Pallas
-    # kernel (ISSUE 4 tentpole). The SearchRequest.fused_min_rows override
-    # steers the dispatch without code change; bit-parity is asserted
-    # against the unsharded ideal reference either way. NOTE: on this CPU
-    # container the fused rows measure the Pallas INTERPRETER -- the
-    # dense-vs-fused *crossover* must be measured on real TPU HBM; these
-    # rows track that both routes stay wired and bit-identical.
-    ideal_ref = jax.jit(lambda st, q: (eng.search(
-        st, q, SearchRequest(mode="ideal", k=K)).dist,))
-    _, (ref_dist,) = time_us(ideal_ref, store, qv)
-    for n_dev in (2, 8):
-        mesh = make_mesh((n_dev,), ("data",))
-        sstore = store.shard(mesh, ("data",))
-        for tag, fmr in (("dense", 1 << 30), ("fused", 1)):
-            req = SearchRequest(mode="ideal", k=K, backend="mxu",
-                                fused_min_rows=fmr)
-            with mesh:
-                f = jax.jit(lambda st, q, r=req: (eng.search(st, q, r).dist,))
-                us, (dist,) = time_us(f, sstore, qv)
-            np.testing.assert_array_equal(np.asarray(ref_dist),
-                                          np.asarray(dist))
-            records.append({"name": "engine_sharded/ideal_%s_k%d_dev%d"
-                                    % (tag, K, n_dev),
-                            "us_per_call": us, "shards": n_dev,
-                            "shortlist": tag, "qps": B / us * 1e6})
-    mesh = make_mesh((8,), ("data",))
-    sstore = store.shard(mesh, ("data",))
-    for tag, fmr in (("dense", 1 << 30), ("fused", 1)):
-        req = SearchRequest(mode="two_phase", k=K, backend="mxu",
-                            fused_min_rows=fmr)
-        with mesh:
-            f = jax.jit(lambda st, q, r=req: (eng.search(st, q, r).votes,))
-            us, (votes,) = time_us(f, sstore, qv)
-        np.testing.assert_array_equal(np.asarray(ref_votes),
-                                      np.asarray(votes))
-        records.append({"name": "engine_sharded/two_phase_%s_k%d_dev8"
-                                % (tag, K),
-                        "us_per_call": us, "shards": 8,
-                        "shortlist": tag, "qps": B / us * 1e6})
 
     # streaming (shard-local) writes: program a W-row batch into the ring;
     # the write-through keeps programming local to each shard, so per-batch

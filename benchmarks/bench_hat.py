@@ -8,7 +8,7 @@ Two row families, merged into results/bench_summary.json by benchmarks.run:
   baseline for the hardware-simulation overhead.
 * encoding_sweep/* -- `engine.search` cost per encoding (mtmc / b4e /
   b4we / sre) on the same store geometry: what the paper's Table 1
-  encoding choice costs at serve time (two-phase, mxu backend), with the
+  encoding choice costs at serve time (two-phase, pallas backend), with the
   word-line iteration count in the derived column.
 """
 
@@ -65,7 +65,7 @@ def _encoding_sweep_rows():
         qv = jax.random.randint(jax.random.PRNGKey(1), (b, d), 0, 4)
         store = MemoryStore.from_quantized(
             sv, jnp.arange(n, dtype=jnp.int32) % 17, cfg)
-        eng = RetrievalEngine(cfg, backend="mxu")
+        eng = RetrievalEngine(cfg, backend="pallas")
         req = SearchRequest(mode="two_phase", k=k)
         fn = jax.jit(lambda q, st=store, e=eng, r=req: e.search(st, q, r))
         us, _ = time_us(fn, qv)

@@ -42,12 +42,6 @@ def run():
                                rtol=1e-5)
     rows.append((f"kernel/pallas_full_N{N}", us, "backend=pallas-interpret"))
 
-    # MXU LUT distance
-    f_mxu = jax.jit(lambda q, s: ops.avss_ideal_dist(q, s, enc))
-    us, _ = time_us(f_mxu, qv, sv, iters=3)
-    rows.append((f"kernel/mxu_lut_dist_N{N}", us,
-                 f"inner_dim={4*d};dtype=bf16"))
-
     # two-phase recall@k
     full = avss_lib.search_quantized(qv, sv, cfg)
     full_best = np.asarray(jnp.argmax(

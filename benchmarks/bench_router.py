@@ -8,11 +8,10 @@ plus recall@1 of the routed 1-NN retrieval against the exhaustive
 all-shards search. `nprobe=S` must be BYTE-identical to `nprobe=None`
 (asserted every run), so the curve's end point IS the baseline.
 
-NOTE: on this CPU container the timings measure XLA CPU (and, past the
-fused crossover, the Pallas INTERPRETER); the recall curve and the
-routed-vs-exhaustive latency ORDERING are the signal, not absolute
-wall-times -- re-measure on a real TPU before using the numbers for
-capacity planning (the note is embedded in BENCH_router.json).
+NOTE: on this CPU container the timings measure XLA CPU; the recall
+curve and the routed-vs-exhaustive latency ORDERING are the signal, not
+absolute wall-times -- re-measure on a real TPU before using the numbers
+for capacity planning (the note is embedded in BENCH_router.json).
 
     PYTHONPATH=src python -m benchmarks.run --only router      # full sweep
     PYTHONPATH=src python -m benchmarks.bench_router --dry-run # CI gate
@@ -43,7 +42,7 @@ def _fixture(n, s, n_way, dim, batch):
     """Class-coherent partitioned store + quantized queries: clustered
     episode embeddings, rows SORTED by label so each shard holds few
     classes (the layout that makes a class-centroid sketch selective)."""
-    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="mxu")
+    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="pallas")
     sup, s_lab, qf, _ = synthetic_episode(
         0, n_way, n // n_way, -(-batch // n_way), dim, sep=2.2, noise=0.9)
     sv, qv = quantize_pair(sup, qf, cfg.enc.levels, cfg.mode)

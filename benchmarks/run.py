@@ -12,9 +12,8 @@ Suites:
   engine   retrieval engine: full vs two-phase vs sharded vs store-based
            unified search, plus the streaming-write and large-N ideal
            serving rows (bench_engine)
-  engine_sharded  multi-device sharded scaling (search, shard-local
-           streaming writes, AND the per-shard shortlist dense-vs-fused
-           sweep) on a forced 8-device host mesh (subprocess, like
+  engine_sharded  multi-device sharded scaling (search and shard-local
+           streaming writes) on a forced 8-device host mesh (subprocess, like
            tests/test_distributed.py); writes
            results/bench_engine_sharded.json (CI artifact)
   router   hierarchical-routing sweep: recall@1 + latency percentiles per
@@ -30,12 +29,6 @@ Suites:
 Every run also consolidates the rows of ALL executed suites into
 results/bench_summary.json (uploaded as a CI artifact by the weekly full
 job), so the perf trajectory is tracked PR-over-PR in one file.
-
-results/ is NOT committed, so any run that refreshes the `engine` suite
-also emits the committed repo-root `BENCH_shortlist.json` -- the
-dense-vs-fused shortlist rows at the acceptance shape (N=4096) next to
-the pinned pre-rework baseline -- making the kernel's crossover claim
-checkable from the repo alone.
 """
 
 from __future__ import annotations
@@ -60,36 +53,7 @@ SUITES = {
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUMMARY_PATH = os.path.join(ROOT, "results", "bench_summary.json")
-SHORTLIST_PATH = os.path.join(ROOT, "BENCH_shortlist.json")
 ROUTER_PATH = os.path.join(ROOT, "BENCH_router.json")
-
-# The large-N ideal rows as measured BEFORE the shortlist kernel rework
-# (PR 5, same CPU pallas-interpret mode): the fused kernel's O(k*(k+tile_n))
-# per-step extraction loop left it at 0.1x of the dense path it replaced.
-# Pinned here so BENCH_shortlist.json always shows the trajectory.
-SHORTLIST_BASELINE = {
-    "pr": 5,
-    "engine/ideal_dense_N4096": {"us_per_call": 4500.0},
-    "engine/ideal_fused_N4096": {"us_per_call": 86000.0,
-                                 "speedup_vs_dense": 0.05},
-}
-
-
-def _emit_shortlist_bench(engine_rows: list[dict]) -> bool:
-    """Refresh the committed repo-root BENCH_shortlist.json from the engine
-    suite's large-N ideal rows (dense vs fused, before/after)."""
-    after = {r["name"]: r for r in engine_rows
-             if r["name"].startswith("engine/ideal_")}
-    if len(after) < 2:
-        return False
-    with open(SHORTLIST_PATH, "w") as f:
-        json.dump({"generated_by": "benchmarks.run --only engine",
-                   "measurement": "cpu pallas-interpret (same mode as the "
-                                  "pinned PR5 baseline)",
-                   "before": SHORTLIST_BASELINE,
-                   "after": after}, f, indent=1)
-    return True
-
 
 def _emit_router_bench(router_rows: list[dict]) -> bool:
     """Refresh the committed repo-root BENCH_router.json from the router
@@ -99,8 +63,7 @@ def _emit_router_bench(router_rows: list[dict]) -> bool:
         return False
     with open(ROUTER_PATH, "w") as f:
         json.dump({"generated_by": "benchmarks.run --only router",
-                   "measurement": "cpu xla / pallas-interpret past the "
-                                  "fused crossover -- recall curve and "
+                   "measurement": "cpu xla -- recall curve and "
                                   "routed-vs-exhaustive ordering are the "
                                   "signal; re-measure on TPU for absolute "
                                   "latencies",
@@ -176,9 +139,6 @@ def main() -> None:
     print(f"# wrote {os.path.relpath(SUMMARY_PATH, ROOT)} "
           f"({sum(len(v) for v in merged.values())} rows, "
           f"{len(merged)} suite(s))")
-    if "engine" in summary and _emit_shortlist_bench(summary["engine"]):
-        print(f"# wrote {os.path.relpath(SHORTLIST_PATH, ROOT)} "
-              f"(dense-vs-fused shortlist trajectory)")
     if "router" in summary and _emit_router_bench(summary["router"]):
         print(f"# wrote {os.path.relpath(ROUTER_PATH, ROOT)} "
               f"(recall-vs-nprobe routing curve)")

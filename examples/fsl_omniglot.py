@@ -137,7 +137,7 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="paper geometry (200-way 10-shot, CL=32); slow")
     ap.add_argument("--engine-backend", default="auto",
-                    choices=["auto", "ref", "pallas", "mxu", "fused"])
+                    choices=["auto", "ref", "pallas"])
     ap.add_argument("--two-phase-eval", action="store_true",
                     help="evaluate via the two-phase engine path "
                          "(shortlist + exact rescore) instead of full search")

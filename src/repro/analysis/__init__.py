@@ -16,11 +16,6 @@ Three passes, one CLI (`python -m repro.analysis`):
              drift or a lost route exits non-zero (CI gates pushes
              against the committed RESOURCES_baseline.json).
 
-repro/analysis/vmem.py is the symbolic VMEM side of the oracle: a
-closed-form per-tile footprint of the fused shortlist kernel, used by
-benchmarks/autotune_shortlist.py to reject over-budget tile configs
-before a sweep ever lowers them.
-
 The test suite asserts its HLO expectations through the same
 `hlo_contracts.assert_*` helpers the registry checks with, so every
 invariant has exactly ONE spelling -- and every

@@ -21,8 +21,8 @@ Three layers:
   delegate: it builds the compiled variants, the math is here.
 
 * The per-route resource report over the PR-7 contract registry:
-  `resource_report` walks `registry.build_cells()` (the full mode x
-  backend x sharded x packed x threshold-side matrix) and emits one row
+  `resource_report` walks `registry.build_cells()` (the mode x backend
+  x unsharded/sharded/routed/tenant matrix) and emits one row
   {flops, hbm_bytes_read/written, temp_bytes, peak_bytes, jit_entries,
   op_census} per (entry x config) route; `diff_resource_reports` gates a
   fresh report against the committed RESOURCES_baseline.json. CLI:

@@ -2,8 +2,8 @@
 
 The architecture's contract is mostly *negative* statements about compiled
 programs: a store-based search must not re-run `layout_support`, a sharded
-write must not emit collectives or scatter, the fused shortlist kernel must
-engage exactly when the dispatch rule says so. Those statements used to be
+write must not emit collectives or scatter, the phase-0 router must engage
+exactly when a request routes. Those statements used to be
 string asserts scattered through individual tests, each with its own list
 of op spellings. This module is now the single home:
 
@@ -17,9 +17,9 @@ of op spellings. This module is now the single home:
 Checkers take the compiled HLO text (`jit(...).lower(...).compile()
 .as_text()`) and return the list of offending HLO lines -- empty means the
 invariant holds. The scope tags they look for are real compiler metadata:
-`layout_support` and `shortlist_fused` are `jax.named_scope` tags that
+`layout_support` and `router_sketch` are `jax.named_scope` tags that
 survive into HLO op metadata (see repro/core/avss.py and
-repro/kernels/shortlist.py).
+repro/engine/router.py).
 """
 
 from __future__ import annotations
@@ -40,11 +40,6 @@ SCATTER_SPELLINGS = ("scatter(", "dynamic-update-slice")
 # (repro/core/avss.layout_support): store-based searches jit against the
 # write-time grids and must not contain it.
 LAYOUT_SCOPE_TAG = "layout_support"
-
-# jax.named_scope tag wrapping the fused Pallas shortlist
-# (repro/kernels/shortlist.lut_shortlist_pallas): present in compiled HLO
-# iff the fused kernel was traced.
-FUSED_SCOPE_TAG = "shortlist_fused"
 
 # jax.named_scope tag wrapping the phase-0 router's sketch matmul
 # (repro/engine/router.route_scores): present in compiled HLO iff a
@@ -265,18 +260,6 @@ def check_layout_ops_present(hlo: str) -> list[str]:
             f"layout) but the compiled program contains none"]
 
 
-def check_fused_tag(hlo: str, expected: bool) -> list[str]:
-    """The `shortlist_fused` scope tag appears iff the dispatch rule
-    (repro/engine/sharded._use_fused) says the fused kernel engages."""
-    lines = scoped_lines(hlo, FUSED_SCOPE_TAG)
-    if expected and not lines:
-        return [f"dispatch rule says the fused shortlist engages but the "
-                f"{FUSED_SCOPE_TAG!r} tag is absent from the compiled HLO"]
-    if not expected and lines:
-        return lines
-    return []
-
-
 def check_router_tag(hlo: str, expected: bool) -> list[str]:
     """The `router_sketch` scope tag appears iff routing is engaged
     (`SearchRequest.nprobe < store.n_shards`) -- exhaustive searches must
@@ -288,6 +271,17 @@ def check_router_tag(hlo: str, expected: bool) -> list[str]:
     if not expected and lines:
         return lines
     return []
+
+
+def check_phase1_one_block(hlo: str) -> list[str]:
+    """Phase 1 ranks every query in one block: no `while` loop in the
+    `search_shortlist` layer. A search whose (B, N) f32 distance block
+    fits `engine.sharded.SHORTLIST_DENSE_MAX_BYTES` must compile to the
+    unchunked dot + top-k; query chunks (a loop) are for blocks over it."""
+    _, ops = parse_hlo(hlo)
+    return [f"{o.name}: a while loop in {SHORTLIST_SCOPE} ({o.op_name})"
+            for o in ops if o.opcode == "while"
+            and innermost_layer(o.op_name) == SHORTLIST_SCOPE]
 
 
 def check_no_f64(hlo: str) -> list[str]:
@@ -340,14 +334,13 @@ def assert_layout_ops_present(hlo: str) -> None:
     _raise(check_layout_ops_present(hlo), "layout scope tag not visible")
 
 
-def assert_fused_tag(hlo: str, expected: bool) -> None:
-    _raise(check_fused_tag(hlo, expected),
-           f"fused-shortlist tag mismatch (expected engaged={expected})")
-
-
 def assert_router_tag(hlo: str, expected: bool) -> None:
     _raise(check_router_tag(hlo, expected),
            f"router-sketch tag mismatch (expected engaged={expected})")
+
+
+def assert_phase1_one_block(hlo: str) -> None:
+    _raise(check_phase1_one_block(hlo), "phase 1 ranks in query chunks")
 
 
 def assert_no_f64(hlo: str) -> None:

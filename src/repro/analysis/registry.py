@@ -4,16 +4,15 @@ Every public entry point of the serving stack is registered here together
 with the invariants its compiled program must satisfy, across the full
 config matrix:
 
-  engine.search      mode (full/two_phase/ideal) x backend (ref/mxu/fused)
-                     x sharded/unsharded x packed/unpacked operand
-                     x fused_min_rows (forcing both sides of the dispatch)
-                     x routed (nprobe < n_shards on a host-partitioned
-                     store engages the phase-0 sketch router, PR 10: the
-                     `router_sketch` scope tag must appear iff routing is
-                     engaged, and the sketch matmul adds no collectives)
+  engine.search      mode (full/two_phase/ideal) x backend (ref/pallas)
+                     x unsharded / mesh-sharded / routed (nprobe <
+                     n_shards on a logically partitioned store engages the
+                     phase-0 sketch router: the `router_sketch`
+                     scope tag must appear iff routing is engaged, and the
+                     sketch matmul adds no collectives)
   engine.search_tenants
                      the vmapped multi-tenant dispatch (PR 9) over a
-                     ragged 5-tenant stack: same fused/layout/f64
+                     ragged 5-tenant stack: same layout/f64/phase-1
                      invariants as engine.search, plus zero collectives
                      on the tenant axis and the
                      single_jit_entry_across_tenants cache invariant
@@ -29,10 +28,9 @@ invariant -- the test suite asserts through the same functions), and
 writes results/contract_report.json with pass/fail per cell and the
 matched HLO lines on failure.
 
-The fused-tag expectation of every cell is computed from the SAME dispatch
-rule the engine uses (repro/engine/sharded._use_fused), so the registry
-can never drift from the implementation: a cell fails when compiled
-reality and the rule disagree, whichever of the two changed.
+Every search cell's (B, N) phase-1 block is far under
+`sharded.SHORTLIST_DENSE_MAX_BYTES`, so each must rank its queries in one
+block: no loop in the `search_shortlist` layer.
 
 Cells needing more devices than available are recorded as skipped with a
 reason (the CLI forces an 8-device host platform so nothing skips there).
@@ -48,7 +46,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.analysis import cost as cost_lib
 from repro.analysis import hlo_contracts as hc
 from repro.launch.mesh import make_mesh
 
@@ -56,17 +53,16 @@ from repro.launch.mesh import make_mesh
 CELL_K = 16
 #: queries per search-cell batch (the shared fixture's `qv`).
 CELL_B = 5
-#: fused_min_rows values forcing each side of the dispatch rule.
-FMR_FORCE_FUSED = 1
-FMR_FORCE_DENSE = 1 << 30
+#: the backends every search cell is registered on.
+CELL_BACKENDS = ("ref", "pallas")
 
 
 @dataclasses.dataclass(frozen=True)
 class Cell:
     """One entry-point configuration and the invariants checked on it.
 
-    build() returns the cell's artifacts: at least {"hlo": str}; fused
-    cells add "expect_fused", the HBM cells add "hbm", the jit-cache cell
+    build() returns the cell's artifacts: at least {"hlo": str}; routed
+    cells add "expect_router", the jit-cache cells
     "cache_size"/"expected".
     """
 
@@ -84,19 +80,6 @@ class Cell:
 # -- invariant name -> checker over cell artifacts --------------------------
 
 
-def _inv_hbm_buffer_bound(art: dict) -> list[str]:
-    h = art["hbm"]
-    if h["measured_bytes"] <= h["bound_bytes"]:
-        return []
-    if not h["strict"]:
-        # CPU interpret mode materialises the emulated kernel's blocks, so
-        # the O(B*k + N*4d) bound only binds on real TPU HBM; the measured
-        # bytes are still recorded in the report for trend tracking.
-        return []
-    return [f"temp buffers {h['measured_bytes']}B exceed the "
-            f"O(B*k + N*4d) bound {h['bound_bytes']}B"]
-
-
 def _inv_jit_cache(art: dict) -> list[str]:
     if art["cache_size"] == art["expected"]:
         return []
@@ -112,12 +95,10 @@ INVARIANTS: dict[str, Callable[[dict], list[str]]] = {
     "scatter_write_engaged": lambda a: hc.check_scatter_write(a["hlo"]),
     "no_layout_ops": lambda a: hc.check_no_layout_ops(a["hlo"]),
     "layout_ops_present": lambda a: hc.check_layout_ops_present(a["hlo"]),
-    "fused_tag_iff_dispatch_rule":
-        lambda a: hc.check_fused_tag(a["hlo"], a["expect_fused"]),
+    "phase1_one_block": lambda a: hc.check_phase1_one_block(a["hlo"]),
     "router_tag_iff_engaged":
         lambda a: hc.check_router_tag(a["hlo"], a["expect_router"]),
     "no_f64_promotion": lambda a: hc.check_no_f64(a["hlo"]),
-    "hbm_buffer_bound": _inv_hbm_buffer_bound,
     "single_jit_cache_entry_per_request_family": _inv_jit_cache,
     "single_jit_entry_across_tenants":
         lambda a: hc.check_single_jit_entry_across_tenants(
@@ -195,26 +176,24 @@ def _compile(fn, *args, mesh=None):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _unpacked(store):
-    """The same store streaming the WIDE projection: proj_packed dropped,
-    so every fused route takes the unpacked-operand path."""
-    return dataclasses.replace(store, proj_packed=None)
-
-
-def _expect_fused(backend: str, rows_loc: int, mode: str, fmr: int,
-                  batch: int = CELL_B) -> bool:
-    """The registry's expectation IS the engine's dispatch rule."""
-    from repro.engine.sharded import _use_fused
-    if mode == "full":
-        return False
-    return _use_fused(backend, batch, rows_loc, fmr)
-
-
 # -- cell builders ----------------------------------------------------------
 
 
-def _search_cell(mode: str, backend: str, fmr: int, packed: bool,
-                 sharded: bool, n_shards: int) -> Cell:
+def _search_invariants(mode: str, collectives: bool) -> tuple[str, ...]:
+    """Every search cell: no read-time layout, no f64, one phase-1 block
+    (the `full` mode has no phase 1), and no collectives unless the store
+    is mesh-sharded (the sharded searches all-gather the per-shard top-k
+    by design)."""
+    inv = ["no_layout_ops", "no_f64_promotion"]
+    if mode != "full":
+        inv.append("phase1_one_block")
+    if not collectives:
+        inv.append("no_collectives")
+    return tuple(inv)
+
+
+def _search_cell(mode: str, backend: str, sharded: bool,
+                 n_shards: int) -> Cell:
     from repro.engine import RetrievalEngine, SearchRequest
 
     def build() -> dict:
@@ -224,31 +203,13 @@ def _search_cell(mode: str, backend: str, fmr: int, packed: bool,
         if sharded:
             mesh = make_mesh((n_shards,), ("data",))
             store = store.shard(mesh, ("data",))
-        if not packed:
-            store = _unpacked(store)
         eng = RetrievalEngine(fx["cfg"], backend=backend)
-        req = SearchRequest(mode=mode, k=CELL_K, fused_min_rows=fmr)
+        req = SearchRequest(mode=mode, k=CELL_K)
         compiled = _compile(
             lambda st, q: eng.search(st, q, req).votes, store, qv,
             mesh=mesh)
-        rows_loc = store.capacity // (n_shards if sharded else 1)
-        art = {"hlo": compiled.as_text(), "compiled": compiled,
-               "expect_fused": _expect_fused(backend, rows_loc, mode, fmr,
-                                             batch=qv.shape[0])}
-        if mode == "ideal" and art["expect_fused"] and not sharded:
-            art["hbm"] = _hbm_stats(compiled, qv.shape[0], CELL_K,
-                                    store.capacity, store.dim)
-        return art
+        return {"hlo": compiled.as_text(), "compiled": compiled}
 
-    invariants = ["fused_tag_iff_dispatch_rule", "no_layout_ops",
-                  "no_f64_promotion"]
-    if not sharded:
-        # unsharded searches must not touch collectives at all; sharded
-        # two-phase/ideal all-gather the per-shard top-k by design
-        invariants.append("no_collectives")
-        if (mode == "ideal"
-                and _expect_fused(backend, 72, mode, fmr)):
-            invariants.append("hbm_buffer_bound")
     skip = ""
     if sharded and len(jax.devices()) < n_shards:
         skip = (f"needs {n_shards} devices, have {len(jax.devices())} "
@@ -256,13 +217,13 @@ def _search_cell(mode: str, backend: str, fmr: int, packed: bool,
                 f"an 8-device host platform)")
     return Cell(entry="engine.search",
                 config={"mode": mode, "backend": backend,
-                        "sharded": sharded, "packed": packed,
-                        "fused_min_rows": fmr},
-                invariants=tuple(invariants), build=build, skip=skip)
+                        "sharded": sharded},
+                invariants=_search_invariants(mode, sharded), build=build,
+                skip=skip)
 
 
-def _routed_cell(mode: str, backend: str, fmr: int, packed: bool,
-                 nprobe: int, n_shards: int = 8) -> Cell:
+def _routed_cell(mode: str, backend: str, nprobe: int,
+                 n_shards: int = 8) -> Cell:
     """engine.search with nprobe on a LOGICALLY partitioned store
     (`shard(n_shards=...)`, mesh-less -- so no device minimum and no
     collectives anywhere, sketch matmul included). nprobe < n_shards must
@@ -270,80 +231,41 @@ def _routed_cell(mode: str, backend: str, fmr: int, packed: bool,
     control: the SAME exhaustive program as nprobe=None, tag absent."""
     from repro.engine import RetrievalEngine, SearchRequest
 
-    engaged = nprobe < n_shards
-
     def build() -> dict:
         fx = _fix()
         store = fx["store"].shard(n_shards=n_shards)
-        if not packed:
-            store = _unpacked(store)
         eng = RetrievalEngine(fx["cfg"], backend=backend)
-        req = SearchRequest(mode=mode, k=CELL_K, fused_min_rows=fmr,
-                            nprobe=nprobe)
+        req = SearchRequest(mode=mode, k=CELL_K, nprobe=nprobe)
         compiled = _compile(
             lambda st, q: eng.search(st, q, req).votes, store, fx["qv"])
-        # the routed shortlist ranks the CONCATENATED visited blocks:
-        # rows_loc = nprobe * rows_per_shard; the control is exhaustive
-        # over the whole (unsharded-dispatch) store
-        rows_loc = (nprobe * (store.capacity // n_shards) if engaged
-                    else store.capacity)
         return {"hlo": compiled.as_text(), "compiled": compiled,
-                "expect_router": engaged,
-                "expect_fused": _expect_fused(backend, rows_loc, mode,
-                                              fmr)}
+                "expect_router": nprobe < n_shards}
 
     return Cell(entry="engine.search",
-                config={"mode": mode, "backend": backend, "packed": packed,
-                        "fused_min_rows": fmr, "nprobe": nprobe,
+                config={"mode": mode, "backend": backend, "nprobe": nprobe,
                         "n_shards": n_shards},
                 invariants=("router_tag_iff_engaged",
-                            "fused_tag_iff_dispatch_rule", "no_layout_ops",
-                            "no_f64_promotion", "no_collectives"),
+                            *_search_invariants(mode, False)),
                 build=build)
 
 
-def _hbm_stats(compiled, B: int, k: int, N: int, d: int) -> dict:
-    """Temp-buffer bytes of the compiled cell vs the O(B*k + N*4d) bound
-    the fused shortlist advertises (kernels/shortlist.py): the per-query
-    top-k buffers plus one pass over the streamed projection, times 4 for
-    dtype width and double-buffering slack. Strict only on TPU -- the CPU
-    interpreter materialises emulated blocks, so there the measured bytes
-    are recorded (trend data) without binding."""
-    kp = 128 if k <= 128 else k                 # lane-width internal pad
-    bound = 4 * 4 * (B * kp * 2 + N * 4 * d)
-    measured = cost_lib.temp_bytes(compiled)
-    return {"measured_bytes": measured, "bound_bytes": bound,
-            "strict": jax.default_backend() == "tpu"}
-
-
-def _tenant_search_cell(mode: str, backend: str, fmr: int,
-                        packed: bool) -> Cell:
+def _tenant_search_cell(mode: str, backend: str) -> Cell:
     from repro.engine import RetrievalEngine, SearchRequest
 
     def build() -> dict:
         fx = _tenant_fix()
-        tstore, qv, tids = fx["tstore"], fx["qv"], fx["tids"]
-        if not packed:
-            tstore = _unpacked(tstore)
         eng = RetrievalEngine(fx["cfg"], backend=backend)
-        req = SearchRequest(mode=mode, k=CELL_K, fused_min_rows=fmr)
+        req = SearchRequest(mode=mode, k=CELL_K)
         compiled = _compile(
             lambda ts, q, i: eng.search_tenants(ts, q, i, req).votes,
-            tstore, qv, tids)
-        # the per-query vmapped search sees every tenant at the PADDED
-        # row count -- that is the rows_loc the dispatch rule acts on
-        return {"hlo": compiled.as_text(), "compiled": compiled,
-                "expect_fused": _expect_fused(backend, tstore.n_pad,
-                                              mode, fmr, batch=qv.shape[0])}
+            fx["tstore"], fx["qv"], fx["tids"])
+        return {"hlo": compiled.as_text(), "compiled": compiled}
 
     # the tenant axis is a pure batch axis: beyond the solo-search
     # invariants, the vmapped program must introduce ZERO collectives
     return Cell(entry="engine.search_tenants",
-                config={"mode": mode, "backend": backend, "packed": packed,
-                        "fused_min_rows": fmr},
-                invariants=("fused_tag_iff_dispatch_rule", "no_layout_ops",
-                            "no_f64_promotion", "no_collectives"),
-                build=build)
+                config={"mode": mode, "backend": backend},
+                invariants=_search_invariants(mode, False), build=build)
 
 
 def _tenant_jit_cache_cell() -> Cell:
@@ -488,55 +410,19 @@ def build_cells() -> list[Cell]:
     n_shards = max(2, min(8, n_dev))            # what the CLI forces to 8
     cells: list[Cell] = []
 
-    # engine.search, unsharded
-    for mode in ("full", "two_phase", "ideal"):
-        for backend in ("ref", "mxu", "fused"):
-            fmrs = ((FMR_FORCE_FUSED,) if mode == "full"
-                    or backend == "ref" else (FMR_FORCE_FUSED,
-                                              FMR_FORCE_DENSE))
-            for fmr in fmrs:
-                cells.append(_search_cell(mode, backend, fmr, True,
-                                          False, 1))
-                if _expect_fused(backend, 72, mode, fmr):
-                    # fused cells also cover the unpacked-operand route
-                    cells.append(_search_cell(mode, backend, fmr, False,
-                                              False, 1))
-
-    # engine.search, sharded (two_phase/ideal dispatch through shard_map)
-    for mode in ("two_phase", "ideal"):
-        for backend, fmr in (("mxu", FMR_FORCE_FUSED),
-                             ("mxu", FMR_FORCE_DENSE),
-                             ("fused", FMR_FORCE_DENSE)):
-            cells.append(_search_cell(mode, backend, fmr, True, True,
-                                      n_shards))
-        cells.append(_search_cell(mode, "fused", FMR_FORCE_DENSE, False,
-                                  True, n_shards))
-
-    # engine.search, routed (PR 10): nprobe over a host-partitioned store
-    # -- both phase-1 dispositions (dense mxu / fused packed + unpacked)
-    # plus the nprobe == n_shards control whose program must contain NO
-    # router tag (it IS the exhaustive search)
-    cells.append(_routed_cell("two_phase", "mxu", FMR_FORCE_DENSE, True, 2))
-    cells.append(_routed_cell("two_phase", "fused", FMR_FORCE_FUSED, True,
-                              2))
-    cells.append(_routed_cell("ideal", "fused", FMR_FORCE_FUSED, True, 2))
-    cells.append(_routed_cell("ideal", "fused", FMR_FORCE_FUSED, False, 2))
-    cells.append(_routed_cell("two_phase", "mxu", FMR_FORCE_DENSE, True, 8))
-
-    # engine.search_tenants: the vmapped multi-tenant dispatch (PR 9) --
-    # one cell per representative route (full dense x ref/mxu, two-phase
-    # on both sides of the fused threshold, fused ideal packed + unpacked)
-    # plus the cross-tenant-count jit cache cell
-    cells.append(_tenant_search_cell("full", "ref", FMR_FORCE_FUSED, True))
-    cells.append(_tenant_search_cell("full", "mxu", FMR_FORCE_FUSED, True))
-    cells.append(_tenant_search_cell("two_phase", "mxu", FMR_FORCE_DENSE,
-                                     True))
-    cells.append(_tenant_search_cell("two_phase", "fused", FMR_FORCE_FUSED,
-                                     True))
-    cells.append(_tenant_search_cell("ideal", "fused", FMR_FORCE_FUSED,
-                                     True))
-    cells.append(_tenant_search_cell("ideal", "fused", FMR_FORCE_FUSED,
-                                     False))
+    # engine.search: unsharded (every mode), mesh-sharded and routed
+    # (the shortlist modes), the routed nprobe == n_shards control (the
+    # exhaustive program: NO router tag), and the vmapped multi-tenant
+    # dispatch (every mode) plus its cross-tenant-count jit cell
+    for backend in CELL_BACKENDS:
+        for mode in ("full", "two_phase", "ideal"):
+            cells.append(_search_cell(mode, backend, False, 1))
+        for mode in ("two_phase", "ideal"):
+            cells.append(_search_cell(mode, backend, True, n_shards))
+            cells.append(_routed_cell(mode, backend, 2))
+        for mode in ("full", "two_phase", "ideal"):
+            cells.append(_tenant_search_cell(mode, backend))
+    cells.append(_routed_cell("two_phase", "pallas", 8))
     cells.append(_tenant_jit_cache_cell())
 
     # MemoryStore.write: scatter vs write-through per n_shards
@@ -581,8 +467,6 @@ def run_cells(cells: list[Cell] | None = None) -> dict:
                    "status": "fail" if violations else "pass",
                    "detail": violations[0] if violations else "",
                    "matched": violations[:8]}
-            if inv == "hbm_buffer_bound":
-                row["hbm"] = art["hbm"]
             rows.append(row)
     summary = {s: sum(1 for r in rows if r["status"] == s)
                for s in ("pass", "fail", "error", "skip")}

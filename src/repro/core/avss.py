@@ -45,7 +45,7 @@ class SearchConfig:
     mode: Mode = "avss"
     mcam: MCAMConfig = dataclasses.field(default_factory=MCAMConfig)
     noisy: bool = True          # device/read noise on (paper-faithful)
-    use_kernel: str = "auto"    # 'ref' | 'pallas' | 'mxu' | 'auto'
+    use_kernel: str = "auto"    # 'ref' | 'pallas' | 'auto'
     query_chunk: int = 8        # reference-path chunking over queries
 
     @property

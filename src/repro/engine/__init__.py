@@ -1,4 +1,4 @@
-"""Unified retrieval engine: one API over the ref / Pallas / MXU-LUT backends.
+"""Unified retrieval engine: one API over the ref and Pallas backends.
 
 The serving contract (PR 2) is store-centric:
 

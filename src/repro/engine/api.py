@@ -8,7 +8,7 @@ replacing the five overlapping ad-hoc paths of the pre-redesign API
 (`engine.full` / `engine.two_phase` / `engine.sharded_two_phase`,
 `memory.search` / `memory.distributed_search`) and their untyped result
 dicts. The request names WHAT to search (mode, k, backend, shard axes,
-fused-shortlist threshold); the store (repro/engine/store.py) carries the
+noise, routing); the store (repro/engine/store.py) carries the
 programmed memory and its sharding; the result is a registered pytree safe
 to return from jit.
 
@@ -48,19 +48,9 @@ class SearchRequest:
                           (the cheapest serving path).
     k:       candidate count for 'two_phase' / 'ideal' (ignored by 'full').
     backend: 'auto' defers to the engine's backend; any other value
-             ('ref' | 'pallas' | 'mxu' | 'fused') overrides it per request.
+             ('ref' | 'pallas') overrides it per request.
     axes:    shard axes override; None defers to the store's own sharding
              (`MemoryStore.shard` records mesh + axes on the store).
-    fused_min_rows: per-request override of the engine's fused-shortlist
-             row threshold (None defers to the engine). Shortlists -- the
-             'ideal' mode and phase 1 of 'two_phase', per SHARD-LOCAL block
-             on a sharded store -- take the dense XLA dot + top_k while
-             their (B, N) f32 block fits
-             `sharded.SHORTLIST_DENSE_MAX_BYTES`, the fused Pallas kernel
-             (repro/kernels/shortlist.py) beyond it, and also once the
-             row count reaches this threshold when one is set; results
-             are bit-identical either way, so this is purely a
-             performance knob.
     noisy:   per-request override of SearchConfig.noisy (None defers to
              the config). noisy=False serves the NOISELESS hardware
              forward on any mode/backend/sharding -- the serving side of
@@ -93,7 +83,6 @@ class SearchRequest:
     k: int = 64
     backend: str = "auto"
     axes: tuple[str, ...] | None = None
-    fused_min_rows: int | None = None
     noisy: bool | None = None
     nprobe: int | None = None
 

@@ -1,24 +1,20 @@
 """Backend registry and resolution for the retrieval engine.
 
-Backends (every one produces bit-identical results -- the choice is purely a
+Backends (both produce bit-identical results -- the choice is purely a
 performance/hardware decision, see the parity suite in tests/test_engine.py):
 
   ref     pure-jnp reference (kernels/ref.py semantics); always available.
   pallas  fused Pallas VPU search kernel (kernels/mcam_search.py) for the
-          full search; shortlists by the shared dispatch rule
-          (engine/sharded._use_fused): XLA's dense dot + lax.top_k, or the
-          fused shortlist kernel for a (B, N) block over the budget.
-  mxu     alias of `pallas`.
-  fused   shortlists always via the fused distance+top-k Pallas kernel
-          (kernels/shortlist.py); full search as `pallas`.
+          full search. Shortlists take the one phase-1 route every site
+          shares (engine/sharded._local_shortlist): XLA's dense dot of the
+          query one-hots against the stored projection + lax.top_k.
 """
 
 from __future__ import annotations
 
 import functools
 
-BACKENDS = ("ref", "pallas", "mxu", "fused")
-KERNEL_BACKENDS = ("pallas", "mxu", "fused")
+BACKENDS = ("ref", "pallas")
 
 
 @functools.cache

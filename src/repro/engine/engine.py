@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import TYPE_CHECKING, Any, Hashable, Sequence
+from typing import TYPE_CHECKING, Any, Callable, Hashable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -65,27 +65,32 @@ def _noise_stream(key: jax.Array | int | None) -> jax.Array | None:
         s = mcam_lib._mix(s ^ arr[i])
     return s
 
+
+def _map_queries(one: Callable[..., Any], chunk: int, *xs: Any) -> Any:
+    """The per-query function `one` over the leading (query) axis of the
+    pytrees `xs`: `jax.vmap` while `chunk` covers the batch, else
+    `jax.lax.map` in batches of `chunk` queries, so at most `chunk`
+    queries' phase-1 blocks are live at a time (the loop is phase 1's, and
+    takes its scope). `one` sees one query either way, so the results are
+    the same bits."""
+    if chunk >= jax.tree_util.tree_leaves(xs)[0].shape[0]:
+        return jax.vmap(one)(*xs)
+    with jax.named_scope(hc.SHORTLIST_SCOPE):
+        return jax.lax.map(lambda a: one(*a), xs, batch_size=chunk)
+
+
 @dataclasses.dataclass(frozen=True)
 class RetrievalEngine:
     """Dispatches AVSS/SVSS searches to a selected backend.
 
     cfg:      the end-to-end search configuration (encoding, MCAM physics,
               noise). `cfg.use_kernel` is honoured as a fallback preference.
-    backend:  'auto' | 'ref' | 'pallas' | 'mxu' | 'fused'; overrides
-              cfg.use_kernel when not 'auto'.
-    fused_min_rows: optional row count from which shortlists run the
-              fused Pallas kernel (per shard-local block on sharded
-              stores). None leaves phase 1 to the shape rule
-              (`sharded._use_fused`): the dense XLA route while its
-              (B, N) f32 block fits `sharded.SHORTLIST_DENSE_MAX_BYTES`,
-              the kernel beyond. 'fused' always fuses and 'ref' never
-              does. `SearchRequest.fused_min_rows` overrides this per
-              request.
+    backend:  'auto' | 'ref' | 'pallas'; overrides cfg.use_kernel when not
+              'auto'.
     """
 
     cfg: SearchConfig
     backend: str = "auto"
-    fused_min_rows: int | None = None
 
     @property
     def resolved_backend(self) -> str:
@@ -124,15 +129,6 @@ class RetrievalEngine:
         return self._cached_replace(
             ("noisy", noisy), cfg=dataclasses.replace(self.cfg, noisy=noisy))
 
-    def _fused_threshold(self, request: SearchRequest | None = None
-                         ) -> int | None:
-        """Effective fused-shortlist row threshold: the request override
-        when set, else this engine's `fused_min_rows` (None: the shape
-        rule alone decides)."""
-        if request is not None and request.fused_min_rows is not None:
-            return request.fused_min_rows
-        return self.fused_min_rows
-
     # -- unified entry point -----------------------------------------------
 
     def search(self, store: MemoryStore, queries: jax.Array,
@@ -145,13 +141,13 @@ class RetrievalEngine:
                   (mesh, axes) metadata selects the sharded dispatch.
         queries:  (B, dim) float embeddings (quantized with the store's
                   calibrated range) or pre-quantized ints (passed through).
-        request:  SearchRequest (mode, k, backend, axes, fused threshold);
+        request:  SearchRequest (mode, k, backend, axes, noise, routing);
                   default two-phase.
 
         Results are bit-identical to the raw-array methods below for every
         mode/backend/sharding (tests/test_engine.py, tests/test_store.py)
-        -- including whether a shortlist ran the dense XLA dot + top_k or
-        the fused Pallas kernel (the dispatch is purely a perf decision).
+        -- including whether a shortlist ranked its queries in one block or
+        in chunks (`sharded.shortlist_chunk`).
 
         >>> import jax.numpy as jnp
         >>> from repro.core.avss import SearchConfig
@@ -187,11 +183,9 @@ class RetrievalEngine:
         if store.mesh is None or req.mode == "full":
             return eng._search_unsharded(store, q, req)
 
-        # per-shard shortlists share the unsharded dispatch rule, on each
-        # shard's LOCAL (B, N_loc) block (engine/sharded._use_fused)
+        # per-shard shortlists share the unsharded phase-1 block, chunked on
+        # each shard's LOCAL (B, N_loc) block (engine/sharded.shortlist_chunk)
         axes = req.axes if req.axes is not None else store.axes
-        fmr = eng._fused_threshold(req)
-        backend = eng.resolved_backend
         if req.mode == "two_phase":
             from repro.engine import sharded
             with jax.named_scope(hc.SHORTLIST_SCOPE):
@@ -199,9 +193,7 @@ class RetrievalEngine:
             res = sharded.sharded_two_phase_search(
                 q, store.values, eng.cfg, store.mesh, axes=axes,
                 k=req.k, valid=valid, labels=store.labels,
-                s_grid=store.s_grid, proj=store.proj,
-                packed=store.proj_packed, pack_bits=store.pack_bits,
-                backend=backend, fused_min_rows=fmr)
+                s_grid=store.s_grid, proj=store.proj)
             # labels come from the per-shard fold (-1 on empty/pad
             # rows): mask their votes without any global gather
             with jax.named_scope(hc.ANSWER_SCOPE):
@@ -214,10 +206,7 @@ class RetrievalEngine:
         with jax.named_scope(hc.SHORTLIST_SCOPE):
             q1h = kernel_ops.query_onehot(q, jnp.float32)
         res = sharded.sharded_ideal_search(
-            q1h, store.proj, store.labels, store.mesh, axes=axes,
-            k=req.k, backend=backend, fused_min_rows=fmr,
-            packed=store.proj_packed, pack_bits=store.pack_bits,
-            enc=eng.cfg.enc)
+            q1h, store.proj, store.labels, store.mesh, axes=axes, k=req.k)
         with jax.named_scope(hc.ANSWER_SCOPE):
             votes = jnp.where(res["labels"] >= 0, res["votes"], -jnp.inf)
         return SearchResult(votes, res["dist"], res["indices"],
@@ -225,18 +214,16 @@ class RetrievalEngine:
 
     def _search_unsharded(self, store: MemoryStore, q: jax.Array,
                           req: SearchRequest,
-                          noise_qidx: jax.Array | None = None,
-                          batch: int | None = None) -> SearchResult:
+                          noise_qidx: jax.Array | None = None
+                          ) -> SearchResult:
         """The unsharded (single-block) search body shared by `search` and
         `search_tenants`: `self` must already carry the request's backend
         and noisy overrides, `q` is already quantized. `noise_qidx` (B,)
         overrides the per-query noise coordinates (see `full`); `search`
         leaves it None (arange(B)), `search_tenants` passes each query's
         rank within its tenant group so the vmapped dispatch is
-        bit-identical to per-tenant solo calls. `batch` is the query count
-        of the whole dense block the shortlist dispatch sizes (default
-        q's; `search_tenants` vmaps this body one query at a time)."""
-        from repro.engine.sharded import _local_shortlist, _use_fused
+        bit-identical to per-tenant solo calls."""
+        from repro.engine.sharded import _local_shortlist
         with jax.named_scope(hc.SHORTLIST_SCOPE):   # phase 1's row mask
             valid = store.valid
         iters = self._iterations(q.shape[-1])
@@ -252,13 +239,9 @@ class RetrievalEngine:
                 labels = jnp.broadcast_to(store.labels, votes.shape)
             return SearchResult(votes, res["dist"], indices, labels,
                                 res["iterations"])
-        fused = _use_fused(self.resolved_backend, batch or q.shape[0],
-                           store.capacity, self._fused_threshold(req))
         if req.mode == "two_phase":
             res = self.two_phase(q, store.values, k=req.k, valid=valid,
                                  s_grid=store.s_grid, proj=store.proj,
-                                 packed=store.proj_packed,
-                                 pack_bits=store.pack_bits, fused=fused,
                                  noise_qidx=noise_qidx)
             with jax.named_scope(hc.ANSWER_SCOPE):
                 labels = store.labels[res["indices"]]  # -1 on empty slots
@@ -268,21 +251,12 @@ class RetrievalEngine:
         # ideal: top-k by the exact integer-valued digital distance against
         # the write-time LUT projection. Masked rows carry the integer-exact
         # SHORTLIST_MASK_PENALTY (the same contract as two_phase / the
-        # sharded ideal path), so both routes below are bit-identical: the
-        # dense XLA dot + top_k while its (B, N) block fits the budget, the
-        # fused Pallas kernel (HBM O(B*k + N*4d)) beyond it.
+        # sharded ideal path).
         from repro.kernels import ops as kernel_ops
         k = min(req.k, store.capacity)
         with jax.named_scope(hc.SHORTLIST_SCOPE):
-            if fused:
-                dist, idx = kernel_ops.lut_shortlist(
-                    q, store.values, self.cfg.enc, k, valid=valid,
-                    proj=store.proj, packed=store.proj_packed,
-                    pack_bits=store.pack_bits)
-            else:
-                q1h = kernel_ops.query_onehot(q, store.proj.dtype)
-                dist, idx = _local_shortlist(q1h, store.proj, valid, k,
-                                             fused=False)
+            q1h = kernel_ops.query_onehot(q, store.proj.dtype)
+            dist, idx = _local_shortlist(q1h, store.proj, valid, k)
         with jax.named_scope(hc.ANSWER_SCOPE):
             labels = store.labels[idx]
             votes = jnp.where(labels >= 0, -dist, -jnp.inf)
@@ -312,19 +286,16 @@ class RetrievalEngine:
             # store these reshapes stay sharded and XLA inserts the
             # per-query block gathers (the single-device / logical-partition
             # path is the one the routed contract cells pin collective-free)
-            packed_t = (None if store.proj_packed is None
-                        else store.proj_packed.reshape(s, rows, -1))
             return self._routed_block_search(
                 q, sids, jnp.arange(s, dtype=jnp.int32),
-                store.proj.reshape(s, rows, -1), packed_t,
+                store.proj.reshape(s, rows, -1),
                 store.s_grid.reshape((s, rows) + store.s_grid.shape[1:]),
-                store.labels.reshape(s, rows), req, store.pack_bits)
+                store.labels.reshape(s, rows), req)
 
     def _routed_block_search(self, q: jax.Array, slot_ids: jax.Array,
                              shard_of: jax.Array, proj_t: jax.Array,
-                             packed_t: jax.Array | None,
                              sgrid_t: jax.Array, labels_t: jax.Array,
-                             req: SearchRequest, pack_bits: int,
+                             req: SearchRequest,
                              noise_qidx: jax.Array | None = None
                              ) -> SearchResult:
         """Shared routed-search core over per-shard block tables.
@@ -335,14 +306,16 @@ class RetrievalEngine:
         global-shard map. Per query, `slot_ids` (B, p) names the visited
         table rows ORDERED BY ASCENDING GLOBAL SHARD ID -- concatenating
         the blocks in that order makes the candidate axis globally
-        index-ordered, so the shared `_local_shortlist` (fused kernel or
-        dense matmul, same mask penalty) reproduces the exhaustive
-        search's (distance, global index) lex order exactly on the
-        visited subset. Phase 2 rescores with GLOBAL indices feeding the
-        noise counters, so routed votes equal the full search's votes for
-        every shortlisted candidate.
+        index-ordered, so the shared `_local_shortlist` (same dot, same
+        mask penalty) reproduces the exhaustive search's (distance, global
+        index) lex order exactly on the visited subset. Phase 2 rescores
+        with GLOBAL indices feeding the noise counters, so routed votes
+        equal the full search's votes for every shortlisted candidate.
+        The per-query search is vmapped over the batch, or mapped over it
+        in chunks where the batch's (B, p * rows) f32 block would exceed
+        the phase-1 budget (`sharded.shortlist_chunk`).
         """
-        from repro.engine.sharded import _local_shortlist, _use_fused
+        from repro.engine.sharded import _local_shortlist, shortlist_chunk
         from repro.kernels import ops as kernel_ops
         cfg = self.cfg
         assert cfg.mode == "avss", "routed search shortlists the AVSS LUT"
@@ -350,8 +323,6 @@ class RetrievalEngine:
         rows = proj_t.shape[1]
         rows_vis = p * rows
         k = min(req.k, rows_vis)
-        fused = _use_fused(self.resolved_backend, q.shape[0], rows_vis,
-                           self._fused_threshold(req))
         two_phase = req.mode == "two_phase"
         with jax.named_scope(hc.SHORTLIST_SCOPE):
             q1h = kernel_ops.query_onehot(q, jnp.float32)
@@ -368,11 +339,8 @@ class RetrievalEngine:
                 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
             lab_vis = labels_t[sl_b].reshape(rows_vis)
             proj_vis = proj_t[sl_b].reshape(rows_vis, -1)
-            pk_vis = (packed_t[sl_b].reshape(rows_vis, -1)
-                      if fused and packed_t is not None else None)
             dist, li = _local_shortlist(q1h_b[None], proj_vis,
-                                        lab_vis >= 0, k, fused=fused,
-                                        packed=pk_vis, pack_bits=pack_bits)
+                                        lab_vis >= 0, k)
             # local candidate position -> global store row: visited blocks
             # are ascending-shard-ordered, block i covers global rows
             # [shard_of[sl_b[i]] * rows, ...)
@@ -391,7 +359,8 @@ class RetrievalEngine:
                 votes = jnp.where(lab >= 0, votes, -jnp.inf)
             return votes[0], dist[0], gidx[0], lab[0]
 
-        votes, dist, indices, labels = jax.vmap(one)(
+        votes, dist, indices, labels = _map_queries(
+            one, shortlist_chunk(q.shape[0], rows_vis),
             q1h, q_grid, slot_ids, noise_qidx.astype(jnp.uint32))
         return SearchResult(votes, dist, indices, labels,
                             self._iterations(q.shape[-1]))
@@ -413,14 +382,16 @@ class RetrievalEngine:
                     hit the same compiled program (one jit cache entry per
                     tenant count/batch shape, asserted by the
                     `single_jit_entry_across_tenants` contract cell).
-        request:    SearchRequest; `mode`/`backend`/`k`/`fused_min_rows`/
-                    `noisy` all apply (axes is meaningless here -- tenant
-                    stacks are unsharded).
+        request:    SearchRequest; `mode`/`backend`/`k`/`noisy` all apply
+                    (axes is meaningless here -- tenant stacks are
+                    unsharded).
 
         Dispatch: gather each query's tenant leaves out of the stacked
         store and vmap the single-query unsharded search over the batch --
-        full/two_phase/ideal x ref/mxu/fused (the Pallas kernels batch
-        under vmap), bit-identical per tenant to solo `engine.search` on
+        full/two_phase/ideal x ref/pallas (the Pallas kernels batch under
+        vmap; a shortlist batch whose (B, N) f32 block is over the phase-1
+        budget is mapped in chunks, `sharded.shortlist_chunk`),
+        bit-identical per tenant to solo `engine.search` on
         `tstore.tenant(i)` for queries grouped in batch order (the noise
         coordinates are each query's rank within its tenant group, exactly
         the solo batch positions; tests/test_tenant.py). Results span the
@@ -453,10 +424,13 @@ class RetrievalEngine:
         def one(store_b: MemoryStore, q_b: jax.Array,
                 rank_b: jax.Array) -> SearchResult:
             return eng._search_unsharded(store_b, q_b[None], req,
-                                         noise_qidx=rank_b[None],
-                                         batch=q.shape[0])
+                                         noise_qidx=rank_b[None])
 
-        res = jax.vmap(one)(view, q, rank)
+        chunk = q.shape[0]
+        if req.mode != "full":
+            from repro.engine.sharded import shortlist_chunk
+            chunk = shortlist_chunk(q.shape[0], tstore.n_pad)
+        res = _map_queries(one, chunk, view, q, rank)
         # drop the inner singleton query axis: (B, 1, K) -> (B, K)
         return SearchResult(res.votes[:, 0], res.dist[:, 0],
                             res.indices[:, 0], res.labels[:, 0],
@@ -609,7 +583,7 @@ class RetrievalEngine:
                     lambda args: fn(args[0], s_grid, args[1]),
                     (q_grid, noise_qidx.astype(jnp.uint32)),
                     batch_size=min(cfg.query_chunk, q_grid.shape[0]))
-            else:  # pallas / mxu / fused all use the fused VPU search kernel
+            else:  # pallas: the fused VPU search kernel
                 from repro.kernels import ops as kernel_ops
                 votes, dist = kernel_ops.mcam_search(
                     q_grid, s_grid, weights, cfg, thresholds,
@@ -621,10 +595,7 @@ class RetrievalEngine:
 
     def shortlist(self, q_values: jax.Array, s_values: jax.Array, k: int,
                   valid: jax.Array | None = None,
-                  proj: jax.Array | None = None,
-                  packed: jax.Array | None = None,
-                  pack_bits: int | None = None,
-                  fused: bool | None = None
+                  proj: jax.Array | None = None
                   ) -> tuple[jax.Array, jax.Array]:
         """Top-k supports by ideal digital AVSS distance.
 
@@ -638,52 +609,39 @@ class RetrievalEngine:
         and sharding bit-parity). Their returned dist includes the penalty.
 
         proj: optional write-time LUT projection (MemoryStore.proj) for the
-        kernel backends; identical to recomputing it from s_values (the
+        kernel backend; identical to recomputing it from s_values (the
         projection is a deterministic function of the values), just hoisted
-        out of the search. The ref backend always recomputes -- it is the
-        readable reference, and its distances are bit-identical anyway.
-
-        packed: optional bit-packed projection (MemoryStore.proj_packed);
-        the fused kernel then streams the 4-8x smaller int32 operand
-        instead of `proj`, bit-identically (kernels/shortlist.py).
-        pack_bits: the width `packed` was packed with (MemoryStore
-        .pack_bits); required whenever `packed` is given without the
-        matching `proj` (the width depends on the packing dtype).
-
-        fused: run the fused Pallas kernel (True) or the dense route
-        (False); None applies the dispatch rule every shortlist site
-        shares (`sharded._use_fused`) to this call's (B, N) with the
-        engine's `fused_min_rows`. The dense route on a kernel backend is
-        the same XLA dot against `proj` + lax.top_k as every other site
-        (`sharded._local_shortlist`); 'ref' keeps its readable
-        recomputation from s_values.
+        out of the search. The kernel backend runs the same XLA dot against
+        `proj` + lax.top_k as every other site (`sharded._local_shortlist`);
+        'ref' keeps its readable recomputation from s_values. Both rank the
+        queries in chunks where the (B, N) f32 block is over the phase-1
+        budget (`sharded.shortlist_chunk`).
         """
-        from repro.engine.sharded import _local_shortlist, _use_fused
+        from repro.engine.sharded import (_local_shortlist, map_query_chunks,
+                                          shortlist_chunk)
         from repro.kernels import ops as kernel_ops
         cfg = self.cfg
         assert cfg.mode == "avss", "shortlists use the AVSS LUT"
         k = min(k, s_values.shape[0])
-        backend = self.resolved_backend
-        if fused is None:
-            fused = _use_fused(backend, q_values.shape[0], s_values.shape[0],
-                               self.fused_min_rows)
         with jax.named_scope(hc.SHORTLIST_SCOPE):
-            if fused:
-                return kernel_ops.lut_shortlist(
-                    q_values, s_values, cfg.enc, k, valid=valid, proj=proj,
-                    packed=packed, pack_bits=pack_bits)
-            if backend != "ref":
+            if self.resolved_backend != "ref":
                 if proj is None:
                     proj = kernel_ops.support_projection(s_values, cfg.enc)
                 q1h = kernel_ops.query_onehot(q_values, proj.dtype)
-                return _local_shortlist(q1h, proj, valid, k, fused=False)
+                return _local_shortlist(q1h, proj, valid, k)
             lut = jnp.asarray(enc_lib.avss_sum_lut(cfg.enc), jnp.float32)
-            dist = ref_kernels.avss_dist_ref(q_values, s_values, lut)
-            if valid is not None:
-                dist = dist + jnp.where(
-                    valid, 0.0, kernel_ops.SHORTLIST_MASK_PENALTY)[None]
-            neg, idx = jax.lax.top_k(-dist, k)
-            return -neg, idx
+
+            def block(q: jax.Array) -> tuple[jax.Array, jax.Array]:
+                dist = ref_kernels.avss_dist_ref(q, s_values, lut)
+                if valid is not None:
+                    dist = dist + jnp.where(
+                        valid, 0.0, kernel_ops.SHORTLIST_MASK_PENALTY)[None]
+                neg, idx = jax.lax.top_k(-dist, k)
+                return -neg, idx
+
+            return map_query_chunks(
+                block, shortlist_chunk(q_values.shape[0], s_values.shape[0]),
+                q_values)
 
     # -- two-phase search --------------------------------------------------
 
@@ -691,17 +649,12 @@ class RetrievalEngine:
                   k: int = 64, valid: jax.Array | None = None, *,
                   s_grid: jax.Array | None = None,
                   proj: jax.Array | None = None,
-                  packed: jax.Array | None = None,
-                  pack_bits: int | None = None,
-                  fused: bool | None = None,
                   noise_qidx: jax.Array | None = None
                   ) -> dict[str, jax.Array]:
         """Shortlist + exact noisy rescore (beyond-paper TPU pipeline).
 
         s_grid / proj: optional write-time layouts (MemoryStore fields);
         omitted -> recomputed here, read-time, with identical results.
-        fused: phase-1 route (see `shortlist`); None applies the dispatch
-        rule.
         noise_qidx: optional (B,) per-query noise coordinates for the
         rescore (see `full`); default arange(B).
         Returns {votes (B, k), dist (B, k) ideal shortlist distances
@@ -712,8 +665,7 @@ class RetrievalEngine:
         from repro.kernels import ops as kernel_ops
         cfg = self.cfg
         dist, idx = self.shortlist(q_values, s_values, k, valid=valid,
-                                   proj=proj, packed=packed,
-                                   pack_bits=pack_bits, fused=fused)
+                                   proj=proj)
         with jax.named_scope(hc.RESCORE_SCOPE):
             q_grid, s_grid, weights, thresholds = self._grids(
                 q_values, s_values, s_grid)
@@ -732,8 +684,7 @@ class RetrievalEngine:
         """Two-phase search with the store row-sharded over mesh `axes`.
 
         Bit-identical to `two_phase` on a single device: each shard
-        shortlists its rows (dense XLA dot + top_k, or the fused Pallas
-        kernel where `sharded._use_fused` says so), rescores its local
+        shortlists its rows (XLA's dot + top_k), rescores its local
         candidates with GLOBAL support indices feeding the noise counters,
         and the candidate sets are all-gathered and merged by (distance,
         global index). See repro/engine/sharded.py for the exactness
@@ -741,6 +692,4 @@ class RetrievalEngine:
         """
         from repro.engine import sharded
         return sharded.sharded_two_phase_search(
-            q_values, s_values, self.cfg, mesh, axes=axes, k=k, valid=valid,
-            backend=self.resolved_backend,
-            fused_min_rows=self.fused_min_rows)
+            q_values, s_values, self.cfg, mesh, axes=axes, k=k, valid=valid)

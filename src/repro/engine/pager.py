@@ -60,7 +60,7 @@ from repro.engine.engine import RetrievalEngine
 from repro.engine.store import MemoryStore
 
 #: host-side block tables a slot holds (the leaves a routed search reads).
-_BLOCK_FIELDS = ("proj", "proj_packed", "s_grid", "labels")
+_BLOCK_FIELDS = ("proj", "s_grid", "labels")
 
 
 class ShardPager:
@@ -70,8 +70,8 @@ class ShardPager:
              `residency="host"` is the intended placement -- the pager
              reads the blocks as host numpy views and owns all device
              placement itself.
-    engine:  the RetrievalEngine whose routed-search core (and backend /
-             fused threshold) the paged search runs.
+    engine:  the RetrievalEngine whose routed-search core (and backend)
+             the paged search runs.
     slots:   device-resident shard slots (default min(S, 4)). A search
              needs the BATCH's visited-shard union (at most
              `B * nprobe`, typically far fewer for correlated queries)
@@ -103,7 +103,7 @@ class ShardPager:
         self._host = {
             f: np.asarray(getattr(store, f)).reshape(
                 (s, self.rows) + np.asarray(getattr(store, f)).shape[1:])
-            for f in _BLOCK_FIELDS if getattr(store, f) is not None}
+            for f in _BLOCK_FIELDS}
 
         # device slot tables (m, rows, ...) + the tiny resident sketch
         dev = jax.devices()[0]
@@ -119,16 +119,13 @@ class ShardPager:
         enc = engine.cfg.enc
         self._route = jax.jit(lambda q, su, c: router_lib.route_scores(
             q, su, c, enc))
-        pack_bits = store.pack_bits
 
         @partial(jax.jit, static_argnames=("req",))
-        def _jsearch(proj_t: jax.Array, packed_t: jax.Array | None,
-                     sgrid_t: jax.Array, labels_t: jax.Array,
-                     shard_of: jax.Array, q: jax.Array, slot_ids: jax.Array,
-                     req: SearchRequest) -> SearchResult:
+        def _jsearch(proj_t: jax.Array, sgrid_t: jax.Array,
+                     labels_t: jax.Array, shard_of: jax.Array, q: jax.Array,
+                     slot_ids: jax.Array, req: SearchRequest) -> SearchResult:
             return engine._routed_block_search(
-                q, slot_ids, shard_of, proj_t, packed_t, sgrid_t,
-                labels_t, req, pack_bits)
+                q, slot_ids, shard_of, proj_t, sgrid_t, labels_t, req)
 
         self._jsearch = _jsearch
         # slot is STATIC (at most `slots` variants) so installing pages no
@@ -221,10 +218,8 @@ class ShardPager:
             dev)
         shard_of = jax.device_put(self._shard_of(), dev)
         res = self._jsearch(
-            self._tables["proj"],
-            self._tables.get("proj_packed"),
-            self._tables["s_grid"], self._tables["labels"],
-            shard_of, q, slot_ids, request)
+            self._tables["proj"], self._tables["s_grid"],
+            self._tables["labels"], shard_of, q, slot_ids, request)
         if self.prefetch and p < self.n_shards and len(self._staged) < 2:
             # double-buffer: while the search above executes, stage the
             # (p+1)-th-best shard by aggregate score rank across the batch

@@ -23,7 +23,7 @@ Design constraints, inherited from the serving contract:
   out-rank a shard with real rows.
 * The sketch matmul runs under ``jax.named_scope("router_sketch")`` --
   the contract registry asserts the tag appears iff routing is engaged
-  (``nprobe < n_shards``), mirroring the fused-kernel tag.
+  (``nprobe < n_shards``).
 
 >>> import jax.numpy as jnp
 >>> vals = jnp.array([[0, 9], [2, 9], [8, 1], [8, 3]])
@@ -45,7 +45,6 @@ import jax.numpy as jnp
 
 from repro.core.encodings import Encoding
 from repro.kernels import ops as kernel_ops
-from repro.kernels.shortlist import SHORTLIST_MASK_PENALTY
 
 #: class buckets per shard sketch (label % ROUTER_BUCKETS).  Small on
 #: purpose: the whole sketch is S * R * d int32, and routing cost is one
@@ -122,7 +121,7 @@ def route_scores(q_values: jax.Array, sketch_sums: jax.Array,
         q1h = kernel_ops.query_onehot(q_values, jnp.float32)
         dist = q1h @ proj.astype(jnp.float32).T            # (B, S*R)
         mask = jnp.where(sketch_counts > 0, 0.0,
-                         SHORTLIST_MASK_PENALTY).reshape(s * r)
+                         kernel_ops.SHORTLIST_MASK_PENALTY).reshape(s * r)
         return jnp.min(dist.reshape(-1, s, r) + mask.reshape(s, r)[None],
                        axis=-1)
 

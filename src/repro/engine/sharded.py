@@ -18,26 +18,21 @@ Two flavours:
                              path formerly inlined in core/memory.py).
 
 Both paths share ONE per-shard shortlist implementation with the unsharded
-engine (`_use_fused` + `_local_shortlist`): phase 1 is one MXU dot of the
-query one-hots against the shard's LUT projection, the mask penalty and
-XLA's exact lax.top_k, wherever the (B, N_loc) f32 distance block fits
-SHORTLIST_DENSE_MAX_BYTES. Only a block over that budget (or the 'fused'
-backend, or an explicit `fused_min_rows`) runs the fused Pallas shortlist
-kernel (kernels/shortlist.py, HBM O(B*k_loc + N_loc*4d)) inside the
-shard_map body -- masked rows (ragged pads, empty slots) are penalised
-NATIVELY in the kernel with the integer-exact SHORTLIST_MASK_PENALTY, and
-ragged (non-tile-aligned) local blocks are padded inside the kernel
-wrapper. Both routes are bit-identical.
+engine (`_local_shortlist`): phase 1 is one MXU dot of the query one-hots
+against the shard's LUT projection, the integer-exact
+SHORTLIST_MASK_PENALTY on masked rows (ragged pads, empty slots) and XLA's
+exact lax.top_k. Where the (B, N_loc) f32 distance block would exceed
+SHORTLIST_DENSE_MAX_BYTES, the same block runs over query chunks that fit
+it (`shortlist_chunk`), bit-identically.
 
 Exactness argument for the two-phase path (verified by
 tests/test_engine.py::test_sharded_two_phase_bit_identical):
 
 * Shortlist distances are integer-valued f32 (AVSS LUT entries are small
   integers, one-hot queries are 0/1, f32 accumulation is exact below 2**24),
-  so every shard computes the same exact distance a single device would --
-  fused or dense.
-* `jax.lax.top_k` ranks by (value, index), and the fused kernel reproduces
-  that order exactly (ties included): a support in the GLOBAL top-k is
+  so every shard computes the same exact distance a single device would.
+* `jax.lax.top_k` ranks by (value, index), ties included: a support in
+  the GLOBAL top-k is
   necessarily in its shard's LOCAL top-k under the same order, so no global
   candidate is lost by local pruning.
 * The all-gather stacks shards in mesh-axis-major order -- the same order a
@@ -50,7 +45,7 @@ tests/test_engine.py::test_sharded_two_phase_bit_identical):
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Any, Sequence
+from typing import TYPE_CHECKING, Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -81,72 +76,85 @@ def _gather_candidates(x: jax.Array, axes: Sequence[str]) -> jax.Array:
     return jnp.moveaxis(stacked, 0, 1).reshape(x.shape[0], -1)
 
 
-# Largest (B, N_loc) f32 distance block the dense phase-1 route may
-# materialise before lax.top_k. On a TPU v5e XLA's dense dot + exact top_k
-# beats the fused Pallas kernel by 11x at B=64 over 1,048,576 rows (9.0 ms
-# against 101.0 ms), so the dense route runs wherever its block fits: 1 GiB
-# is under 7% of a v5e's 15.75 GiB of HBM, room the store and the phase-2
-# operands leave over. A larger block (a big multi-tenant flush, a huge
-# shard) streams through the kernel, whose HBM stays O(B*k + N*4d).
+# Largest (B, N_loc) f32 distance block phase 1 materialises before
+# lax.top_k. On a TPU v5e XLA's dense dot + exact top_k ranks B=64 queries
+# over 1,572,864 rows in 11.5 ms (a 403 MB block); 1 GiB is under 7% of a
+# v5e's 15.75 GiB of HBM, room the store and the phase-2 operands leave
+# over. A larger block (a big multi-tenant flush, a huge shard) is ranked
+# in query chunks that each fit it (`shortlist_chunk`).
 SHORTLIST_DENSE_MAX_BYTES = 1 << 30
 
 
-def _use_fused(backend: str, batch: int, rows_loc: int,
-               fused_min_rows: int | None) -> bool:
-    """Shared phase-1 dispatch rule, applied to the shape of the block one
-    shortlist call ranks (per shard inside the shard_map bodies): the
-    fused Pallas kernel engages on the 'fused' backend, and on any other
-    kernel backend when the dense (batch, rows_loc) f32 distance block
-    would exceed SHORTLIST_DENSE_MAX_BYTES, or when an explicit
-    `fused_min_rows` is reached. 'ref' always keeps the dense route as the
-    readable reference."""
-    if backend == "fused":
-        return True
-    if backend == "ref":
-        return False
-    if fused_min_rows is not None and rows_loc >= fused_min_rows:
-        return True
-    return batch * rows_loc * 4 > SHORTLIST_DENSE_MAX_BYTES
+def shortlist_chunk(batch: int, rows: int) -> int:
+    """Queries per phase-1 block over `rows` rows: all `batch` of them while
+    their (batch, rows) f32 distance block fits SHORTLIST_DENSE_MAX_BYTES,
+    else the most whose block does. A shape rule of the call, read at
+    trace time; one query's block over the budget raises.
+
+    >>> shortlist_chunk(64, 1_572_864)          # the omniglot cell: one block
+    64
+    >>> shortlist_chunk(64, 1 << 24)            # 64 MiB a query: 16 a block
+    16
+    """
+    per_query = rows * 4
+    if per_query > SHORTLIST_DENSE_MAX_BYTES:
+        raise ValueError(
+            f"phase 1 over {rows} rows needs a {per_query}-byte f32 "
+            f"distance row per query, more than SHORTLIST_DENSE_MAX_BYTES "
+            f"({SHORTLIST_DENSE_MAX_BYTES}); shard the store so each shard "
+            f"holds fewer rows")
+    return min(batch, SHORTLIST_DENSE_MAX_BYTES // per_query)
+
+
+def map_query_chunks(fn: Callable[[jax.Array], Any], chunk: int,
+                     q: jax.Array) -> Any:
+    """`fn(q)` over the leading (query) axis of `q`, `chunk` queries at a
+    time: one call when `chunk` covers the batch, else a `jax.lax.map` over
+    the batch padded to a multiple of `chunk`, so one chunk's block is live
+    at a time. Each query's result depends on that query alone, so the
+    chunked results equal the unchunked ones bit for bit."""
+    b = q.shape[0]
+    if chunk >= b:
+        return fn(q)
+    n = -(-b // chunk)
+    q = jnp.pad(q, [(0, n * chunk - b)] + [(0, 0)] * (q.ndim - 1))
+    out = jax.lax.map(fn, q.reshape((n, chunk) + q.shape[1:]))
+    return jax.tree_util.tree_map(
+        lambda y: y.reshape((n * chunk,) + y.shape[2:])[:b], out)
 
 
 def _local_shortlist(q1h: jax.Array, proj_loc: jax.Array,
-                     valid_loc: jax.Array | None, k_loc: int, *,
-                     fused: bool, packed: jax.Array | None = None,
-                     pack_bits: int | None = None
+                     valid_loc: jax.Array | None, k_loc: int
                      ) -> tuple[jax.Array, jax.Array]:
-    """Block shortlist shared by every dispatch site (per shard inside the
+    """Block shortlist shared by every search site (per shard inside the
     shard_map bodies here, the unsharded engine, the routed blocks):
-    top-k_loc of the rows by exact integer LUT distance (+ native mask
-    penalty on rows `valid_loc` masks), fused or dense -- bit-identical
-    (the kernel reproduces lax.top_k's (distance, row) order). When the
-    store provides its bit-packed projection (`packed`/`pack_bits`), the
-    fused kernel streams that 4-8x smaller operand instead of proj_loc.
+    top-k_loc of the rows by exact integer LUT distance (+ the mask
+    penalty on rows `valid_loc` masks), in (distance, row) order, in query
+    chunks where the (B, N_loc) block is over the budget
+    (`shortlist_chunk`).
 
-    The dense route dots against proj_loc as stored, accumulating in f32:
-    the one-hot is 0/1 and every stored entry is a value of proj_loc's own
-    dtype, so each product is exact and so is any sum below 2**24. A bf16
-    projection therefore needs no per-call f32 copy; an f32 one asks for
-    full precision, which the TPU's default one-pass bf16 would round."""
-    with jax.named_scope(hc.SHORTLIST_SCOPE):
-        if fused:
-            from repro.kernels import shortlist as shortlist_kernel
-            if packed is not None:
-                return shortlist_kernel.lut_shortlist_pallas(
-                    q1h, None, k_loc, valid=valid_loc, packed=packed,
-                    pack_bits=pack_bits)
-            return shortlist_kernel.lut_shortlist_pallas(
-                q1h, proj_loc, k_loc, valid=valid_loc)
-        from repro.kernels import ops as kernel_ops
-        exact = (None if proj_loc.dtype == jnp.bfloat16
-                 else jax.lax.Precision.HIGHEST)
-        dist = jax.lax.dot_general(                        # (B, N_loc)
-            q1h.astype(proj_loc.dtype), proj_loc, (((1,), (1,)), ((), ())),
+    It dots against proj_loc as stored, accumulating in f32: the one-hot
+    is 0/1 and every stored entry is a value of proj_loc's own dtype, so
+    each product is exact and so is any sum below 2**24. A bf16 projection
+    therefore needs no per-call f32 copy; an f32 one asks for full
+    precision, which the TPU's default one-pass bf16 would round."""
+    from repro.kernels import ops as kernel_ops
+    exact = (None if proj_loc.dtype == jnp.bfloat16
+             else jax.lax.Precision.HIGHEST)
+
+    def block(q: jax.Array) -> tuple[jax.Array, jax.Array]:
+        dist = jax.lax.dot_general(                        # (b, N_loc)
+            q.astype(proj_loc.dtype), proj_loc, (((1,), (1,)), ((), ())),
             precision=exact, preferred_element_type=jnp.float32)
         if valid_loc is not None:
             dist = dist + jnp.where(valid_loc, 0.0,
                                     kernel_ops.SHORTLIST_MASK_PENALTY)[None]
         neg, idx = jax.lax.top_k(-dist, k_loc)
         return -neg, idx
+
+    with jax.named_scope(hc.SHORTLIST_SCOPE):
+        return map_query_chunks(
+            block, shortlist_chunk(q1h.shape[0], proj_loc.shape[0]), q1h)
 
 
 def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
@@ -155,11 +163,7 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
                              k: int = 64, valid: jax.Array | None = None,
                              labels: jax.Array | None = None,
                              s_grid: jax.Array | None = None,
-                             proj: jax.Array | None = None,
-                             packed: jax.Array | None = None,
-                             pack_bits: int | None = None,
-                             backend: str = "ref",
-                             fused_min_rows: int | None = None
+                             proj: jax.Array | None = None
                              ) -> dict[str, jax.Array]:
     """Two-phase AVSS over a store row-sharded on `axes`.
 
@@ -176,15 +180,9 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
     MemoryStore.s_grid); omitted -> each shard lays out its rows here.
     proj: optional (N, 4d) write-time LUT projection (row-sharded,
     MemoryStore.proj); omitted -> each shard projects its rows here.
-    packed: optional bit-packed projection (row-sharded,
-    MemoryStore.proj_packed); the fused per-shard shortlist then streams
-    this 4-8x smaller operand instead of proj, bit-identically.
-    backend / fused_min_rows: per-shard shortlist dispatch (see
-    `_use_fused`); the default 'ref' keeps the dense local matmul.
     Returns {votes (B, k), dist (B, k), indices (B, k) global rows
     [, labels (B, k)], iterations} -- bit-identical to
-    RetrievalEngine.two_phase(q, s, k, valid) on a single device,
-    whichever shortlist path engages.
+    RetrievalEngine.two_phase(q, s, k, valid) on a single device.
     """
     from repro.core import avss as avss_lib
     from repro.kernels import ops as kernel_ops
@@ -199,8 +197,6 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
         f"(MemoryStore.shard pads ragged splits)")
     k = min(k, N)
     k_loc = min(k, N // n_shards)
-    fused = _use_fused(backend, q_values.shape[0], N // n_shards,
-                       fused_min_rows)
 
     with jax.named_scope(hc.SHORTLIST_SCOPE):
         q1h = kernel_ops.query_onehot(q_values, jnp.float32)   # (B, 4d)
@@ -228,17 +224,6 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
     if proj is not None:
         extras.append(proj)
         extra_specs.append(P(axes))
-    if packed is not None:
-        extras.append(packed)
-        extra_specs.append(P(axes))
-        if pack_bits is None:
-            # fallback for callers that packed from `proj` right here; the
-            # engine passes store.pack_bits (the authoritative pack-time
-            # width) so a bf16-vs-f32 proj dtype can never mis-unpack
-            pack_bits = kernel_ops.projection_pack_bits(
-                enc, proj.dtype if proj is not None else jnp.bfloat16)
-    else:
-        pack_bits = None
     ax = axes[0] if len(axes) == 1 else tuple(axes)
 
     def local(q1h_: jax.Array, q_grid_: jax.Array, s_loc: jax.Array,
@@ -248,18 +233,15 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
         labels_loc = rest_l.pop(0) if labels is not None else None
         s_grid_loc = rest_l.pop(0) if s_grid is not None else None
         proj_loc = rest_l.pop(0) if proj is not None else None
-        packed_loc = rest_l.pop(0) if packed is not None else None
-        # phase 1 on local rows: exact integer-valued distances, fused
-        # kernel or dense MXU matmul (same LUT projection as
-        # kernels/ops.support_projection, materialised at write time when
-        # the store provides `proj` / its bit-packed `packed` twin)
+        # phase 1 on local rows: exact integer-valued distances by one MXU
+        # dot (same LUT projection as kernels/ops.support_projection,
+        # materialised at write time when the store provides `proj`)
         with jax.named_scope(hc.SHORTLIST_SCOPE):
             offset = _shard_index(mesh, axes) * jnp.int32(s_loc.shape[0])
             if proj_loc is None:                       # (N_loc, 4d)
                 proj_loc = lut.T[s_loc].reshape(s_loc.shape[0], -1)
-            d_loc, idx_loc = _local_shortlist(
-                q1h_, proj_loc, valid_loc, k_loc, fused=fused,
-                packed=packed_loc, pack_bits=pack_bits)
+            d_loc, idx_loc = _local_shortlist(q1h_, proj_loc, valid_loc,
+                                              k_loc)
             gidx = idx_loc + offset
         # phase 2 on local candidates, GLOBAL indices for the noise counters
         with jax.named_scope(hc.RESCORE_SCOPE):
@@ -311,50 +293,23 @@ def sharded_two_phase_search(q_values: jax.Array, s_values: jax.Array,
 def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
                          labels: jax.Array, mesh: Mesh,
                          axes: Sequence[str] = ("data",),
-                         k: int = 16, backend: str = "ref",
-                         fused_min_rows: int | None = None,
-                         packed: jax.Array | None = None,
-                         pack_bits: int | None = None,
-                         enc: Any = None) -> dict[str, jax.Array]:
+                         k: int = 16) -> dict[str, jax.Array]:
     """Ideal-digital-distance block search (no rescore; cheap serving path).
 
     q_onehot: (B, 4d) replicated query one-hots; proj: (N, 4d) row-sharded
     LUT projections; labels: (N,) row-sharded (< 0 marks empty slots --
     their distance carries the integer-exact SHORTLIST_MASK_PENALTY, the
     same masking the two-phase and unsharded ideal paths use, so results
-    stay bit-identical to the single-device fused/dense ideal search even
-    when masked rows reach the top-k).
-    backend / fused_min_rows: per-shard shortlist dispatch (see
-    `_use_fused`); a shard whose dense (B, N_loc) block is over the budget
-    streams through the fused Pallas shortlist kernel instead.
-    packed / enc: optional bit-packed projection (row-sharded,
-    MemoryStore.proj_packed) and its encoding; the fused path then streams
-    the 4-8x smaller operand, bit-identically.
+    stay bit-identical to the single-device ideal search even when masked
+    rows reach the top-k).
     Collective volume is O(B * k * shards), independent of capacity.
     Returns {dist, votes=-dist, labels, indices} each (B, k').
     """
 
-    rows_loc = proj.shape[0] // int(np.prod([mesh.shape[a] for a in axes]))
-    fused = _use_fused(backend, q_onehot.shape[0], rows_loc, fused_min_rows)
-    extras: list[jax.Array] = []
-    extra_specs: list[P] = []
-    if packed is not None and (pack_bits is not None or enc is not None):
-        extras.append(packed)
-        extra_specs.append(P(axes))
-        if pack_bits is None:            # fallback: derive from enc + proj
-            from repro.kernels import ops as kernel_ops
-            pack_bits = kernel_ops.projection_pack_bits(enc, proj.dtype)
-    else:
-        pack_bits = None
-
-    def local(qr: jax.Array, proj_loc: jax.Array, labels_loc: jax.Array,
-              *rest: jax.Array
+    def local(qr: jax.Array, proj_loc: jax.Array, labels_loc: jax.Array
               ) -> tuple[jax.Array, jax.Array, jax.Array]:
-        packed_loc = rest[0] if rest else None
         kk = min(k, proj_loc.shape[0])
-        d_loc, idx = _local_shortlist(qr, proj_loc, labels_loc >= 0, kk,
-                                      fused=fused, packed=packed_loc,
-                                      pack_bits=pack_bits)
+        d_loc, idx = _local_shortlist(qr, proj_loc, labels_loc >= 0, kk)
         with jax.named_scope(hc.MERGE_SCOPE):
             offset = _shard_index(mesh, axes) * jnp.int32(proj_loc.shape[0])
             d_all = _gather_candidates(d_loc, axes)
@@ -367,9 +322,9 @@ def sharded_ideal_search(q_onehot: jax.Array, proj: jax.Array,
     with jax.named_scope(hc.SHORTLIST_SCOPE):     # as in the two-phase
         dist, labels_out, indices = jax.shard_map(
             local, mesh=mesh,
-            in_specs=(P(), P(axes), P(axes), *extra_specs),
+            in_specs=(P(), P(axes), P(axes)),
             out_specs=(P(), P(), P()),
             check_vma=False,
-        )(q_onehot, proj, labels, *extras)
+        )(q_onehot, proj, labels)
     return {"dist": dist, "labels": labels_out, "votes": -dist,
             "indices": indices}

@@ -7,10 +7,6 @@ strings and searched many times: MTMC encoding happens at write time (Sec.
 
   values   (N, d)  int32   quantized support values (ring buffer)
   proj     (N, 4d) bf16    AVSS LUT projection (phase-1 MXU shortlists)
-  proj_packed (N, ceil(4d/wpi)) int32  the same projection bit-packed
-                           (kernels/ops.pack_projection, wpi = 32/bits LUT
-                           words per int32) -- the fused shortlist streams
-                           this 4-8x smaller operand instead of `proj`
   s_grid   (N, seg, L, sl) int8  string-grid layout (full search / rescore)
   labels   (N,)    int32   class / token labels; -1 marks an empty slot
                            (never written, or a ragged-shard pad row)
@@ -40,7 +36,7 @@ Writes on a MULTI-shard store stay shard-LOCAL (the paper's economics:
 NAND programming is the cheap in-place operation). `write` dispatches to a
 shard_map write-through in which each shard computes which slice of the
 (replicated) incoming batch lands in its own ring segment and programs
-values/proj/proj_packed/s_grid/labels in place -- the compiled HLO
+values/proj/s_grid/labels in place -- the compiled HLO
 contains no cross-device collectives and no scatter (tests/test_store.py),
 and the result is bit-identical to the unsharded scatter path, including
 ragged pads and ring wraparound across shard boundaries. With 1 shard (or
@@ -76,8 +72,8 @@ from repro.kernels import ops as kernel_ops
 
 #: array leaves of the store pytree (register_dataclass data_fields; also
 #: the per-leaf set `shard(residency="host")` moves to host memory).
-_DATA_FIELDS = ["values", "proj", "proj_packed", "s_grid", "labels",
-                "size", "lo", "hi", "sketch_sums", "sketch_counts"]
+_DATA_FIELDS = ["values", "proj", "s_grid", "labels", "size", "lo", "hi",
+                "sketch_sums", "sketch_counts"]
 
 
 def _host_device() -> jax.Device:
@@ -132,7 +128,6 @@ class MemoryStore:
 
     values: jax.Array
     proj: jax.Array
-    proj_packed: jax.Array
     s_grid: jax.Array
     labels: jax.Array
     size: jax.Array
@@ -169,7 +164,6 @@ class MemoryStore:
         return cls(
             values=zeros,
             proj=fill(proj),
-            proj_packed=fill(kernel_ops.pack_projection(proj, enc)),
             s_grid=fill(_layout(row, cfg)),
             labels=labels,
             size=jnp.zeros((), jnp.int32),
@@ -192,12 +186,10 @@ class MemoryStore:
         cfg = MemoryConfig(capacity=n, dim=d, search=search_cfg)
         v = values.astype(jnp.int32)
         lab = labels.astype(jnp.int32)
-        proj = kernel_ops.support_projection(v, cfg.search.enc)
         sk_sums, sk_counts = router_lib.build_sketch(v, lab, 1)
         return cls(
             values=v,
-            proj=proj,
-            proj_packed=kernel_ops.pack_projection(proj, cfg.search.enc),
+            proj=kernel_ops.support_projection(v, cfg.search.enc),
             s_grid=_layout(v, cfg),
             labels=lab,
             size=jnp.asarray(n, jnp.int32),
@@ -233,14 +225,12 @@ class MemoryStore:
                    cfg: MemoryConfig) -> "MemoryStore":
         """Adopt a legacy `core.memory` state dict (pre-redesign contract).
         Dicts from old checkpoints may lack the write-time `s_grid`; it is
-        derived from `values` (deterministic, so results stay identical)."""
+        derived from `values` (deterministic, so results stay identical).
+        Any other key (the bit-packed projection older stores saved) is
+        ignored."""
         s_grid = state.get("s_grid")
         if s_grid is None:
             s_grid = _layout(state["values"], cfg)
-        packed = state.get("proj_packed")
-        if packed is None:
-            packed = kernel_ops.pack_projection(state["proj"],
-                                                cfg.search.enc)
         # legacy dicts carry no calibration flag; adopt their lo/hi as-is
         # (the pre-redesign API managed calibration itself) so the shims in
         # core/memory.py stay bit-identical. The router sketch is a
@@ -249,7 +239,6 @@ class MemoryStore:
         sk_sums, sk_counts = router_lib.build_sketch(
             state["values"], state["labels"], 1)
         return cls(values=state["values"], proj=state["proj"],
-                   proj_packed=packed,
                    s_grid=s_grid, labels=state["labels"],
                    size=state["size"], lo=state["lo"], hi=state["hi"],
                    sketch_sums=sk_sums, sketch_counts=sk_counts,
@@ -306,17 +295,6 @@ class MemoryStore:
         if self.mesh is not None:
             return int(np.prod([self.mesh.shape[a] for a in self.axes]))
         return int(self.sketch_sums.shape[0])
-
-    @property
-    def pack_bits(self) -> int:
-        """Field width (4/8/16/32) of `proj_packed`, fixed at PACK time by
-        the encoding and the stored `proj` dtype. This is the one
-        authoritative spelling: consumers must unpack with the width the
-        operand was packed with, never re-derive it from a default dtype
-        (a bf16-vs-f32 projection changes the width for large-LUT
-        encodings -- see ops.projection_pack_bits)."""
-        return kernel_ops.projection_pack_bits(self.cfg.search.enc,
-                                               self.proj.dtype)
 
     @property
     def valid(self) -> jax.Array:
@@ -393,8 +371,7 @@ class MemoryStore:
 
     def _program(self, idx: jax.Array, v: jax.Array, labels: jax.Array,
                  n: int) -> "MemoryStore":
-        enc = self.cfg.search.enc
-        proj = kernel_ops.support_projection(v, enc)
+        proj = kernel_ops.support_projection(v, self.cfg.search.enc)
         lab = labels.astype(jnp.int32)
         new_values = self.values.at[idx].set(v)
         new_labels = self.labels.at[idx].set(lab)
@@ -419,8 +396,6 @@ class MemoryStore:
             self,
             values=new_values,
             proj=self.proj.at[idx].set(proj),
-            proj_packed=self.proj_packed.at[idx].set(
-                kernel_ops.pack_projection(proj, enc)),
             s_grid=self.s_grid.at[idx].set(_layout(v, self.cfg)),
             labels=new_labels,
             sketch_sums=sk_sums,
@@ -450,14 +425,13 @@ class MemoryStore:
         enc = self.cfg.search.enc
         n_buckets = self.sketch_sums.shape[1]
         start = (self.size % ring).astype(jnp.int32)
-        proj_b = kernel_ops.support_projection(v, enc)
-        batch = (v, proj_b, kernel_ops.pack_projection(proj_b, enc),
+        batch = (v, kernel_ops.support_projection(v, enc),
                  _layout(v, self.cfg), labels.astype(jnp.int32))
 
         def local(start_: jax.Array, v_: jax.Array, proj_: jax.Array,
-                  packed_: jax.Array, grid_: jax.Array, labels_: jax.Array,
+                  grid_: jax.Array, labels_: jax.Array,
                   values_loc: jax.Array, proj_loc: jax.Array,
-                  packed_loc: jax.Array, grid_loc: jax.Array,
+                  grid_loc: jax.Array,
                   labels_loc: jax.Array) -> tuple[jax.Array, ...]:
             rows = values_loc.shape[0]
             g = _shard_index(mesh, axes) * jnp.int32(rows) \
@@ -482,21 +456,18 @@ class MemoryStore:
             sk_sums, sk_counts = router_lib.bucket_sums(new_vals, new_labs,
                                                         n_buckets)
             return (new_vals, sel(proj_, proj_loc),
-                    sel(packed_, packed_loc),
                     sel(grid_, grid_loc), new_labs,
                     sk_sums[None], sk_counts[None])
 
         out = jax.shard_map(
             local, mesh=mesh,
-            in_specs=(P(),) * 6 + (P(axes),) * 5,
-            out_specs=(P(axes),) * 7,
+            in_specs=(P(),) * 5 + (P(axes),) * 4,
+            out_specs=(P(axes),) * 6,
             check_vma=False,
-        )(start, *batch, self.values, self.proj, self.proj_packed,
-          self.s_grid, self.labels)
+        )(start, *batch, self.values, self.proj, self.s_grid, self.labels)
         return dataclasses.replace(
-            self, values=out[0], proj=out[1], proj_packed=out[2],
-            s_grid=out[3], labels=out[4],
-            sketch_sums=out[5], sketch_counts=out[6], size=self.size + n)
+            self, values=out[0], proj=out[1], s_grid=out[2], labels=out[3],
+            sketch_sums=out[4], sketch_counts=out[5], size=self.size + n)
 
     def quantize_queries(self, queries: jax.Array) -> jax.Array:
         """Float embeddings -> quantized query words ([0, 4) for AVSS,
@@ -595,7 +566,6 @@ class MemoryStore:
             store,
             values=jax.device_put(store.values, row),
             proj=jax.device_put(store.proj, row),
-            proj_packed=jax.device_put(store.proj_packed, row),
             s_grid=jax.device_put(store.s_grid, row),
             labels=jax.device_put(store.labels, row),
             size=jax.device_put(store.size, rep),
@@ -617,7 +587,6 @@ class MemoryStore:
         if self.capacity != n:
             base = dataclasses.replace(
                 self, values=self.values[:n], proj=self.proj[:n],
-                proj_packed=self.proj_packed[:n],
                 s_grid=self.s_grid[:n], labels=self.labels[:n])
         if base.sketch_sums.shape[0] != 1 or base.residency != "device":
             sk_sums, sk_counts = router_lib.build_sketch(
@@ -630,16 +599,13 @@ class MemoryStore:
     def _pad_rows(self, pad: int) -> "MemoryStore":
         if pad == 0:
             return self
-        enc = self.cfg.search.enc
         zeros = jnp.zeros((pad, self.dim), jnp.int32)
-        proj_pad = kernel_ops.support_projection(zeros, enc)
         cat = lambda a, b: jnp.concatenate([a, b], axis=0)
         return dataclasses.replace(
             self,
             values=cat(self.values, zeros),
-            proj=cat(self.proj, proj_pad),
-            proj_packed=cat(self.proj_packed,
-                            kernel_ops.pack_projection(proj_pad, enc)),
+            proj=cat(self.proj, kernel_ops.support_projection(
+                zeros, self.cfg.search.enc)),
             s_grid=cat(self.s_grid, _layout(zeros, self.cfg)),
             labels=cat(self.labels, jnp.full((pad,), -1, jnp.int32)),
         )

@@ -16,11 +16,11 @@ Stacking rules (enforced by `stack`):
   consistent write-time layouts, masked by the integer-exact
   SHORTLIST_MASK_PENALTY, so pad rows rank after every valid row and
   bit-parity with the solo per-tenant search survives padding;
-* per-tenant state that searches need under jit (values / proj /
-  proj_packed / s_grid / labels / size / lo / hi / the router
-  sketch_sums / sketch_counts) becomes batched data leaves; per-tenant static metadata (each store's MemoryConfig and
-  calibration flag) rides along as aux data, so `tenant(i)` round-trips
-  the EXACT original store.
+* per-tenant state that searches need under jit (values / proj / s_grid /
+  labels / size / lo / hi / the router sketch_sums / sketch_counts)
+  becomes batched data leaves; per-tenant static metadata (each store's
+  MemoryConfig and calibration flag) rides along as aux data, so
+  `tenant(i)` round-trips the EXACT original store.
 
 Lifecycle mirrors the solo store: `stack(stores)` -> serve via
 `engine.search_tenants` -> `write_at(tenant_id, vectors, labels)` per-
@@ -64,21 +64,20 @@ def tenant_query_rank(tenant_ids: jax.Array) -> jax.Array:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["values", "proj", "proj_packed", "s_grid", "labels",
-                      "size", "lo", "hi", "sketch_sums", "sketch_counts"],
+         data_fields=["values", "proj", "s_grid", "labels", "size", "lo",
+                      "hi", "sketch_sums", "sketch_counts"],
          meta_fields=["cfgs", "calibrated"])
 @dataclasses.dataclass(frozen=True)
 class TenantStore:
     """N per-tenant MemoryStores as ONE batched pytree (module docstring).
 
     Data leaves carry a leading tenant axis over the solo store's layout:
-    values (T, Np, d), proj (T, Np, 4d), proj_packed (T, Np, w),
-    s_grid (T, Np, seg, L, sl), labels (T, Np), size/lo/hi (T,),
-    sketch_sums (T, 1, R, d) / sketch_counts (T, 1, R) (each tenant's
-    unpartitioned router sketch, kept write-consistent by `write_at`) --
-    with Np the stack-wide padded capacity. `cfgs` / `calibrated` keep each
-    tenant's ORIGINAL static metadata so `tenant(i)` is an exact inverse
-    of `stack`.
+    values (T, Np, d), proj (T, Np, 4d), s_grid (T, Np, seg, L, sl),
+    labels (T, Np), size/lo/hi (T,), sketch_sums (T, 1, R, d) /
+    sketch_counts (T, 1, R) (each tenant's unpartitioned router sketch,
+    kept write-consistent by `write_at`) -- with Np the stack-wide padded
+    capacity. `cfgs` / `calibrated` keep each tenant's ORIGINAL static
+    metadata so `tenant(i)` is an exact inverse of `stack`.
 
     >>> import jax.numpy as jnp
     >>> from repro.core.avss import SearchConfig
@@ -98,7 +97,6 @@ class TenantStore:
 
     values: jax.Array
     proj: jax.Array
-    proj_packed: jax.Array
     s_grid: jax.Array
     labels: jax.Array
     size: jax.Array
@@ -139,7 +137,7 @@ class TenantStore:
                   for s in stores]
         stk = lambda leaf: jnp.stack([getattr(s, leaf) for s in padded])
         return cls(values=stk("values"), proj=stk("proj"),
-                   proj_packed=stk("proj_packed"), s_grid=stk("s_grid"),
+                   s_grid=stk("s_grid"),
                    labels=stk("labels"), size=stk("size"), lo=stk("lo"),
                    hi=stk("hi"), sketch_sums=stk("sketch_sums"),
                    sketch_counts=stk("sketch_counts"),
@@ -178,7 +176,6 @@ class TenantStore:
         cap = self.cfgs[i].capacity
         return MemoryStore(
             values=self.values[i, :cap], proj=self.proj[i, :cap],
-            proj_packed=self.proj_packed[i, :cap],
             s_grid=self.s_grid[i, :cap], labels=self.labels[i, :cap],
             size=self.size[i], lo=self.lo[i], hi=self.hi[i],
             sketch_sums=self.sketch_sums[i],
@@ -194,8 +191,6 @@ class TenantStore:
         take = lambda a: a[tenant_ids]
         return MemoryStore(
             values=take(self.values), proj=take(self.proj),
-            proj_packed=(None if self.proj_packed is None
-                         else take(self.proj_packed)),
             s_grid=take(self.s_grid), labels=take(self.labels),
             size=take(self.size), lo=take(self.lo), hi=take(self.hi),
             sketch_sums=take(self.sketch_sums),
@@ -233,8 +228,8 @@ class TenantStore:
         The solo `MemoryStore.write` contract per tenant: quantize against
         the tenant's calibrated range, scatter into its ring at
         `(size % capacity + arange(n)) % capacity` (the LOGICAL capacity,
-        so pad rows are never written), materialise proj/proj_packed/
-        s_grid write-time. Every leaf keeps its shape, so a compiled
+        so pad rows are never written), materialise proj/s_grid
+        write-time. Every leaf keeps its shape, so a compiled
         `search_tenants` program is NEVER retraced by a write --
         `tenant(t)` afterwards equals `stores[t].write(vectors, labels)`
         bit-for-bit. `tenant_id` may be a traced array (one jitted write
@@ -289,8 +284,6 @@ class TenantStore:
             self,
             values=self.values.at[t, idx].set(v),
             proj=self.proj.at[t, idx].set(proj.astype(self.proj.dtype)),
-            proj_packed=self.proj_packed.at[t, idx].set(
-                kernel_ops.pack_projection(proj, enc)),
             s_grid=self.s_grid.at[t, idx].set(_layout(v, self.cfgs[0])),
             labels=self.labels.at[t, idx].set(lab),
             sketch_sums=self.sketch_sums.at[t, 0].add(ds_new - ds_old),

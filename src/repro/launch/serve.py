@@ -220,7 +220,6 @@ def build_datastore(mem_cfg, keys, labels,
 def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
           retrieval: bool = False, retrieval_mode: str = "two-phase",
           retrieval_backend: str = "auto", retrieval_k: int = 32,
-          retrieval_fused_min_rows: int | None = None,
           retrieval_shards: int | None = None,
           retrieval_nprobe: int | None = None) -> dict:
     """Prefill `batch` seeded prompts of `prompt_len` tokens into the decode
@@ -256,10 +255,7 @@ def serve(arch: str, smoke: bool, batch: int, steps: int, prompt_len: int,
             # decode loop routes through the per-shard sketch
             # (repro/engine/router.py) instead of searching every shard
             store = store.shard(n_shards=retrieval_shards)
-        eng_kw = {} if retrieval_fused_min_rows is None else \
-            {"fused_min_rows": retrieval_fused_min_rows}
-        engine = RetrievalEngine(mem_cfg.search, backend=retrieval_backend,
-                                 **eng_kw)
+        engine = RetrievalEngine(mem_cfg.search, backend=retrieval_backend)
         mode = "ideal" if retrieval_mode == "ideal" else "two_phase"
         step = steps_lib.make_serve_step_with_mcam(
             cfg, rules, mem_cfg, engine=engine, k=retrieval_k, mode=mode,
@@ -325,14 +321,8 @@ def main(argv=None):
                          "rescore; ideal: engine top-k by exact digital "
                          "distance only")
     ap.add_argument("--retrieval-backend", default="auto",
-                    choices=["auto", "ref", "pallas", "mxu", "fused"])
+                    choices=["auto", "ref", "pallas"])
     ap.add_argument("--retrieval-k", type=int, default=32)
-    ap.add_argument("--retrieval-fused-min-rows", type=int, default=None,
-                    help="row count from which shortlists run the fused "
-                         "kernel (default: only a dense block over "
-                         "engine.SHORTLIST_DENSE_MAX_BYTES does; applies "
-                         "per shard-local block on sharded stores) -- a "
-                         "perf knob, results are bit-identical either way")
     ap.add_argument("--retrieval-shards", type=int, default=None,
                     help="partition the serve store into this many logical "
                          "row shards (MemoryStore.shard(n_shards=...)); "
@@ -356,8 +346,7 @@ def main(argv=None):
         return
     serve(args.arch, args.smoke, args.batch, args.steps, args.prompt_len,
           args.retrieval, args.retrieval_mode, args.retrieval_backend,
-          args.retrieval_k, args.retrieval_fused_min_rows,
-          args.retrieval_shards, args.retrieval_nprobe)
+          args.retrieval_k, args.retrieval_shards, args.retrieval_nprobe)
 
 
 if __name__ == "__main__":

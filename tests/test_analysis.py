@@ -56,11 +56,22 @@ def test_checkers_catch_injected_violations():
     # scope checks read op_name path components, as compiled HLO spells
     # them; a function name that merely CONTAINS a tag is not a scope
     tagged = CLEAN_HLO + ("  f.2 = f32[5] fusion(x), metadata={op_name="
-                          "\"jit(search)/shortlist_fused/dot_general\"}\n")
-    assert hc.check_fused_tag(tagged, True) == []
-    assert hc.check_fused_tag(tagged, False)
-    assert hc.check_fused_tag(CLEAN_HLO, False) == []
-    assert hc.check_fused_tag(CLEAN_HLO, True)
+                          "\"jit(search)/router_sketch/dot_general\"}\n")
+    assert hc.check_router_tag(tagged, True) == []
+    assert hc.check_router_tag(tagged, False)
+    assert hc.check_router_tag(CLEAN_HLO, False) == []
+    assert hc.check_router_tag(CLEAN_HLO, True)
+
+    # phase 1 in query chunks is a while loop in the shortlist layer; a
+    # loop in another layer (the ref `full` search's lax.map) is not one
+    def entry(scope: str) -> str:
+        return ("ENTRY %main (p0: f32[5,16]) -> f32[5,16] {\n"
+                "  %w.1 = (s32[], f32[5,16]) while(%t), condition=%c, "
+                f'body=%b, metadata={{op_name="jit(search)/{scope}/while"}}'
+                "\n}\n")
+    assert hc.check_phase1_one_block(entry(hc.SHORTLIST_SCOPE))
+    assert hc.check_phase1_one_block(entry(hc.RESCORE_SCOPE)) == []
+    assert hc.check_phase1_one_block(CLEAN_HLO) == []
 
     layout = CLEAN_HLO + ("  l.1 = s8[4] copy(x), metadata={op_name="
                           "\"jit(f)/jvp(layout_support)/copy\"}\n")
@@ -176,7 +187,7 @@ def test_repo_tree_is_lint_clean():
 
 def test_registry_matrix_covers_every_route():
     cells = registry.build_cells()
-    assert len(cells) >= 30          # ~119 invariant rows under the CLI
+    assert len(cells) >= 28          # 95 invariant rows under the CLI
     for cell in cells:
         for inv in cell.invariants:
             assert inv in registry.INVARIANTS, inv
@@ -187,16 +198,11 @@ def test_registry_matrix_covers_every_route():
     modes = {c.config["mode"] for c in search}
     backends = {c.config["backend"] for c in search}
     assert modes == {"full", "two_phase", "ideal"}
-    assert backends == {"ref", "mxu", "fused"}
+    assert backends == set(registry.CELL_BACKENDS) == {"ref", "pallas"}
     assert {c.config["sharded"] for c in search} == {True, False}
-    assert {c.config["packed"] for c in search} == {True, False}
-    # both sides of the fused dispatch are forced somewhere in the matrix
-    fmrs = {c.config["fused_min_rows"] for c in search}
-    assert {registry.FMR_FORCE_FUSED, registry.FMR_FORCE_DENSE} <= fmrs
-    # routed cells (PR 10): both phase-1 dispositions engaged, both packed
-    # sides, plus the nprobe == n_shards control with the tag-absent check
-    assert {c.config["backend"] for c in routed} >= {"mxu", "fused"}
-    assert {c.config["packed"] for c in routed} == {True, False}
+    # routed cells on both backends, plus the nprobe == n_shards
+    # control with the tag-absent check
+    assert {c.config["backend"] for c in routed} == {"ref", "pallas"}
     assert any(c.config["nprobe"] == c.config["n_shards"] for c in routed)
     assert any(c.config["nprobe"] < c.config["n_shards"] for c in routed)
     for c in routed:
@@ -206,16 +212,19 @@ def test_registry_matrix_covers_every_route():
               if c.entry == "MemoryStore.write"}
     assert writes == {"unsharded", "one_shard", "multi_shard"}
     assert any(c.entry == "episode_votes" for c in cells)
-    # every fused-expected unsharded ideal cell carries the HBM bound
-    for c in search:
-        if (c.config["mode"] == "ideal" and not c.config["sharded"]
-                and registry._expect_fused(c.config["backend"], 72, "ideal",
-                                           c.config["fused_min_rows"])):
-            assert "hbm_buffer_bound" in c.invariants, c.config
+    # every shortlist-mode search cell, tenants included, ranks its
+    # (under-budget) batch in one phase-1 block
+    tenants = [c for c in cells if c.entry == "engine.search_tenants"
+               and "mode" in c.config]
+    assert {c.config["mode"] for c in tenants} == {"full", "two_phase",
+                                                   "ideal"}
+    for c in search + routed + tenants:
+        assert ("phase1_one_block" in c.invariants) == (
+            c.config["mode"] != "full"), c.config
 
 
 def test_registry_sharded_cells_skip_without_devices():
-    cell = registry._search_cell("ideal", "mxu", 1, True, True,
+    cell = registry._search_cell("ideal", "pallas", True,
                                  len(jax.devices()) + 1)
     assert cell.skip
     report = registry.run_cells([cell])
@@ -227,8 +236,7 @@ def test_registry_small_subset_passes():
     """A cheap unsharded slice of the matrix compiles and passes in-process
     (the full matrix runs via `python -m repro.analysis run` in CI)."""
     cells = [
-        registry._search_cell("two_phase", "fused", registry.FMR_FORCE_FUSED,
-                              True, False, 1),
+        registry._search_cell("two_phase", "pallas", False, 1),
         registry._write_cell("unsharded", 1),
         registry._layout_control_cell(),
     ]
@@ -242,15 +250,17 @@ def test_registry_small_subset_passes():
                 "matched"} <= set(row)
 
 
-def test_registry_detects_broken_invariant():
+def test_registry_detects_broken_invariant(monkeypatch):
     """A cell whose artifacts violate its invariant FAILS (the runner is
-    not a rubber stamp): feed the inverted expectation to a real cell."""
-    cell = registry._search_cell("two_phase", "fused",
-                                 registry.FMR_FORCE_FUSED, True, False, 1)
-    art = cell.build()
-    assert art["expect_fused"] is True
-    assert registry.INVARIANTS["fused_tag_iff_dispatch_rule"](
-        {"hlo": art["hlo"], "expect_fused": False})
+    not a rubber stamp): a real cell built with the phase-1 budget cut
+    below its (B, N) block ranks in query chunks, which the one-block
+    invariant must catch."""
+    from repro.engine import sharded
+    cell = registry._search_cell("two_phase", "pallas", False, 1)
+    assert registry.INVARIANTS["phase1_one_block"](cell.build()) == []
+    monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                        2 * 72 * 4)
+    assert registry.INVARIANTS["phase1_one_block"](cell.build())
 
 
 # ---------------------------------------------------------------------------

@@ -1,63 +1,18 @@
 """Benchmark harness contracts: the dry-run artifacts CI gates on.
 
-`benchmarks.autotune_shortlist --dry-run` is the fast-job parity +
-regression gate for the fused shortlist; downstream consumers (the CI
-badge, `--retrieval-fused-min-rows`, benchmarks/run.py) read its JSON, so
-the schema is pinned here. The multi-tenant budget test pins the
-serving-scale wall-clock contract: a 64-tenant coalesced search must
-stay inside a fixed CPU-interpret ceiling, which a per-tenant retrace
-(the failure mode the one-jit-entry invariant guards) blows by orders
-of magnitude.
+`benchmarks.bench_router --dry-run` is the fast-job routing gate. The
+multi-tenant budget test pins the serving-scale wall-clock contract: a
+64-tenant coalesced search must stay inside a fixed CPU-interpret
+ceiling, which a per-tenant retrace (the failure mode the one-jit-entry
+invariant guards) blows by orders of magnitude.
 """
 
-import json
 import os
 import subprocess
 import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_autotune_shortlist_dry_run_schema(tmp_path):
-    out = tmp_path / "autotune_shortlist.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.autotune_shortlist",
-         "--dry-run", "--out", str(out)],
-        capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    doc = json.loads(out.read_text())
-
-    assert doc["generated_by"] == "benchmarks.autotune_shortlist --dry-run"
-    assert doc["backend"] in ("cpu", "tpu", "gpu")
-    assert doc["measurement"] in ("pallas-interpret", "compiled")
-    swept_ns = doc["params"]["ns"]
-    assert swept_ns, "dry sweep must cover at least one support count"
-
-    # fused_min_rows: the measured crossover -- a swept N, or None when
-    # fused never beat dense (both are valid outcomes; absence is not)
-    assert "fused_min_rows" in doc
-    fmr = doc["fused_min_rows"]
-    assert fmr is None or fmr in swept_ns, fmr
-
-    # skipped_configs: the static VMEM gate's rejections (analysis/vmem.py)
-    # -- present (possibly empty), never leaking into the timed rows
-    assert isinstance(doc["skipped_configs"], list)
-    assert doc["skipped_configs"] == [], \
-        "dry-sweep tiles fit VMEM comfortably; a rejection is a model bug"
-
-    # rows: one dense row per N plus >= 1 fused config row, each timed
-    rows = doc["rows"]
-    for n in swept_ns:
-        mine = [r for r in rows if r["n"] == n]
-        configs = {r["config"] for r in mine}
-        assert "dense" in configs and "default" in configs, configs
-        for r in mine:
-            assert r["us"] > 0, r
-            if r["config"] != "dense":
-                assert r["speedup_vs_dense"] > 0, r
 
 
 def test_tenant_batch_under_wall_clock_ceiling():

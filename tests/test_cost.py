@@ -157,7 +157,7 @@ def test_resource_report_cheap_cells():
         registry._write_cell("unsharded", 1),
         # a cell needing more devices than available -> a skip row, not
         # a hole in the report
-        registry._search_cell("ideal", "mxu", 1, True, True,
+        registry._search_cell("ideal", "pallas", True,
                               len(jax.devices()) + 1),
     ]
     report = cost.resource_report(cells)

@@ -3,11 +3,11 @@
 Two checks keep the new docs surface from rotting:
 
 * doctests on the public API (`engine/api.py`, `engine/store.py`,
-  `engine/engine.py`, `kernels/shortlist.py`, since ISSUE 5 the trainer
-  surface `core/hat.py` + `launch/steps.py`, since ISSUE 9 the
-  multi-tenant surface `engine/tenant.py`, and since ISSUE 10 the memory
-  hierarchy `engine/router.py` + `engine/pager.py`) -- the same modules
-  CI also runs through `pytest --doctest-modules`;
+  `engine/engine.py`, the phase-1 chunk rule in `engine/sharded.py`, the
+  trainer surface `core/hat.py` + `launch/steps.py`, the multi-tenant
+  surface `engine/tenant.py`, and the memory hierarchy `engine/router.py`
+  + `engine/pager.py`) -- the same modules CI also runs through
+  `pytest --doctest-modules`;
 * extract-and-run over every ```python block in README.md and docs/*.md
   (blocks in one file share a namespace, so a later block may build on an
   earlier one; shell examples use ```bash fences and are not executed).
@@ -24,7 +24,7 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 PUBLIC_MODULES = ("repro.engine.api", "repro.engine.store",
                   "repro.engine.engine", "repro.engine.tenant",
                   "repro.engine.router", "repro.engine.pager",
-                  "repro.kernels.shortlist", "repro.core.hat",
+                  "repro.engine.sharded", "repro.core.hat",
                   "repro.launch.steps")
 
 

@@ -2,7 +2,7 @@
 
 The baseline encodings (B4E, B4WE, SRE) were configurable but effectively
 untested beyond the raw encode/decode rules: this file runs each of them
-through `engine.search` across the ref/mxu/fused backends and a sharded
+through `engine.search` across the ref/pallas backends and a sharded
 store, asserting bit-parity, plus the paper-Table-1 `levels`/`words`
 accounting. (Separate from tests/test_encodings.py, which module-skips
 without hypothesis.)
@@ -57,14 +57,20 @@ def _store_and_queries(name, cl, n=48, d=16, b=5):
 @pytest.mark.slow  # kernel-backend compile matrix: full tier
 @pytest.mark.parametrize("name,cl", ENGINE_ENCODINGS)
 @pytest.mark.parametrize("mode", ["two_phase", "ideal"])
-def test_engine_backends_bit_identical_per_encoding(name, cl, mode):
-    """ref / mxu / fused backends agree bitwise for every encoding, in
-    both serving modes (votes, distances, candidate order, labels)."""
+def test_engine_backends_bit_identical_per_encoding(monkeypatch, name, cl,
+                                                   mode):
+    """ref / pallas backends agree bitwise for every encoding, in both
+    serving modes (votes, distances, candidate order, labels), with phase 1
+    in one block and in query chunks."""
+    from repro.engine import sharded
     cfg, store, qv = _store_and_queries(name, cl)
     req = SearchRequest(mode=mode, k=12)
     ref = RetrievalEngine(cfg, backend="ref").search(store, qv, req)
-    for backend in ("mxu", "fused"):
-        got = RetrievalEngine(cfg, backend=backend).search(store, qv, req)
+    for backend in ("pallas", "chunked"):
+        if backend == "chunked":
+            monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                                2 * store.capacity * 4)
+        got = RetrievalEngine(cfg, backend="pallas").search(store, qv, req)
         for field in ("votes", "dist", "indices", "labels"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(ref, field)),
@@ -72,17 +78,17 @@ def test_engine_backends_bit_identical_per_encoding(name, cl, mode):
                 err_msg=f"{name}/{mode}/{backend}/{field}")
 
 
-@pytest.mark.slow  # shard_map + fused-kernel compile matrix: full tier
+@pytest.mark.slow  # shard_map compile matrix: full tier
 @pytest.mark.parametrize("name,cl", ENGINE_ENCODINGS)
 def test_sharded_store_bit_identical_per_encoding(name, cl):
     """A sharded store (ragged split included: 48 rows never divide a
     5-shard... here 1-dev mesh keeps the fast tier fast; the multi-device
     subprocess sweep lives in tests/test_engine.py) serves every encoding
-    bit-identically to the unsharded search, ref and fused shortlists."""
+    bit-identically to the unsharded search, ref and pallas shortlists."""
     cfg, store, qv = _store_and_queries(name, cl)
     mesh = make_mesh((1,), ("data",))
     sharded = store.shard(mesh)
-    for backend in ("ref", "fused"):
+    for backend in ("ref", "pallas"):
         req = SearchRequest(mode="two_phase", k=12, backend=backend)
         want = RetrievalEngine(cfg, backend="ref").search(store, qv, req)
         got = RetrievalEngine(cfg).search(sharded, qv, req)

@@ -89,12 +89,11 @@ def test_full_search_pallas_matches_ref_svss():
 def test_two_phase_backends_bit_identical(quantized_store):
     cfg, qv, sv = quantized_store
     res = {b: RetrievalEngine(cfg, backend=b).two_phase(qv, sv, k=48)
-           for b in ("ref", "mxu", "fused")}
-    for b in ("mxu", "fused"):
-        for key in ("votes", "dist", "indices"):
-            np.testing.assert_array_equal(
-                np.asarray(res["ref"][key]), np.asarray(res[b][key]),
-                err_msg=f"{b}/{key}")
+           for b in ("ref", "pallas")}
+    for key in ("votes", "dist", "indices"):
+        np.testing.assert_array_equal(
+            np.asarray(res["ref"][key]), np.asarray(res["pallas"][key]),
+            err_msg=f"pallas/{key}")
 
 
 @pytest.mark.slow
@@ -110,10 +109,33 @@ def test_two_phase_votes_match_full_search(quantized_store):
                                       v_full[b, idx[b]])
 
 
-def test_fused_shortlist_matches_topk_tie_heavy():
-    """The fused Pallas shortlist reproduces lax.top_k EXACTLY, including
-    tie order, on a store built almost entirely of duplicated rows."""
+def _chunk_budget(monkeypatch, chunk: int, rows: int) -> None:
+    """Cut the phase-1 budget so `chunk` queries' (chunk, rows) f32 block
+    is the most that fits: the shortlist then ranks in query chunks."""
+    from repro.engine import sharded
+    monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                        chunk * rows * 4)
+
+
+def _tie_heavy_store():
+    """72 rows of 8 distinct ones (9x duplicates), every 4th row masked."""
+    from repro.engine import MemoryStore
+    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="ref")
+    base = jax.random.randint(jax.random.PRNGKey(0), (8, 20), 0,
+                              cfg.enc.levels)
+    sv = jnp.concatenate([base] * 9, axis=0)
+    labels = jnp.where(jnp.arange(72) % 4 == 0, -1,
+                       jnp.arange(72)).astype(jnp.int32)
+    qv = jax.random.randint(jax.random.PRNGKey(1), (5, 20), 0, 4)
+    return cfg, MemoryStore.from_quantized(sv, labels, cfg), qv
+
+
+def test_fused_shortlist_matches_topk_tie_heavy(monkeypatch):
+    """The phase-1 block ranked in query chunks (2, 2, 1 of 5 queries)
+    reproduces lax.top_k over the whole dense block EXACTLY, including tie
+    order, on a store built almost entirely of duplicated rows."""
     from repro.core.encodings import make_encoding
+    from repro.engine.sharded import _local_shortlist
     from repro.kernels import ops as kops
     enc = make_encoding("mtmc", 8)
     base = jax.random.randint(jax.random.PRNGKey(0), (8, 20), 0, enc.levels)
@@ -122,72 +144,72 @@ def test_fused_shortlist_matches_topk_tie_heavy():
     q1h = kops.query_onehot(qv, jnp.float32)
     sp = kops.support_projection(sv, enc, jnp.float32)
     neg, idx_ref = jax.lax.top_k(-(q1h @ sp.T), 30)
-    dist, idx = kops.lut_shortlist(qv, sv, enc, 30)
+    _chunk_budget(monkeypatch, 2, 72)
+    dist, idx = _local_shortlist(q1h, kops.support_projection(sv, enc),
+                                 None, 30)
     np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist))
     np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx))
 
 
-def test_shortlist_valid_mask_excludes_rows():
+def test_shortlist_valid_mask_excludes_rows(monkeypatch):
     """Masked rows rank after every valid row (integer-exact penalty), and
-    masking is bit-identical across shortlist backends."""
+    masking is bit-identical across shortlist backends, whether phase 1
+    ranks the queries in one block or in chunks."""
     cfg = SearchConfig("mtmc", cl=4, mode="avss", mcam=MCAMConfig(),
                        use_kernel="ref")
     sv = jax.random.randint(jax.random.PRNGKey(0), (40, 16), 0,
                             cfg.enc.levels)
     qv = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, 4)
     valid = (jnp.arange(40) % 3) != 0                      # 26 valid rows
-    res = {b: RetrievalEngine(cfg, backend=b).two_phase(qv, sv, k=20,
-                                                        valid=valid)
-           for b in ("ref", "mxu", "fused")}
-    for b in ("mxu", "fused"):
+    run = lambda b: RetrievalEngine(cfg, backend=b).two_phase(
+        qv, sv, k=20, valid=valid)
+    res = {b: run(b) for b in ("ref", "pallas")}
+    _chunk_budget(monkeypatch, 2, 40)
+    res.update({f"chunked-{b}": run(b) for b in ("ref", "pallas")})
+    for b, got in res.items():
         for key in ("votes", "dist", "indices"):
             np.testing.assert_array_equal(
-                np.asarray(res["ref"][key]), np.asarray(res[b][key]),
+                np.asarray(res["ref"][key]), np.asarray(got[key]),
                 err_msg=f"{b}/{key}")
     # k=20 <= 26 valid rows: no masked row may appear at all
     assert bool(jnp.all(valid[res["ref"]["indices"]]))
 
 
 # ---------------------------------------------------------------------------
-# Ideal mode: fused shortlist kernel == dense (B, N) matmul, bit for bit.
+# Ideal mode: query chunks == one dense (B, N) block, bit for bit.
 # ---------------------------------------------------------------------------
 
 
-def test_ideal_fused_matches_dense_tie_heavy():
-    """The large-N ideal serving path (fused shortlist kernel) is
-    bit-identical to the dense-matmul reference -- votes, dist, indices
-    AND labels -- on a tie-heavy store with masked rows inside the top-k
-    (masked rows carry the integer-exact penalty in both paths)."""
-    from repro.engine import MemoryStore, SearchRequest
-    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="ref")
-    base = jax.random.randint(jax.random.PRNGKey(0), (8, 20), 0,
-                              cfg.enc.levels)
-    sv = jnp.concatenate([base] * 9, axis=0)            # 72 rows, 9x dups
-    labels = jnp.where(jnp.arange(72) % 4 == 0, -1,
-                       jnp.arange(72)).astype(jnp.int32)  # 18 masked rows
-    store = MemoryStore.from_quantized(sv, labels, cfg)
-    qv = jax.random.randint(jax.random.PRNGKey(1), (5, 20), 0, 4)
+def test_ideal_fused_matches_dense_tie_heavy(monkeypatch):
+    """The ideal serving path ranked in query chunks is bit-identical to
+    one dense block -- votes, dist, indices AND labels -- on a tie-heavy
+    store with masked rows inside the top-k (masked rows carry the
+    integer-exact penalty in both)."""
+    from repro.engine import SearchRequest
+    cfg, store, qv = _tie_heavy_store()
     req = SearchRequest(mode="ideal", k=70)             # reaches masked rows
     dense = RetrievalEngine(cfg, backend="ref").search(store, qv, req)
-    fused = RetrievalEngine(cfg, backend="fused").search(store, qv, req)
-    for key in ("votes", "dist", "indices", "labels"):
-        np.testing.assert_array_equal(np.asarray(getattr(dense, key)),
-                                      np.asarray(getattr(fused, key)),
-                                      err_msg=key)
-    # masked candidates surface as -inf votes / -1 labels in both
+    _chunk_budget(monkeypatch, 2, 72)
+    for backend in ("ref", "pallas"):
+        chunked = RetrievalEngine(cfg, backend=backend).search(store, qv,
+                                                               req)
+        for key in ("votes", "dist", "indices", "labels"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(dense, key)),
+                np.asarray(getattr(chunked, key)),
+                err_msg=f"{backend}/{key}")
+    # masked candidates surface as -inf votes / -1 labels
     assert np.isneginf(np.asarray(dense.votes)).any()
 
 
 def test_ideal_routes_through_fused_kernel_at_large_n(monkeypatch):
     """Phase 1 follows the size of the dense block: under
-    SHORTLIST_DENSE_MAX_BYTES a kernel backend takes XLA's dense dot +
-    lax.top_k (no fused kernel call, no `shortlist_fused` tag in the
-    compiled search), over it (the budget forced small) the fused Pallas
-    kernel; the ref backend never fuses. Both routes are bit-identical to
-    ref on a ragged (1,100-row), tie-heavy store whose masked rows reach
-    the top-k, in `ideal` and in `two_phase`."""
+    SHORTLIST_DENSE_MAX_BYTES the compiled search ranks every query in one
+    block (no loop in `search_shortlist`), over it (the budget forced
+    small) in query chunks (a loop), on every backend. Both are
+    bit-identical to ref on a ragged (1,100-row), tie-heavy store whose
+    masked rows reach the top-k, in `ideal` and in `two_phase`."""
     from repro.engine import MemoryStore, SearchRequest, sharded
-    from repro.kernels import ops as kernel_ops
     cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="auto")
     N = 1100                    # past the old 1024-row threshold, ragged
     sv = jnp.tile(jax.random.randint(jax.random.PRNGKey(0), (100, 16), 0,
@@ -196,35 +218,28 @@ def test_ideal_routes_through_fused_kernel_at_large_n(monkeypatch):
                        jnp.arange(N) % 17).astype(jnp.int32)
     store = MemoryStore.from_quantized(sv, labels, cfg)
     qv = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, 4)
-    calls = []
-    orig = kernel_ops.lut_shortlist
-    monkeypatch.setattr(kernel_ops, "lut_shortlist",
-                        lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
     eng = RetrievalEngine(cfg)                  # auto -> pallas (kernels)
-    assert eng.resolved_backend != "ref" and eng.fused_min_rows is None
+    assert eng.resolved_backend != "ref"
     ref_eng = RetrievalEngine(cfg, backend="ref")
     budget = sharded.SHORTLIST_DENSE_MAX_BYTES
     for mode in ("ideal", "two_phase"):
-        calls.clear()
         monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", budget)
         req = SearchRequest(mode=mode, k=840)    # 825 valid rows: masked
         ref_res = ref_eng.search(store, qv, req)  # rows reach the top-k
         assert np.isneginf(np.asarray(ref_res.votes)).any(), mode
-        compiled = lambda r=req: jax.jit(
-            lambda st, q: eng.search(st, q, r).votes
+        compiled = lambda e, r=req: jax.jit(
+            lambda st, q: e.search(st, q, r).votes
         ).lower(store, qv).compile().as_text()
 
         dense_res = eng.search(store, qv, req)
-        assert not calls, "a block under the budget takes the dense route"
-        hc.assert_fused_tag(compiled(), False)
+        hc.assert_phase1_one_block(compiled(eng))
         monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
                             3 * N * 4 - 1)
-        fused_res = eng.search(store, qv, req)
-        assert len(calls) == 1, "a block over the budget streams the kernel"
-        ref_eng.search(store, qv, req)
-        assert len(calls) == 1, "ref backend keeps the dense reference"
-        hc.assert_fused_tag(compiled(), True)
-        for got in (dense_res, fused_res):
+        chunked_res = eng.search(store, qv, req)
+        for e in (eng, ref_eng):
+            assert hc.check_phase1_one_block(compiled(e)), \
+                f"{mode}: a block over the budget ranks in query chunks"
+        for got in (dense_res, chunked_res):
             for key in ("votes", "dist", "indices", "labels"):
                 np.testing.assert_array_equal(
                     np.asarray(getattr(ref_res, key)),
@@ -232,20 +247,19 @@ def test_ideal_routes_through_fused_kernel_at_large_n(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Fused shortlist on the SHARDED paths (ISSUE 4 tentpole): the kernel is the
-# one shortlist implementation across unsharded/sharded x ref/mxu/fused.
+# Query chunks on the SHARDED paths: one phase-1 block implementation across
+# unsharded/sharded x ref/pallas.
 # ---------------------------------------------------------------------------
 
 
-def test_shortlist_kernel_native_mask_odd_n_ties():
-    """kernels/shortlist.py handles masked rows natively (per-row penalty
-    block stream) and non-tile-aligned row counts: bit-identical to the
-    dense penalised matrix + lax.top_k, masked rows in the top-k included,
-    on a tie-heavy 45-row (odd) store with a bf16 projection."""
+def test_shortlist_kernel_native_mask_odd_n_ties(monkeypatch):
+    """The shared phase-1 block handles masked rows (the per-row penalty)
+    and a row count no tile divides in query chunks: bit-identical to the
+    dense penalised f32 matrix + lax.top_k, masked rows in the top-k
+    included, on a tie-heavy 45-row (odd) store with a bf16 projection."""
     from repro.core.encodings import make_encoding
+    from repro.engine.sharded import _local_shortlist
     from repro.kernels import ops as kops
-    from repro.kernels.shortlist import (SHORTLIST_MASK_PENALTY,
-                                         lut_shortlist_pallas)
     enc = make_encoding("mtmc", 8)
     base = jax.random.randint(jax.random.PRNGKey(0), (9, 20), 0, enc.levels)
     sv = jnp.concatenate([base] * 5, axis=0)[:45]          # 45 rows, ties
@@ -254,203 +268,68 @@ def test_shortlist_kernel_native_mask_odd_n_ties():
     q1h = kops.query_onehot(qv, jnp.float32)
     sp32 = kops.support_projection(sv, enc, jnp.float32)
     dense = q1h @ sp32.T + jnp.where(valid, 0.0,
-                                     SHORTLIST_MASK_PENALTY)[None]
+                                     kops.SHORTLIST_MASK_PENALTY)[None]
     neg, idx_ref = jax.lax.top_k(-dense, 40)               # masked in top-k
     sp16 = kops.support_projection(sv, enc)                # bf16 write-time
-    dist, idx = lut_shortlist_pallas(q1h, sp16, 40, valid=valid)
+    _chunk_budget(monkeypatch, 3, 45)                      # chunks of 3, 2
+    dist, idx = _local_shortlist(q1h, sp16, valid, 40)
     np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist))
     np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx))
     # the penalty is integer-exact and visible on masked candidates
-    assert float(dist[0, -1]) >= SHORTLIST_MASK_PENALTY
+    assert float(dist[0, -1]) >= kops.SHORTLIST_MASK_PENALTY
 
 
-def test_shortlist_kernel_packed_operand_k_over_lane():
-    """The bit-packed projection operand (MemoryStore.proj_packed layout)
-    feeds the kernel bit-identically to the unpacked matrix, including
-    k > 128 (above the lane width) and k not a lane multiple, with masked
-    rows landing inside the top-k of a tie-heavy store."""
-    from repro.core.encodings import make_encoding
-    from repro.kernels import ops as kops
-    from repro.kernels.shortlist import (SHORTLIST_MASK_PENALTY,
-                                         lut_shortlist_pallas)
-    enc = make_encoding("mtmc", 8)
-    base = jax.random.randint(jax.random.PRNGKey(2), (10, 16), 0, enc.levels)
-    sv = jnp.concatenate([base] * 15, axis=0)              # 150 rows, ties
-    qv = jax.random.randint(jax.random.PRNGKey(3), (4, 16), 0, 4)
-    valid = (jnp.arange(150) % 4) != 0                     # masked in top-k
-    q1h = kops.query_onehot(qv, jnp.float32)
-    proj = kops.support_projection(sv, enc, jnp.float32)
-    dense = q1h @ proj.T + jnp.where(valid, 0.0,
-                                     SHORTLIST_MASK_PENALTY)[None]
-    k = 131                                                # > 128, not 128*m
-    neg, idx_ref = jax.lax.top_k(-dense, k)
-    packed = kops.pack_projection(proj, enc)
-    bits = kops.projection_pack_bits(enc, proj.dtype)
-    dist, idx = lut_shortlist_pallas(q1h, None, k, valid=valid,
-                                     packed=packed, pack_bits=bits)
-    np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist))
-    np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx))
-
-
-def test_packed_shortlist_pack_bits_is_pack_time_width():
-    """Regression: the unpack width must be the PACK-time width, never
-    re-derived from a default dtype. b4e cl=8 is the edge that catches it:
-    the max LUT entry (65535) rounds to 65536 in bf16, so
-    projection_pack_bits says 32 for a bf16 projection but 16 for the f32
-    projection the store actually packs. Deriving bits from the bf16
-    default while holding a 16-bit-packed operand mis-unpacks every field;
-    `pack_bits` (MemoryStore.pack_bits) pins the width end to end."""
-    from repro.core.encodings import make_encoding
-    from repro.kernels import ops as kops
-    from repro.kernels.shortlist import lut_shortlist_pallas
-    enc = make_encoding("b4e", 8)
-    # the widths genuinely diverge on this encoding -- the test's premise
-    assert kops.projection_pack_bits(enc, jnp.float32) == 16
-    assert kops.projection_pack_bits(enc, jnp.bfloat16) == 32
-    base = jax.random.randint(jax.random.PRNGKey(6), (9, 12), 0, enc.levels)
-    sv = jnp.concatenate([base] * 4, axis=0)               # 36 rows, ties
-    qv = jax.random.randint(jax.random.PRNGKey(7), (3, 12), 0, 4)
-    q1h = kops.query_onehot(qv, jnp.float32)
-    proj = kops.support_projection(sv, enc, jnp.float32)   # write-time f32
-    packed = kops.pack_projection(proj, enc)               # 16-bit fields
-    neg, idx_ref = jax.lax.top_k(-(q1h @ proj.T), 20)
-    # kernel entry point: explicit pack-time width, packed-only operand
-    dist, idx = lut_shortlist_pallas(q1h, None, 20, packed=packed,
-                                     pack_bits=16)
-    np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist))
-    np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx))
-    # ops entry point: packed WITHOUT proj used to fall back to the bf16
-    # default (32 bits); the explicit pack_bits must win
-    dist2, idx2 = kops.lut_shortlist(qv, sv, enc, 20, packed=packed,
-                                     pack_bits=16)
-    np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist2))
-    np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx2))
-
-
-def test_shortlist_kernel_network_path_parity():
-    """The compiled-TPU lowering (use_network=True: per-tile bitonic sort +
-    sorted-run merge instead of lax.top_k/sort) is bit-identical to the
-    dense reference, including k > tile capacity forcing k_pad widening and
-    a non-tile-aligned N (jitted: the network is hundreds of eager ops)."""
-    from repro.core.encodings import make_encoding
-    from repro.kernels import ops as kops
-    from repro.kernels.shortlist import lut_shortlist_pallas
-    enc = make_encoding("mtmc", 8)
-    base = jax.random.randint(jax.random.PRNGKey(4), (9, 8), 0, enc.levels)
-    sv = jnp.concatenate([base] * 5, axis=0)[:44]          # 44 rows, ties
-    qv = jax.random.randint(jax.random.PRNGKey(5), (3, 8), 0, 4)
-    q1h = kops.query_onehot(qv, jnp.float32)
-    proj = kops.support_projection(sv, enc, jnp.float32)
-    neg, idx_ref = jax.lax.top_k(-(q1h @ proj.T), 40)
-    f = jax.jit(lambda q, p: lut_shortlist_pallas(
-        q, p, 40, tile_b=4, tile_n=16, k_pad=64, use_network=True))
-    dist, idx = f(q1h, proj)
-    np.testing.assert_array_equal(np.asarray(-neg), np.asarray(dist))
-    np.testing.assert_array_equal(np.asarray(idx_ref), np.asarray(idx))
-
-
-def test_sharded_fused_shortlist_matches_dense_and_unsharded():
-    """Sharded `ideal` and `two_phase` above the fused threshold run the
-    fused Pallas kernel inside shard_map (asserted on compiled HLO via the
-    shortlist_fused scope tag) and stay bit-identical to the sharded-dense
-    path AND the unsharded ref store -- ties and masked rows in the top-k
-    included."""
-    from repro.engine import MemoryStore, SearchRequest
-    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="ref")
-    base = jax.random.randint(jax.random.PRNGKey(0), (8, 20), 0,
-                              cfg.enc.levels)
-    sv = jnp.concatenate([base] * 9, axis=0)               # 72 rows, ties
-    labels = jnp.where(jnp.arange(72) % 4 == 0, -1,
-                       jnp.arange(72)).astype(jnp.int32)   # 18 masked rows
-    store = MemoryStore.from_quantized(sv, labels, cfg)
-    qv = jax.random.randint(jax.random.PRNGKey(1), (5, 20), 0, 4)
+def test_sharded_fused_shortlist_matches_dense_and_unsharded(monkeypatch):
+    """Sharded `ideal` and `two_phase` over the phase-1 budget rank each
+    shard's block in query chunks inside shard_map (a loop in the
+    compiled `search_shortlist`, none under the budget) and stay
+    bit-identical to the one-block path AND the unsharded ref store --
+    ties and masked rows in the top-k included."""
+    from repro.engine import SearchRequest, sharded
+    cfg, store, qv = _tie_heavy_store()
     mesh = make_mesh((1,), ("data",))
     sstore = store.shard(mesh, ("data",))
-    eng = RetrievalEngine(cfg, backend="mxu")
+    eng = RetrievalEngine(cfg, backend="pallas")
+    budget = sharded.SHORTLIST_DENSE_MAX_BYTES
     for mode in ("ideal", "two_phase"):
         ref = RetrievalEngine(cfg, backend="ref").search(
             store, qv, SearchRequest(mode=mode, k=60))
-        for fmr, fused in ((1, True), (1 << 30, False)):
-            req = SearchRequest(mode=mode, k=60, fused_min_rows=fmr)
+        req = SearchRequest(mode=mode, k=60)
+        for chunked, cut in ((False, budget), (True, 2 * 72 * 4)):
+            monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", cut)
             with mesh:
-                got = jax.jit(lambda st, q, r=req: eng.search(st, q, r))(
+                got = jax.jit(lambda st, q: eng.search(st, q, req))(
                     sstore, qv)
                 hlo = jax.jit(
-                    lambda st, q, r=req: eng.search(st, q, r).votes
+                    lambda st, q: eng.search(st, q, req).votes
                 ).lower(sstore, qv).compile().as_text()
             for key in ("votes", "dist", "indices", "labels"):
                 np.testing.assert_array_equal(
                     np.asarray(getattr(ref, key)),
                     np.asarray(getattr(got, key)),
-                    err_msg=f"{mode}/fmr={fmr}/{key}")
-            hc.assert_fused_tag(hlo, fused)
+                    err_msg=f"{mode}/chunked={chunked}/{key}")
+            assert bool(hc.check_phase1_one_block(hlo)) == chunked, mode
         # masked candidates did reach the merged top-k (k=60 > 54 valid)
         assert np.isneginf(np.asarray(ref.votes)).any(), mode
 
 
-def test_fused_min_rows_knob_engine_and_request(monkeypatch):
-    """The default is no row threshold (the shape rule decides), and an
-    explicit one still forces the kernel: the engine field and the
-    per-request SearchRequest.fused_min_rows override both steer the
-    dispatch (request wins), the 'fused' backend always fuses, and a
-    block over the budget fuses whatever the threshold."""
-    from repro.engine import MemoryStore, SearchRequest, sharded
-    from repro.kernels import ops as kernel_ops
-    cfg = SearchConfig("mtmc", cl=8, mode="avss", use_kernel="auto")
-    sv = jax.random.randint(jax.random.PRNGKey(0), (64, 16), 0,
-                            cfg.enc.levels)
-    store = MemoryStore.from_quantized(
-        sv, jnp.arange(64, dtype=jnp.int32), cfg)
-    qv = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, 4)
-    calls = []
-    orig = kernel_ops.lut_shortlist
-    monkeypatch.setattr(kernel_ops, "lut_shortlist",
-                        lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
-    default_eng = RetrievalEngine(cfg)
-    assert default_eng.fused_min_rows is None
-    req = SearchRequest(mode="ideal", k=8)
-    default_eng.search(store, qv, req)           # 3 x 64 block: dense
-    assert not calls
-    low_eng = RetrievalEngine(cfg, fused_min_rows=8)
-    low_eng.search(store, qv, req)               # 64 >= 8: fused
-    assert len(calls) == 1
-    # request override wins over the engine field, in both directions
-    low_eng.search(store, qv,
-                   SearchRequest(mode="ideal", k=8, fused_min_rows=1 << 30))
-    assert len(calls) == 1
-    default_eng.search(store, qv,
-                       SearchRequest(mode="ideal", k=8, fused_min_rows=16))
-    assert len(calls) == 2
-    # the two-phase shortlist obeys the same threshold (one implementation)
-    default_eng.search(store, qv,
-                       SearchRequest(mode="two_phase", k=8,
-                                     fused_min_rows=16))
-    assert len(calls) == 3
-    RetrievalEngine(cfg, backend="fused").search(store, qv, req)
-    assert len(calls) == 4
-    # over the budget the kernel runs even under a threshold not reached
-    monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", 3 * 64 * 4 - 1)
-    low_eng.search(store, qv,
-                   SearchRequest(mode="ideal", k=8, fused_min_rows=1 << 30))
-    assert len(calls) == 5
-
-
 @pytest.mark.slow
 def test_sharded_fused_8dev_ragged_bit_identical():
-    """Acceptance (ISSUE 4 tentpole): on a forced 8-device mesh with a
-    RAGGED capacity-100 split (13-row local blocks, 4 pad rows), sharded
-    `ideal` and `two_phase` above the fused threshold run the fused Pallas
-    shortlist kernel inside shard_map (compiled-HLO scope-tag assertion)
-    with results bit-identical to the sharded-dense path and the unsharded
-    store -- tie-heavy rows and masked rows (70 empty slots + 4 pads)
-    inside the merged top-k."""
+    """On a forced 8-device mesh with a RAGGED capacity-100 split (13-row
+    local blocks, 4 pad rows), sharded `ideal` and `two_phase` over the
+    phase-1 budget rank each shard's block in query chunks inside
+    shard_map (a loop in the compiled `search_shortlist`) with results
+    bit-identical to the one-block path and the unsharded store --
+    tie-heavy rows and masked rows (70 empty slots + 4 pads) inside the
+    merged top-k."""
     code = """
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.mesh import make_mesh
         from repro.analysis import hlo_contracts as hc
         from repro.core.avss import SearchConfig
         from repro.core.memory import MemoryConfig
-        from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+        from repro.engine import (MemoryStore, RetrievalEngine,
+                                  SearchRequest, sharded)
 
         cfg = MemoryConfig(capacity=100, dim=24,
                            search=SearchConfig("mtmc", cl=8, mode="avss",
@@ -463,7 +342,8 @@ def test_sharded_fused_8dev_ragged_bit_identical():
         mesh = make_mesh((8,), ("data",))
         sstore = store.shard(mesh, ("data",))
         assert sstore.capacity == 104, sstore.capacity  # ragged: 13/shard
-        eng = RetrievalEngine(cfg.search, backend="mxu")
+        eng = RetrievalEngine(cfg.search, backend="pallas")
+        budget = sharded.SHORTLIST_DENSE_MAX_BYTES
 
         # k=50 > 30 valid rows: masked (empty + pad) rows reach the top-k;
         # k_loc = 13 == the full local block, so the merge is exhaustive
@@ -471,36 +351,27 @@ def test_sharded_fused_8dev_ragged_bit_identical():
             ref = RetrievalEngine(cfg.search, backend="ref").search(
                 store, q, SearchRequest(mode=mode, k=50))
             assert np.isneginf(np.asarray(ref.votes)).any(), mode
+            req = SearchRequest(mode=mode, k=50)
             outs = {}
-            for tag, fmr in (("fused", 1), ("dense", 1 << 30)):
-                req = SearchRequest(mode=mode, k=50, fused_min_rows=fmr)
+            # 4 of the 6 queries' (4, 13) block fits: chunks of 4 and 2
+            for tag, cut in (("dense", budget), ("chunked", 4 * 13 * 4)):
+                sharded.SHORTLIST_DENSE_MAX_BYTES = cut
                 with mesh:
-                    f = jax.jit(lambda st, qq, r=req: eng.search(st, qq, r))
+                    f = jax.jit(lambda st, qq: eng.search(st, qq, req))
                     outs[tag] = f(sstore, q)
-                    hlo = jax.jit(lambda st, qq, r=req: eng.search(
-                        st, qq, r).votes).lower(sstore, q).compile().as_text()
-                hc.assert_fused_tag(hlo, tag == "fused")
-            for tag in ("fused", "dense"):
+                    hlo = jax.jit(lambda st, qq: eng.search(
+                        st, qq, req).votes).lower(sstore, q).compile(
+                        ).as_text()
+                assert bool(hc.check_phase1_one_block(hlo)) == (
+                    tag == "chunked"), (mode, tag)
+            sharded.SHORTLIST_DENSE_MAX_BYTES = budget
+            for tag in ("chunked", "dense"):
                 for key in ("votes", "dist", "indices", "labels"):
                     np.testing.assert_array_equal(
                         np.asarray(getattr(ref, key)),
                         np.asarray(getattr(outs[tag], key)),
                         err_msg=f"{mode}/{tag}/{key}")
-
-        # the 'fused' backend fuses unconditionally (no threshold), and the
-        # engine-level field steers the default dispatch
-        with mesh:
-            hlo = jax.jit(lambda st, qq: RetrievalEngine(
-                cfg.search, backend="fused").search(
-                    st, qq, SearchRequest(mode="ideal", k=13)).votes
-                ).lower(sstore, q).compile().as_text()
-            hc.assert_fused_tag(hlo, True)
-            hlo = jax.jit(lambda st, qq: RetrievalEngine(
-                cfg.search, backend="mxu", fused_min_rows=13).search(
-                    st, qq, SearchRequest(mode="two_phase", k=13)).votes
-                ).lower(sstore, q).compile().as_text()
-            hc.assert_fused_tag(hlo, True)
-        print("SHARDED-FUSED-OK")
+        print("SHARDED-CHUNKED-OK")
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -509,7 +380,7 @@ def test_sharded_fused_8dev_ragged_bit_identical():
                           capture_output=True, text=True, timeout=600,
                           env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert "SHARDED-FUSED-OK" in proc.stdout
+    assert "SHARDED-CHUNKED-OK" in proc.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -84,9 +84,18 @@ def test_noiseless_dist_equals_weighted_l1():
 @pytest.mark.parametrize("cl", [2, 8, 32])
 @pytest.mark.parametrize("d", [16, 48, 100])
 def test_mxu_lut_dist_exact(cl, d):
+    """The dense phase-1 distance -- the query one-hots dotted against the
+    stored bf16 `proj` with f32 accumulation (engine/sharded
+    ._local_shortlist, every row ranked) -- equals the reference AVSS
+    distance and |cl*q - v| exactly."""
+    from repro.engine.sharded import _local_shortlist
     enc = make_encoding("mtmc", cl)
     qv, sv = _layouts("avss", enc, d, N=70, B=6, seed=cl + d)
-    di = ops.avss_ideal_dist(qv, sv, enc)
+    dist, idx = _local_shortlist(ops.query_onehot(qv),
+                                 ops.support_projection(sv, enc), None,
+                                 sv.shape[0])
+    di = np.empty(dist.shape, np.float32)
+    np.put_along_axis(di, np.asarray(idx), np.asarray(dist), axis=1)
     dr = ref.avss_dist_ref(qv, sv, jnp.asarray(avss_sum_lut(enc)))
     np.testing.assert_array_equal(np.asarray(di), np.asarray(dr))
     # against direct value-space distance |cl*q - v|

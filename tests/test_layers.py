@@ -61,11 +61,19 @@ def search_text(cfg, store, q, backend, **request) -> str:
     return jax.jit(search).lower(store, q).compile().as_text()
 
 
-@pytest.mark.parametrize("backend", ["ref", "fused"])
-@pytest.mark.parametrize("mode", ["two_phase", "ideal", "full"])
-def test_search_ops_map_to_layers(toy, mode, backend):
+@pytest.mark.parametrize("mode,backend", [
+    ("two_phase", "ref"), ("ideal", "ref"), ("full", "ref"),
+    ("two_phase", "chunked"), ("ideal", "chunked"), ("full", "pallas")])
+def test_search_ops_map_to_layers(toy, monkeypatch, mode, backend):
     cfg, store, s, q = toy
     store = store.write(s, jnp.arange(N))
+    chunked = backend == "chunked"
+    if chunked:
+        # phase 1 over a budget cut to 2 queries' (2, N) block: the batch
+        # is ranked in query chunks, a loop inside `search_shortlist`
+        from repro.engine import sharded
+        monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", 2 * N * 4)
+        backend = "pallas"
     text = search_text(cfg, store, q, backend, mode=mode)
     assert unmapped(text) == []
     want = {hc.QUANTIZE_SCOPE, hc.ANSWER_SCOPE,
@@ -73,10 +81,8 @@ def test_search_ops_map_to_layers(toy, mode, backend):
     assert want <= layers_in(text)
     if mode == "two_phase":
         assert hc.SHORTLIST_SCOPE in layers_in(text)
-    if backend == "fused" and mode != "full":
-        # the contract tag stays, nested inside the layer
-        fused = hc.scoped_lines(text, hc.FUSED_SCOPE_TAG)
-        assert fused and all(hc.SHORTLIST_SCOPE in line for line in fused)
+    # the query-chunk loop is phase 1's own, and only where chunks are due
+    assert bool(hc.check_phase1_one_block(text)) == chunked
 
 
 @pytest.mark.parametrize("mode", ["two_phase", "ideal"])
@@ -133,14 +139,16 @@ def test_knnlm_decode_step_and_prefill_ops_map_to_layers():
 
 def test_sharded_search_and_write_ops_map_to_layers():
     """Four forced host devices (a subprocess: the device count is fixed
-    at JAX start-up): the mesh-sharded search on both shortlist paths and
+    at JAX start-up): the mesh-sharded search, phase 1 in one block and in
+    query chunks, and
     the shard-local streamed write."""
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
         from repro.analysis import hlo_contracts as hc
         from repro.core.avss import SearchConfig
         from repro.core.memory import MemoryConfig
-        from repro.engine import MemoryStore, RetrievalEngine, SearchRequest
+        from repro.engine import (MemoryStore, RetrievalEngine,
+                                  SearchRequest, sharded)
         from repro.launch.mesh import make_mesh
 
         def unmapped(text):
@@ -157,9 +165,14 @@ def test_sharded_search_and_write_ops_map_to_layers():
         store = store.write(s, jnp.arange(256))
         mesh = make_mesh((4,), ("data",))
         store = store.shard(mesh, ("data",))
-        for backend in ("ref", "fused"):
+        budget = sharded.SHORTLIST_DENSE_MAX_BYTES
+        for backend in ("ref", "chunked"):
+            # chunked: each shard's 64 rows ranked 2 queries at a time
+            sharded.SHORTLIST_DENSE_MAX_BYTES = (
+                2 * 64 * 4 if backend == "chunked" else budget)
             for mode in ("two_phase", "ideal"):
-                eng = RetrievalEngine(cfg, backend=backend)
+                eng = RetrievalEngine(
+                    cfg, backend="pallas" if backend == "chunked" else "ref")
                 def search(st, q):
                     res = eng.search(st, q, SearchRequest(mode=mode, k=8))
                     return res, res.predict()

@@ -25,7 +25,7 @@ from repro.engine.pager import ShardPager
 N, DIM, S = 144, 12, 8
 
 
-def _cfg(backend="mxu"):
+def _cfg(backend="pallas"):
     return SearchConfig("mtmc", cl=8, mode="avss", use_kernel=backend)
 
 
@@ -152,8 +152,8 @@ def test_paged_store_save_restore_bit_identical(paged_fixture):
         host.save(td, 0)
         back = MemoryStore.restore(td, host.cfg).shard(
             n_shards=S, residency="host")
-    for f in ("values", "proj", "proj_packed", "s_grid", "labels",
-              "sketch_sums", "sketch_counts", "lo", "hi", "size"):
+    for f in ("values", "proj", "s_grid", "labels", "sketch_sums",
+              "sketch_counts", "lo", "hi", "size"):
         np.testing.assert_array_equal(np.asarray(getattr(host, f)),
                                       np.asarray(getattr(back, f)),
                                       err_msg=f)

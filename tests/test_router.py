@@ -48,7 +48,7 @@ def routed_fixture():
     labs = jnp.asarray(labs)
     q = jnp.asarray(rng.integers(0, 4, (5, DIM)))
     stores = {}
-    for backend in ("ref", "mxu", "fused"):
+    for backend in ("ref", "pallas"):
         stores[backend] = MemoryStore.from_quantized(
             vals, labs, _cfg(backend)).shard(n_shards=N_SHARDS)
     return stores, q
@@ -59,30 +59,34 @@ def _leaves(res):
             for f in ("votes", "dist", "indices", "labels")}
 
 
-@pytest.mark.parametrize("backend", ["ref", "mxu", "fused"])
+@pytest.mark.parametrize("backend", ["ref", "pallas", "chunked"])
 @pytest.mark.parametrize("mode", ["two_phase", "ideal"])
 @pytest.mark.parametrize("nprobe", [1, 3, 5])
 def test_routed_bit_identical_to_restricted_brute_force(
-        routed_fixture, backend, mode, nprobe):
+        routed_fixture, monkeypatch, backend, mode, nprobe):
     """Routed == exhaustive search filtered to the visited shards, per
     query, on every leaf -- including two-phase votes (global noise
-    coordinates) and distances (exact integers, so equality is exact)."""
+    coordinates) and distances (exact integers, so equality is exact).
+    `chunked`: the routed batch is mapped 2 queries at a time (the
+    phase-1 budget cut to a (2, nprobe * rows) block)."""
     stores, q = routed_fixture
-    store = stores[backend]
+    store = stores["pallas" if backend == "chunked" else backend]
     eng = RetrievalEngine(store.cfg.search)
-    fmr = 1 if backend == "fused" else None    # force the fused kernel
-    routed = eng.search(store, q, SearchRequest(
-        mode=mode, k=K, nprobe=nprobe, fused_min_rows=fmr))
 
     # the reference: the FULL search ranked over all rows, then filtered
     # to the rows of the router's visited shards
-    full = eng.search(store, q, SearchRequest(
-        mode=mode, k=store.capacity, fused_min_rows=fmr))
+    full = eng.search(store, q, SearchRequest(mode=mode, k=store.capacity))
+    rows = store.capacity // N_SHARDS
+    if backend == "chunked":
+        from repro.engine import sharded
+        monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                            2 * nprobe * rows * 4)
+    routed = eng.search(store, q, SearchRequest(mode=mode, k=K,
+                                                nprobe=nprobe))
     scores = router_lib.route_scores(
         store.quantize_queries(q), store.sketch_sums, store.sketch_counts,
         store.cfg.search.enc)
     sids = np.asarray(router_lib.top_shards(scores, nprobe))
-    rows = store.capacity // N_SHARDS
     got, ref = _leaves(routed), _leaves(full)
     for b in range(q.shape[0]):
         shard_of_row = ref["indices"][b] // rows
@@ -98,7 +102,7 @@ def test_nprobe_none_and_all_shards_byte_identical(routed_fixture, mode):
     """nprobe=None, nprobe=n_shards and nprobe>n_shards are the SAME
     exhaustive program -- byte-identical results."""
     stores, q = routed_fixture
-    store = stores["mxu"]
+    store = stores["pallas"]
     eng = RetrievalEngine(store.cfg.search)
     base = eng.search(store, q, SearchRequest(mode=mode, k=K))
     for p in (N_SHARDS, N_SHARDS + 3):
@@ -113,7 +117,7 @@ def test_nprobe_on_unpartitioned_store_is_exhaustive(routed_fixture):
     rng = np.random.default_rng(3)
     store = MemoryStore.from_quantized(
         jnp.asarray(rng.integers(0, 16, (24, DIM))),
-        jnp.asarray(rng.integers(0, 5, (24,))), _cfg("mxu"))
+        jnp.asarray(rng.integers(0, 5, (24,))), _cfg("pallas"))
     eng = RetrievalEngine(store.cfg.search)
     a = eng.search(store, q, SearchRequest(mode="two_phase", k=6))
     b = eng.search(store, q, SearchRequest(mode="two_phase", k=6, nprobe=1))

@@ -121,7 +121,7 @@ def test_from_quantized_matches_raw_two_phase():
     qv = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, 4)
     store = MemoryStore.from_quantized(
         sv, jnp.arange(40, dtype=jnp.int32), cfg)
-    for backend in ("ref", "mxu", "fused"):
+    for backend in ("ref", "pallas"):
         eng = RetrievalEngine(cfg, backend=backend)
         old = jax.jit(lambda s, q, e=eng: e.two_phase(q, s, k=8))(sv, qv)
         new = jax.jit(lambda st, q, e=eng: e.search(
@@ -234,7 +234,7 @@ def test_empty_store_predicts_sentinel_every_mode_and_backend():
     store = MemoryStore.create(cfg)
     q = jax.random.randint(jax.random.PRNGKey(0), (3, 8), 0, 4)
     for mode in ("full", "two_phase", "ideal"):
-        for backend in ("ref", "mxu", "fused"):
+        for backend in ("ref", "pallas"):
             eng = RetrievalEngine(cfg.search, backend=backend)
             res = eng.search(store, q, SearchRequest(mode=mode, k=4))
             assert (np.asarray(res.predict()) == -1).all(), (mode, backend)
@@ -255,12 +255,12 @@ def test_request_backend_override_engine_is_cached():
     call instead of a rebuilt engine (and a cold jit closure)."""
     eng = RetrievalEngine(_cfg().search)
     assert eng.with_backend("auto") is eng
-    a = eng.with_backend("fused")
-    assert a is eng.with_backend("fused")
-    assert a.backend == "fused" and a.cfg is eng.cfg
+    a = eng.with_backend("pallas")
+    assert a is eng.with_backend("pallas")
+    assert a.backend == "pallas" and a.cfg is eng.cfg
     # the override engine caches too, and distinct backends stay distinct
-    assert eng.with_backend("mxu") is not a
-    assert a.with_backend("fused") is a
+    assert eng.with_backend("ref") is not a
+    assert a.with_backend("pallas") is a
 
 
 # ---------------------------------------------------------------------------
@@ -307,8 +307,7 @@ def test_single_shard_write_dispatches_to_scatter():
         return st._program_streamed(vq, l, v.shape[0])
     g = jax.jit(stream_write)
     streamed = g(g(sstore, vecs[:12], labs[:12]), vecs[12:], labs[12:])
-    for key in ("values", "proj", "proj_packed", "s_grid", "labels",
-                "size"):
+    for key in ("values", "proj", "s_grid", "labels", "size"):
         np.testing.assert_array_equal(
             np.asarray(getattr(streamed, key)),
             np.asarray(getattr(written, key)), err_msg=key)
@@ -320,8 +319,7 @@ def test_single_shard_write_dispatches_to_scatter():
     hc.assert_scatter_write(hlo)
     # ...and matches the scatter path on the unsharded store exactly
     scattered = base.write(vecs[:12], labs[:12]).write(vecs[12:], labs[12:])
-    for key in ("values", "proj", "proj_packed", "s_grid", "labels",
-                "size"):
+    for key in ("values", "proj", "s_grid", "labels", "size"):
         np.testing.assert_array_equal(
             np.asarray(getattr(scattered, key)),
             np.asarray(getattr(written, key)), err_msg=key)

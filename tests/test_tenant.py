@@ -2,9 +2,10 @@
 
 The tentpole's contract: `RetrievalEngine.search_tenants` over a stacked
 `TenantStore` is BIT-IDENTICAL per tenant to solo `engine.search` over
-each tenant's own store -- on every mode x backend x packed/unpacked
-route, including the noisy paths (whose counter-hash noise is keyed on
-the query's rank WITHIN its tenant group, not its batch position) -- and
+each tenant's own store -- on every mode x backend route, phase 1 in one
+block or in query chunks, including the noisy paths (whose counter-hash
+noise is keyed on the query's rank WITHIN its tenant group, not its batch
+position) -- and
 ONE jitted search program serves any tenant count (one cache entry per
 distinct batch shape, none per tenant, none per write).
 
@@ -14,8 +15,6 @@ queries must predict the -1 sentinel), one tie-heavy tenant (duplicated
 rows force (distance, index) lexicographic rank to carry the parity),
 and masked label -1 rows placed to land inside the top-k.
 """
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -81,16 +80,21 @@ def _assert_rows_equal(batched, solo, sel, width, mode):
         assert bool((batched.votes[sel][:, width:] == -jnp.inf).all())
 
 
-@pytest.mark.parametrize("backend", ["ref", "mxu", "fused"])
-@pytest.mark.parametrize("mode", ["full", "two_phase", "ideal"])
-@pytest.mark.parametrize("packed", [True, False])
-def test_search_tenants_bit_parity(tenant_fixture, mode, backend, packed):
+@pytest.mark.parametrize("mode,backend,chunked", [
+    *[(m, b, False) for b in ("ref", "pallas")
+      for m in ("full", "two_phase", "ideal")],
+    ("two_phase", "pallas", True), ("ideal", "pallas", True)])
+def test_search_tenants_bit_parity(tenant_fixture, monkeypatch, mode,
+                                   backend, chunked):
     stores, tstore, queries, tenant_ids = tenant_fixture
-    if not packed:
-        tstore = dataclasses.replace(tstore, proj_packed=None)
-        stores = [dataclasses.replace(s, proj_packed=None) for s in stores]
     eng = RetrievalEngine(_cfg())
     req = SearchRequest(mode=mode, k=K, backend=backend)
+    if chunked:
+        # phase-1 budget cut to 3 queries' (3, Np) block: the tenant batch
+        # of 10 is mapped in chunks of 3 (the last one ragged)
+        from repro.engine import sharded
+        monkeypatch.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES",
+                            3 * tstore.n_pad * 4)
     res = eng.search_tenants(tstore, queries, tenant_ids, req)
     tids = np.asarray(tenant_ids)
     for t in range(len(CAPS)):
@@ -159,8 +163,8 @@ def test_stack_round_trip(tenant_fixture):
     assert tstore.capacities == CAPS
     for i, s in enumerate(stores):
         t = tstore.tenant(i)
-        for leaf in ("values", "proj", "proj_packed", "s_grid", "labels",
-                     "size", "lo", "hi"):
+        for leaf in ("values", "proj", "s_grid", "labels", "size", "lo",
+                     "hi"):
             np.testing.assert_array_equal(np.asarray(getattr(t, leaf)),
                                           np.asarray(getattr(s, leaf)),
                                           err_msg=f"tenant {i}: {leaf}")
@@ -191,8 +195,7 @@ def test_write_at_matches_solo_write(tenant_fixture):
     labs = jnp.array([9, 8, 7])
     t2 = tstore.write_at(EMPTY, vecs, labs).tenant(EMPTY)
     solo = stores[EMPTY].write(vecs, labs)
-    for leaf in ("values", "proj", "proj_packed", "s_grid", "labels",
-                 "size"):
+    for leaf in ("values", "proj", "s_grid", "labels", "size"):
         np.testing.assert_array_equal(np.asarray(getattr(t2, leaf)),
                                       np.asarray(getattr(solo, leaf)),
                                       err_msg=leaf)

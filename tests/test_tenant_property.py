@@ -47,16 +47,23 @@ def _stores(caps, masked, ties, seed):
     return out
 
 
-@settings(max_examples=20, deadline=None,
+# report_multiple_bugs=False: stop at the first failing example, so the
+# pinned one below fails the test at once rather than after the others
+@settings(max_examples=20, deadline=None, report_multiple_bugs=False,
           suppress_health_check=list(HealthCheck))
 @given(caps=st.lists(st.integers(1, 14), min_size=1, max_size=6),
        kfrac=st.floats(0.1, 1.5),
        mode=st.sampled_from(["full", "two_phase", "ideal"]),
-       backend=st.sampled_from(["ref", "mxu", "fused"]),
+       backend=st.sampled_from(["ref", "pallas"]),
        masked=st.booleans(), ties=st.booleans(),
        seed=st.integers(0, 2 ** 16))
+# the known tenant-parity defect (ROADMAP B1): tenant 1's only row is
+# masked, and the stacked search returns the pad row's phase-1 `dist` in
+# its place. Pinned so the test fails on it at once, without a shrink.
+@example(caps=[2, 1], kfrac=1.0, mode="two_phase", backend="ref",
+         masked=True, ties=False, seed=63)
 # maximally ragged + tie-heavy + masked, k beyond the smallest capacity
-@example(caps=[1, 14, 3], kfrac=1.5, mode="two_phase", backend="fused",
+@example(caps=[1, 14, 3], kfrac=1.5, mode="two_phase", backend="pallas",
          masked=True, ties=True, seed=7)
 # single tenant degenerate case through the full (noisy dense) route
 @example(caps=[5], kfrac=0.5, mode="full", backend="ref", masked=False,
@@ -108,8 +115,8 @@ def test_stack_tenant_round_trip_property(caps, masked, seed):
     assert tstore.capacities == tuple(caps)
     for i, s in enumerate(stores):
         t = tstore.tenant(i)
-        for leaf in ("values", "proj", "proj_packed", "s_grid", "labels",
-                     "size", "lo", "hi"):
+        for leaf in ("values", "proj", "s_grid", "labels", "size", "lo",
+                     "hi"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(t, leaf)), np.asarray(getattr(s, leaf)),
                 err_msg=f"tenant {i}: {leaf}")

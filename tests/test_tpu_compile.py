@@ -1,9 +1,10 @@
-"""Every Pallas kernel of the served path compiles for a described TPU v5e.
+"""The served search and its Pallas kernel compile for a described TPU v5e.
 
-Nothing runs here: each kernel is lowered with `interpret=False` and
-compiled by the TPU compiler for a chip that is described, not attached,
-at the widths the service uses (Omniglot geometry: d=48 -> 4d=192 LUT
-columns, MTMC CL=32 -> S=64 strings of sl=24 cells). Mosaic refuses what
+Nothing runs here: each program is lowered (the kernel with
+`interpret=False`) and compiled by the TPU compiler for a chip that is
+described, not attached, at the widths the service uses (Omniglot
+geometry: d=48 -> 4d=192 LUT columns, MTMC CL=32 -> S=64 strings of sl=24
+cells). Mosaic refuses what
 interpret mode accepts -- unaligned dynamic slices, unsupported casts,
 blocks over the VMEM budget -- so this guards every later change at no chip
 time. The topology is described inside a fixture (never at import), and
@@ -11,6 +12,7 @@ all the compiles stay in this one file: only one process may load the TPU
 compiler library at a time.
 """
 
+import math
 import os
 import re
 
@@ -20,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.mcam import MCAMConfig
-from repro.kernels import mcam_dist, mcam_search, shortlist
+from repro.kernels import mcam_search
 
 B, N, K4D, TOPK = 64, 65536, 192, 32
 S, SL = 64, 24
@@ -54,34 +56,6 @@ def _no_persistent_cache():
 
 def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
-
-
-@pytest.mark.parametrize("form", ["bf16", "masked", "packed8", "packed16"])
-def test_shortlist_kernel_compiles(one_chip, form):
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    valid = sds((N,), jnp.bool_)
-    if form.startswith("packed"):
-        bits = int(form[len("packed"):])
-        dp = -(-K4D // (32 // bits))
-        fn = lambda q, p, v: shortlist.lut_shortlist_pallas(
-            q, None, TOPK, valid=v, packed=p, pack_bits=bits,
-            interpret=False)
-        args = (sds((B, K4D), jnp.float32), sds((N, dp), jnp.int32), valid)
-    else:
-        fn = lambda q, s, *v: shortlist.lut_shortlist_pallas(
-            q, s, TOPK, valid=v[0] if v else None, interpret=False)
-        args = (sds((B, K4D), jnp.bfloat16), sds((N, K4D), jnp.bfloat16))
-        if form == "masked":
-            args += (valid,)
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
-
-
-def test_lut_dist_matmul_compiles(one_chip):
-    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
-                                             sharding=one_chip)
-    fn = lambda q, s: mcam_dist.lut_dist_matmul(q, s, interpret=False)
-    assert "tpu_custom_call" in _compiled_text(fn, sds((B, 512)),
-                                               sds((N, 512)))
 
 
 @pytest.mark.parametrize("noisy", [True, False])
@@ -154,7 +128,8 @@ def _result_rows(text: str) -> dict[str, set[int]]:
 def test_two_phase_search_ops_map_to_layers(one_chip, cell):
     """The served two_phase search at each cell's store geometry, compiled
     for the chip: its (B, N) distance block is under the dense budget, so
-    phase 1 is XLA's dot and exact top-k with no Pallas kernel at all;
+    phase 1 is XLA's dot and exact top-k over the whole batch at once (no
+    loop in `search_shortlist`, no Pallas kernel);
     every op that does work, the copies the TPU compiler inserts among
     them, maps to a layer of the program (analysis.hlo_contracts.op_layers),
     and no op of the rescore yields N rows: it gathers the B*k candidate
@@ -163,8 +138,8 @@ def test_two_phase_search_ops_map_to_layers(one_chip, cell):
 
     n, dim, cl, batch = CELL_GEOMETRIES[cell]
     text = _two_phase_search_text(one_chip, n=n, dim=dim, cl=cl, batch=batch)
-    assert hc.scoped_lines(text, hc.FUSED_SCOPE_TAG) == []
     assert "tpu_custom_call" not in text
+    hc.assert_phase1_one_block(text)
     _, ops = hc.parse_hlo(text)
     phase1 = [o for o in ops
               if hc.innermost_layer(o.op_name) == hc.SHORTLIST_SCOPE]
@@ -183,15 +158,31 @@ def test_two_phase_search_ops_map_to_layers(one_chip, cell):
 
 
 def test_two_phase_search_over_budget_compiles_fused_kernel(one_chip):
-    """The same search with its (B, N) block over the dense budget (the
-    budget cut to just under B * N * 4 bytes) compiles the fused Pallas
-    shortlist kernel into phase 1."""
+    """The same search with its (B, N) block over the phase-1 budget (cut
+    to 16 queries' (16, N) f32 block) ranks the batch in query chunks: a
+    loop in `search_shortlist` whose largest f32 temporary fits the
+    budget, still the dot and exact top-k, and no Pallas kernel."""
     from repro.analysis import hlo_contracts as hc
     from repro.engine import sharded
 
+    budget = 16 * N * 4
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", B * N * 4 - 1)
+        mp.setattr(sharded, "SHORTLIST_DENSE_MAX_BYTES", budget)
         text = _two_phase_search_text(one_chip)
-    fused = hc.scoped_lines(text, hc.FUSED_SCOPE_TAG)
-    assert fused and all(hc.SHORTLIST_SCOPE in line for line in fused)
-    assert any("tpu_custom_call" in line for line in fused)
+    assert hc.check_phase1_one_block(text), "expected query chunks"
+    assert "tpu_custom_call" not in text
+    _, ops = hc.parse_hlo(text)
+    phase1 = [o for o in ops
+              if hc.innermost_layer(o.op_name) == hc.SHORTLIST_SCOPE]
+    assert any(o.opcode in ("dot", "convolution") for o in phase1)
+    assert any(o.op_name.endswith("/top_k") for o in phase1)
+    # the arrays the layer's ops materialise (a fusion's result, not the
+    # values inside it)
+    rhs = {m.group(1): m.group(2) for m in map(
+        _RESULT.match, text.splitlines()) if m}
+    f32 = [math.prod(int(x) for x in dims.split(",") if x)
+           for name, layer in hc.op_layers(text).items()
+           if layer == hc.SHORTLIST_SCOPE
+           for dims in re.findall(r"f32\[([\d,]*)\]", rhs.get(name, ""))]
+    assert f32 and max(f32) * 4 <= budget, max(f32) * 4
+    assert max(f32) * 4 > budget // 2      # the chunk's block is there

@@ -4,7 +4,8 @@ forward.
 The contract (ISSUE 5): `RetrievalEngine.episode_votes` -- the forward
 hardware-aware training differentiates through -- produces votes/distances
 BIT-IDENTICAL to `engine.search` on a `MemoryStore` programmed with the
-same supports, across modes, backends (ref + fused) and sharding. The
+same supports, across modes, backends, phase-1 query chunks and sharding.
+The
 straight-through estimators are wrappers around the shared primitives, so
 no future engine refactor can silently diverge from training without
 failing this file.
@@ -70,12 +71,13 @@ def test_episode_scores_equal_served_class_head():
     np.testing.assert_array_equal(np.asarray(scores), np.asarray(served))
 
 
-@pytest.mark.parametrize("backend", ["ref", "fused"])
+@pytest.mark.parametrize("backend", ["ref", "chunked"])
 @pytest.mark.parametrize("sharded", [False, True])
-def test_episode_votes_match_two_phase_candidates(backend, sharded):
+def test_episode_votes_match_two_phase_candidates(monkeypatch, backend,
+                                                  sharded):
     """Every two-phase candidate's vote equals the episodic forward's vote
-    for that support row -- ref and fused backends, sharded store included
-    (the acceptance matrix of ISSUE 5)."""
+    for that support row -- the ref backend, and the pallas backend with
+    phase 1 ranked 2 queries at a time, sharded store included."""
     cfg = SearchConfig("mtmc", cl=4, mode="avss", use_kernel="ref")
     eng = RetrievalEngine(cfg)
     q, s, labels = _episode()
@@ -83,6 +85,11 @@ def test_episode_votes_match_two_phase_candidates(backend, sharded):
     store = _programmed_store(cfg, q, s, labels)
     if sharded:
         store = store.shard(make_mesh((1,), ("data",)))
+    if backend == "chunked":
+        from repro.engine import sharded as sharded_lib
+        monkeypatch.setattr(sharded_lib, "SHORTLIST_DENSE_MAX_BYTES",
+                            2 * store.capacity * 4)
+        backend = "pallas"
     res = eng.search(store, q, SearchRequest(
         mode="two_phase", k=s.shape[0], backend=backend, noisy=False))
     votes = jnp.take_along_axis(ep["votes"], res.indices, axis=1)
